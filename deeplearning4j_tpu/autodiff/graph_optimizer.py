@@ -280,7 +280,7 @@ def optimize(sd: SameDiff) -> Dict[str, int]:
 # activations through the 2-D form. XLA assigns the 2-D dot outputs
 # column-major-style layouts that clash with the 3-D consumers', and the
 # resulting layout-conversion copies measured 4.6 GB/step on the imported
-# BERT-base (vs 0.45 GB in the hand-built model; see BASELINE.md round 3).
+# BERT-base (vs 0.45 GB in the hand-built model, round 3).
 # These passes restore the 3-D form the hand-built layers use: fold the
 # reshape into the matmul, sink the compensating reshape down through
 # elementwise ops until it meets another reshape, and collapse the pair.

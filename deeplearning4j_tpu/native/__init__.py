@@ -34,11 +34,14 @@ _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
-def _compile(srcs, out_path, extra_flags=(), headers=(), timeout=180,
-             march_native=True) -> Optional[str]:
+def _compile(srcs, out_path, extra_flags=(), headers=(),
+             timeout=180) -> Optional[str]:
     """Shared compile-and-cache: rebuild ``out_path`` when any source or
     header is newer; atomic output (compile to .tmp, rename) so concurrent
-    builders never dlopen a half-written .so."""
+    builders never dlopen a half-written .so. Built for the generic ISA
+    (no ``-march=native``): the artifact sits in the working tree, a tree
+    can be copied to a machine with another CPU, and the mtime check there
+    would judge a binary tuned to the first one "up to date"."""
     newest = max(os.path.getmtime(f) for f in tuple(srcs) + tuple(headers))
     if os.path.exists(out_path) and os.path.getmtime(out_path) >= newest:
         return out_path
@@ -48,23 +51,20 @@ def _compile(srcs, out_path, extra_flags=(), headers=(), timeout=180,
     # needs its own tmp so neither can truncate or unlink the other's
     # in-progress object; the atomic rename publishes whichever finishes
     tmp = out_path + f".tmp.{os.getpid()}.{threading.get_ident()}"
-    base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread"]
-    variants = ([base + ["-march=native"], base] if march_native else [base])
-    for cc in variants:
-        try:
-            subprocess.run(cc + ["-o", tmp] + list(srcs) + list(extra_flags),
-                           check=True, capture_output=True, timeout=timeout)
-            os.replace(tmp, out_path)
-            return out_path
-        except (subprocess.SubprocessError, FileNotFoundError, OSError):
-            continue
-        finally:
-            if os.path.exists(tmp):
-                try:
-                    os.unlink(tmp)
-                except OSError:
-                    pass
-    return None
+    cc = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread"]
+    try:
+        subprocess.run(cc + ["-o", tmp] + list(srcs) + list(extra_flags),
+                       check=True, capture_output=True, timeout=timeout)
+        os.replace(tmp, out_path)
+        return out_path
+    except (subprocess.SubprocessError, FileNotFoundError, OSError):
+        return None
+    finally:
+        if os.path.exists(tmp):
+            try:
+                os.unlink(tmp)
+            except OSError:
+                pass
 
 
 def _build() -> Optional[str]:
@@ -460,6 +460,6 @@ def build_capi(force: bool = False) -> Optional[str]:
     libdir = sysconfig.get_config_var("LIBDIR") or ""
     ver = sysconfig.get_config_var("LDVERSION") or "3"
     return _compile(
-        [src], _CAPI_LIB, headers=[hdr], march_native=False,
+        [src], _CAPI_LIB, headers=[hdr],
         extra_flags=[f"-I{inc}", f"-L{libdir}", f"-Wl,-rpath,{libdir}",
                      f"-lpython{ver}"])

@@ -57,7 +57,7 @@ def dropout_mask(rng, keep_prob, shape):
     counter math costs real MXU-adjacent cycles: on the v5e it was measured
     at ~15 ms/step of BERT-base (64x128), ~27% of the whole step. The rbg
     generator is hardware-backed and cut that to noise (1187 -> 1637
-    samples/s, v5e, dropout-site-only switch; see BASELINE.md round 3).
+    samples/s, v5e, dropout-site-only switch, round 3).
     Only dropout routes through here; weight init and every
     other draw keep the threefry key chain, so seeds/goldens elsewhere are
     unchanged. The incoming key may be a raw uint32 vector (old-style) or a

@@ -20,10 +20,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map  # stable location (jax >= 0.7)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.runtime.mesh import PIPE_AXIS
@@ -95,16 +92,9 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
 
     spec_params = jax.tree.map(lambda _: P(axis_name), stacked_params)
     spec_mbs = P(None, tuple(batch_axes)) if batch_axes else P()
-    # jax.shard_map (>=0.7) spells the replication check check_vma; the
-    # experimental one spelled it check_rep
-    try:
-        fn = shard_map(per_device, mesh=mesh,
-                       in_specs=(spec_params, spec_mbs), out_specs=spec_mbs,
-                       check_vma=False)
-    except TypeError:
-        fn = shard_map(per_device, mesh=mesh,
-                       in_specs=(spec_params, spec_mbs), out_specs=spec_mbs,
-                       check_rep=False)
+    fn = shard_map(per_device, mesh=mesh,
+                   in_specs=(spec_params, spec_mbs), out_specs=spec_mbs,
+                   check_vma=False)
     out = fn(stacked_params, mbs)
     return out.reshape((B,) + out.shape[2:])
 

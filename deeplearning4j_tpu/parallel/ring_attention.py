@@ -17,23 +17,12 @@ Public API:
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # stable location (jax >= 0.7)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# jax.shard_map (>=0.7) spells the replication check check_vma; the
-# experimental one spelled it check_rep. Resolved once here — a per-call
-# try/except TypeError would also swallow genuine construction errors.
-_SHARD_MAP_CHECK_KW = ("check_vma" if "check_vma"
-                       in inspect.signature(shard_map).parameters
-                       else "check_rep")
 
 from deeplearning4j_tpu.runtime.mesh import SEQ_AXIS
 
@@ -102,5 +91,5 @@ def sequence_parallel_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     body = functools.partial(ring_attention, axis_name=seq_axis, causal=causal)
     fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, **{_SHARD_MAP_CHECK_KW: False})
+                   out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -7,12 +7,24 @@ process restart or a registry hot-swap recompiles every bucket×replica
 executable from scratch, and compile time gates time-to-ready. This module
 closes both ends:
 
-**Persistent executable cache** (:func:`enable`): wires JAX's persistent
-compilation cache under a *framework-keyed* directory (one subdirectory per
-jax version, so an upgrade never deserializes stale executables), forces
-every executable to be cached (the default 1 s minimum-compile-time gate
-would skip exactly the sub-second serving-bucket programs cold start is
-made of), and instruments the load path:
+**Persistent executable cache** (:func:`enable`): turns on JAX's persistent
+compilation cache, forces every executable to be cached (the default 1 s
+minimum-compile-time gate would skip exactly the sub-second serving-bucket
+programs cold start is made of), and instruments the load path. One rule
+decides where the cache lives (:func:`resolve_dir`):
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: that directory, used as given. JAX
+  read it at import; nothing here calls
+  ``jax.config.update("jax_compilation_cache_dir", ...)``, and an explicit
+  directory argument is ignored with a log line.
+- not set: the explicit directory if one was passed (tests, drills), else
+  ``<repo>/.jax_cache`` — a fixed path derived from the package location,
+  because the path is part of what makes a later process find the entries.
+
+JAX's own cache key already covers the jax/jaxlib version and the backend,
+so the directory carries no version suffix. Nothing in the library enables
+the cache at import; entry points (``chip_smoke.py``, ``bench.py``) call
+:func:`enable`. The instrumentation:
 
 - **hit / miss / corrupt counters + compile seconds**, exposed through
   :func:`stats`, ``runtime.profiler.compile_cache_stats`` and the serving
@@ -22,10 +34,6 @@ made of), and instruments the load path:
   counted, logged, and answered with "not cached" — a cold compile is
   always a correct fallback; a bad cache file can never take the process
   down. The entry is rewritten by the post-compile cache write.
-
-Knobs: ``DL4J_TPU_COMPILE_CACHE=<dir>`` environment variable (read by
-``Environment``'s first-touch init) or
-``get_environment().set_compile_cache(dir)``.
 
 **AOT dispatch fast path** (:class:`AotCache`): the fit loops and the
 serving replica pool re-dispatch ONE jitted program millions of times at a
@@ -50,14 +58,39 @@ import time
 from typing import Any, Dict, Hashable, Optional
 
 import jax
+from jax._src import compilation_cache as _cc
+from jax._src import monitoring
 
 from deeplearning4j_tpu.runtime import chaos, trace
 
 logger = logging.getLogger(__name__)
 
-#: Framework key for the cache directory: executables are only reusable
-#: within one jax/jaxlib build, so the version is part of the path.
-FRAMEWORK_KEY = "dl4j-tpu-v1"
+#: JAX's own variable: when set, it alone places the cache.
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def default_dir() -> str:
+    """``<repo>/.jax_cache``: fixed, derived from where the package lives
+    (never from ``tempfile``, a pid or the clock — a directory that moves
+    never hits)."""
+    repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(repo_root, ".jax_cache")
+
+
+def resolve_dir(directory: Optional[str] = None) -> str:
+    """The one resolution rule (module docstring): environment variable,
+    else the explicit directory, else :func:`default_dir`."""
+    placed = os.environ.get(ENV_VAR)
+    if placed:
+        if directory and directory != placed:
+            logger.warning(
+                "compile cache: %s=%s places the cache; ignoring the "
+                "explicit directory %s", ENV_VAR, placed, directory)
+        return placed
+    if directory:
+        return os.path.abspath(os.path.expanduser(directory))
+    return default_dir()
 
 
 class CompileCacheStats:
@@ -144,16 +177,17 @@ def _install_hooks() -> None:
     global _hooks_installed, _orig_get
     if _hooks_installed:
         return
-    from jax._src import compilation_cache as _cc
-
     _orig_get = _cc.get_executable_and_time
 
-    def _guarded_get(cache_key, compile_options, backend):
+    def _guarded_get(cache_key, *args, **kwargs):
+        # arguments after the key are forwarded as given: jax owns that
+        # signature (0.9 added executable_devices) and a wrapper that
+        # names them turns every read into a TypeError jax downgrades to
+        # a warning — a cache that silently never hits
         t0 = time.perf_counter()
         try:
             chaos.inject("runtime.compile_cache.load")
-            executable, compile_time = _orig_get(
-                cache_key, compile_options, backend)
+            executable, compile_time = _orig_get(cache_key, *args, **kwargs)
         except (KeyboardInterrupt, SystemExit):
             raise  # an abort is not a corrupt entry; let it abort
         except BaseException as e:
@@ -174,44 +208,37 @@ def _install_hooks() -> None:
 
     _cc.get_executable_and_time = _guarded_get
 
-    try:  # compile seconds ride jax's monitoring stream (best effort)
-        from jax._src import monitoring
+    def _on_duration(name: str, dur: float, **kw) -> None:
+        if name == "/jax/core/compile/backend_compile_duration":
+            STATS.record("compiles", dur)
 
-        def _on_duration(name: str, dur: float, **kw) -> None:
-            if name == "/jax/core/compile/backend_compile_duration":
-                STATS.record("compiles", dur)
-
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:  # pragma: no cover - monitoring API moved
-        logger.debug("compile cache: no monitoring stream; compile-seconds "
-                     "counter disabled", exc_info=True)
+    monitoring.register_event_duration_secs_listener(_on_duration)
     _hooks_installed = True
 
 
 def enable(directory: Optional[str] = None) -> str:
-    """Turn on the persistent executable cache rooted at ``directory``
-    (default: the ``DL4J_TPU_COMPILE_CACHE`` environment variable).
-    Returns the resolved framework-keyed cache directory. Safe to call
-    repeatedly / with a new directory."""
+    """Turn on the persistent executable cache at :func:`resolve_dir`
+    ``(directory)`` and return that path. Safe to call repeatedly / with a
+    new directory."""
     global _cache_dir
-    base = directory or os.environ.get("DL4J_TPU_COMPILE_CACHE")
-    if not base:
-        raise ValueError("compile_cache.enable() needs a directory (or set "
-                         "DL4J_TPU_COMPILE_CACHE)")
-    resolved = os.path.join(os.path.abspath(os.path.expanduser(base)),
-                            f"{FRAMEWORK_KEY}-jax{jax.__version__}")
-    os.makedirs(resolved, exist_ok=True)
+    resolved = resolve_dir(directory)
     _install_hooks()
-    jax.config.update("jax_compilation_cache_dir", resolved)
+    if os.environ.get(ENV_VAR):
+        # placed from outside: jax took the directory from its own
+        # variable at import and this module leaves that setting alone
+        if jax.config.jax_compilation_cache_dir != resolved:
+            raise RuntimeError(
+                f"{ENV_VAR}={resolved} but jax holds "
+                f"{jax.config.jax_compilation_cache_dir!r}: the variable "
+                f"must be set before jax is imported")
+    else:
+        os.makedirs(resolved, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", resolved)
+        _cc.reset_cache()  # drop an initialized handle on the old dir
     # Cache EVERYTHING: serving cold start is dominated by many sub-second
     # bucket×replica compiles that the default 1s/size floors would skip.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:  # drop a previously-initialized handle so the new dir takes effect
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover
-        logger.debug("compile cache: reset_cache unavailable", exc_info=True)
     _cache_dir = resolved
     logger.info("compile cache enabled at %s", resolved)
     return resolved
@@ -219,16 +246,17 @@ def enable(directory: Optional[str] = None) -> str:
 
 def disable() -> None:
     """Detach the persistent cache (counters and hooks stay; they are
-    inert without a configured directory)."""
+    inert without a configured directory). A cache placed through
+    ``JAX_COMPILATION_CACHE_DIR`` is not this module's to detach."""
     global _cache_dir
     if _cache_dir is None:
         return
+    if os.environ.get(ENV_VAR):
+        logger.warning("compile cache: %s places the cache; disable() "
+                       "leaves it on", ENV_VAR)
+        return
     jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover
-        pass
+    _cc.reset_cache()
     _cache_dir = None
 
 
@@ -303,6 +331,6 @@ class AotCache:
             # now-stable arguments.
             self._entries.pop(key, None)
             STATS.record("aot_fallbacks")
-            logger.debug("AotCache(%s): signature drift at key %r; falling "
-                         "back to jit dispatch", self.name, key)
+            logger.warning("AotCache(%s): signature drift at key %r; "
+                           "falling back to jit dispatch", self.name, key)
             return jitted(*args)

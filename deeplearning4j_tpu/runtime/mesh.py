@@ -139,16 +139,6 @@ def local_mesh() -> Mesh:
     return create_mesh(MeshSpec({DATA_AXIS: -1}))
 
 
-def _gloo_available() -> bool:
-    """Whether this jaxlib ships the gloo TCP CPU-collectives backend —
-    selecting an unavailable implementation would fail CPU client creation."""
-    try:
-        from jaxlib import xla_extension
-        return hasattr(xla_extension, "make_gloo_tcp_collectives")
-    except ImportError:  # pragma: no cover
-        return False
-
-
 def initialize_multihost(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -161,20 +151,10 @@ def initialize_multihost(
     ICI within a slice and DCN across slices. Safe to call with no arguments
     under TPU metadata-provided environments.
 
-    On the CPU backend (the ``local[N]``-style multi-process smoke path) the
-    default XLA client has no cross-process collectives at all — every
-    allreduce dies with "Multiprocess computations aren't implemented on the
-    CPU backend" — so a gloo TCP implementation must be selected BEFORE the
-    backend initializes. Selected for EVERY multi-process bring-up, not just
-    ``JAX_PLATFORMS=cpu``: the flag only affects the CPU client (which jax
-    creates regardless of which accelerator is primary), so it is harmless
-    on TPU hosts and covers CPU-by-default/auto-detect runs too.
+    On the CPU backend (the ``local[N]``-style multi-process smoke path)
+    cross-process collectives ride jax's gloo TCP implementation, which is
+    the installed default (``jax_cpu_collectives_implementation``).
     """
-    if num_processes is not None and num_processes > 1 and _gloo_available():
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # pragma: no cover
-            pass  # older jax: flag absent — keep the default behaviour
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

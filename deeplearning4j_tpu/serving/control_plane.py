@@ -39,7 +39,9 @@ deliberately share NOTHING but files and scrapes:
   registered itself in the shared config), heartbeat + exit-code
   watchdog, and budgeted restarts. Router pids register in this module's
   own leak-guard tables, polled by the conftest guard exactly like fleet
-  worker pids.
+  worker pids. Router processes, like fleet workers, are CPU processes
+  (the inherited spawn env pins ``JAX_PLATFORMS=cpu``): a router never
+  touches a chip.
 - :class:`MultiRouterClient` — the caller's side of the story:
   round-robin across the live router roster with connect-fail/5xx
   failover, so a SIGKILL'd router is invisible to callers (the drill of
@@ -924,7 +926,6 @@ class RouterSpec:
     #: autoscaler at all (pure data plane)
     autoscaler: Optional[Dict[str, Any]] = None
     host: str = "local"
-    jax_platforms: str = "cpu"
     host_device_count: int = 1
     heartbeat_interval_s: float = 0.5
 

@@ -12,6 +12,11 @@ unaffected.
 
 import glob
 import json
+import logging
+import os
+import pathlib
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -88,9 +93,11 @@ def aot_toggle():
 
 
 # ----------------------------------------------------- persistent cache
-def test_enable_is_framework_keyed_and_counts_hits_and_misses(cache_dir):
-    assert compile_cache.FRAMEWORK_KEY in cache_dir
-    assert f"jax{jax.__version__}" in cache_dir
+def test_enable_uses_directory_as_given_and_counts_hits_and_misses(
+        cache_dir, tmp_path):
+    # no framework/version sub-directory: jax's key covers version+backend
+    assert cache_dir == str(tmp_path / "executable-cache")
+    assert jax.config.jax_compilation_cache_dir == cache_dir
     x = jnp.ones((32, 16))
     r1 = np.asarray(_probe_fn()(x))
     s1 = compile_cache.stats()
@@ -132,6 +139,98 @@ def test_chaos_load_fault_falls_back_to_compile(cache_dir):
     hits = compile_cache.stats()["hits"]
     np.asarray(_probe_fn()(x))
     assert compile_cache.stats()["hits"] > hits
+
+
+def test_cached_compile_with_cache_errors_fatal(cache_dir):
+    """A drift between our load-path wrapper and jax's signature must be
+    an error, not the UserWarning jax downgrades it to — with
+    ``jax_raise_persistent_cache_errors`` a read that raised would fail
+    the compile instead of silently missing forever."""
+    jax.config.update("jax_raise_persistent_cache_errors", True)
+    try:
+        x = jnp.ones((24, 8))
+        r1 = np.asarray(_probe_fn()(x))
+        r2 = np.asarray(_probe_fn()(x))
+    finally:
+        jax.config.update("jax_raise_persistent_cache_errors", False)
+    s = compile_cache.stats()
+    assert s["hits"] >= 1 and s["corrupt_entries"] == 0, s
+    assert (r1 == r2).all()
+
+
+# ------------------------------------------------- where the cache lives
+_REPO = pathlib.Path(__file__).resolve().parents[1]
+
+
+def test_resolve_dir_defaults_to_fixed_path_in_checkout(monkeypatch):
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    assert compile_cache.resolve_dir() == str(_REPO / ".jax_cache")
+    assert compile_cache.resolve_dir() == compile_cache.resolve_dir()
+
+
+def test_resolve_dir_honours_explicit_dir_only_without_env(
+        monkeypatch, tmp_path, caplog):
+    explicit, placed = str(tmp_path / "mine"), str(tmp_path / "outside")
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    assert compile_cache.resolve_dir(explicit) == explicit
+    monkeypatch.setenv(compile_cache.ENV_VAR, placed)
+    with caplog.at_level(logging.WARNING,
+                         logger=compile_cache.logger.name):
+        assert compile_cache.resolve_dir(explicit) == placed
+    assert any("ignoring" in r.getMessage() for r in caplog.records)
+    assert compile_cache.resolve_dir() == placed
+
+
+def test_enable_refuses_env_var_set_after_jax_import(monkeypatch, tmp_path):
+    """jax reads the variable at import; setting it later places nothing,
+    and enable() says so rather than cache somewhere else."""
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "late"))
+    with pytest.raises(RuntimeError, match="before jax is imported"):
+        compile_cache.enable()
+    assert not compile_cache.is_enabled()
+
+
+_PLACED_FROM_OUTSIDE = """
+import glob, os, sys
+import jax, jax.numpy as jnp
+real_update = jax.config.update
+def guarded(name, value):
+    assert name != "jax_compilation_cache_dir", "our code moved the cache"
+    return real_update(name, value)
+jax.config.update = guarded
+from deeplearning4j_tpu.runtime import compile_cache
+placed = os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert compile_cache.enable(sys.argv[1]) == placed      # explicit ignored
+assert jax.config.jax_compilation_cache_dir == placed
+def fresh():  # a new def per call: same HLO, no in-memory executable
+    def probe(x):
+        return (x * 3.0 + 1.0) @ x.T
+    return jax.jit(probe)
+fresh()(jnp.ones((8, 4))).block_until_ready()
+fresh()(jnp.ones((8, 4))).block_until_ready()
+compile_cache.disable()                                  # not ours to detach
+assert jax.config.jax_compilation_cache_dir == placed
+assert glob.glob(placed + "/*-cache"), os.listdir(placed)
+assert not os.path.exists(sys.argv[1])
+print("HITS", compile_cache.stats()["hits"])
+"""
+
+
+def test_cache_placed_from_outside_is_used_as_given(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set before start-up: entries land in
+    exactly that directory, nothing calls ``jax.config.update`` on the
+    cache dir, an explicit directory is ignored — and the cache hits."""
+    placed = tmp_path / "placed"
+    placed.mkdir()
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(placed),
+               PYTHONPATH=str(_REPO) + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.run(
+        [sys.executable, "-c", _PLACED_FROM_OUTSIDE,
+         str(tmp_path / "explicit")],
+        env=env, capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert int(proc.stdout.split("HITS")[-1]) >= 1, proc.stdout
 
 
 # ----------------------------------------------------------- AOT cache
