@@ -342,6 +342,16 @@ class ComputationGraph:
         """Execute one topo node, mutating acts/last_inputs/new_state.
         Returns the (possibly replaced) carries dict."""
         node = self.conf.node(name)
+        # the node boundary of the device trace (see MultiLayerNetwork._forward)
+        with jax.named_scope(f"{name}.{type(node.obj).__name__}"):
+            return self._exec_node_scoped(
+                i, name, node, acts, last_inputs, new_state, params,
+                model_state, training=training, rng=rng, masks=masks,
+                carries=carries, output_set=output_set)
+
+    def _exec_node_scoped(self, i, name, node, acts, last_inputs, new_state,
+                          params, model_state, *, training, rng, masks,
+                          carries, output_set):
         ins = [acts[k] for k in node.inputs]
         if node.kind == "vertex":
             acts[name] = node.obj.forward(*ins)
@@ -526,15 +536,17 @@ class ComputationGraph:
                 lrng = jax.random.fold_in(rng, i_node)
                 out_p = apply_weight_noise(layer, out_p,
                                            jax.random.fold_in(lrng, 7919))
-            total = total + layer.compute_loss(
-                out_p, last_inputs[out_name], y, mask=mask,
-                state=model_state.get(out_name, {}))
+            with jax.named_scope("loss"):
+                total = total + layer.compute_loss(
+                    out_p, last_inputs[out_name], y, mask=mask,
+                    state=model_state.get(out_name, {}))
             if training and hasattr(layer, "update_state_with_labels"):
                 new_state = dict(new_state)
                 new_state[out_name] = layer.update_state_with_labels(
                     model_state.get(out_name, {}),
                     jax.lax.stop_gradient(last_inputs[out_name]), y)
-        total = total + self._reg_score(params)
+        with jax.named_scope("loss"):
+            total = total + self._reg_score(params)
         # layer auxiliary losses (e.g. MoE load balancing) — training only
         if training:
             for s2 in new_state.values():
@@ -579,17 +591,19 @@ class ComputationGraph:
         return out
 
     def _train_step_fn(self):
-        def step(ts: TrainState, inputs, labels, rng, masks):
+        # named anew with the scopes: see MultiLayerNetwork._train_step_fn
+        def graph_train_step(ts: TrainState, inputs, labels, rng, masks):
             (loss, (new_state, _)), grads = jax.value_and_grad(
                 self._loss, has_aux=True)(
                 ts.params, ts.model_state, inputs, labels, rng, masks)
-            updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-            new_params = self._apply_constraints(
-                optax.apply_updates(ts.params, updates))
+            with jax.named_scope("updater"):
+                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
+                new_params = self._apply_constraints(
+                    optax.apply_updates(ts.params, updates))
             return TrainState(params=new_params, model_state=new_state,
                               opt_state=new_opt, step=ts.step + 1), loss
 
-        return step
+        return graph_train_step
 
     def _make_train_step(self):
         return jax.jit(self._train_step_fn(), donate_argnums=(0,))
@@ -602,28 +616,29 @@ class ComputationGraph:
         packer = LeafPacker(self.train_state)
         raw = self._train_step_fn()
 
-        def packed_step(pts, inputs, labels, rng, masks):
+        def packed_graph_train_step(pts, inputs, labels, rng, masks):
             new_ts, loss = raw(packer.unpack(pts), inputs, labels, rng, masks)
             return packer.pack(new_ts), loss
 
-        return jax.jit(packed_step, donate_argnums=(0,)), packer
+        return jax.jit(packed_graph_train_step, donate_argnums=(0,)), packer
 
     def _make_tbptt_step(self):
         """Train step carrying recurrent state across truncated chunks
         (reference: tBPTT on ComputationGraph)."""
-        def step(ts: TrainState, carries, inputs, labels, rng, masks):
+        def tbptt_graph_train_step(ts: TrainState, carries, inputs, labels, rng, masks):
             (loss, (new_state, new_carries)), grads = jax.value_and_grad(
                 self._loss, has_aux=True)(
                 ts.params, ts.model_state, inputs, labels, rng, masks,
                 True, carries)
-            updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-            new_params = optax.apply_updates(ts.params, updates)
+            with jax.named_scope("updater"):
+                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
+                new_params = optax.apply_updates(ts.params, updates)
             new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
             return (TrainState(params=new_params, model_state=new_state,
                                opt_state=new_opt, step=ts.step + 1),
                     new_carries, loss)
 
-        return jax.jit(step, donate_argnums=(0, 1))
+        return jax.jit(tbptt_graph_train_step, donate_argnums=(0, 1))
 
     def _jitted(self, name, factory):
         # remat is read at TRACE time, so flipping env.set_remat() must
@@ -693,6 +708,7 @@ class ComputationGraph:
                                                                PackedStepLoop)
         from deeplearning4j_tpu.train.prefetch import (AsyncLossDelivery,
                                                        stateless_listeners)
+        from deeplearning4j_tpu.train.profiler import sync_timed
         ploop = PackedStepLoop.for_network(self)
         if profiler is not None:
             profiler.start()
@@ -734,7 +750,7 @@ class ComputationGraph:
         finally:
             # any exit path (incl. KeyboardInterrupt / iterator errors) must
             # leave train_state reflecting every completed step
-            ploop.sync(release=True)
+            sync_timed(ploop, profiler)
             if profiler is not None:
                 profiler.stop()
         if adel is not None:
@@ -745,7 +761,8 @@ class ComputationGraph:
                     drain=lambda: None, prefetch_buffer: int = 0,
                     profiler=None) -> None:
         from deeplearning4j_tpu.train.prefetch import batch_source
-        from deeplearning4j_tpu.train.profiler import submit_timed
+        from deeplearning4j_tpu.train.profiler import (drain_timed,
+                                                        submit_timed)
         for _ in range(epochs):
             for lst in self._listeners:
                 lst.on_epoch_start(self, self._epoch)
@@ -775,12 +792,11 @@ class ComputationGraph:
                         gd._deliver((inputs, labels_, None, masks), loss)
                         continue
                     submit_timed(
-                        gd, (inputs, labels_, self.rng.next_key(), masks),
-                        profiler)
+                        gd, self.rng,
+                        lambda key: (inputs, labels_, key, masks), profiler)
             finally:
                 src.close()
-            gd.flush()
-            drain()  # on_epoch_end must observe every iteration_done
+            drain_timed(gd, drain, profiler)
             # no epoch-end sync: packing only runs when every listener is
             # stateless, so nothing reads train_state until fit() returns
             for lst in self._listeners:

@@ -178,40 +178,43 @@ class MultiLayerNetwork:
                      and n > 2)
         for i, layer in enumerate(self.layers):
             k = _layer_key(i, layer)
-            if i in self.conf.preprocessors:
-                x = self.conf.preprocessors[i].pre_process(x, fmask)
-            p = params.get(k, {})
-            s = model_state.get(k, {})
-            lrng = jax.random.fold_in(rng, i) if rng is not None else None
-            if training and getattr(layer, "weight_noise", None) is not None:
-                from deeplearning4j_tpu.nn.constraints import apply_weight_noise
-                p = apply_weight_noise(
-                    layer, p,
-                    None if lrng is None else jax.random.fold_in(lrng, 7919))
-            if i == n - 1 and hasattr(layer, "compute_loss"):
-                x = layer._apply_input_dropout(x, layer._g, training, lrng)
-                last_input = x
-                x = layer.activate(p, x)
-            elif carries is not None and isinstance(layer, BaseRecurrentLayer):
-                x = layer._apply_input_dropout(x, layer._g, training, lrng)
-                y, c_new = layer.forward_with_carry(
-                    p, carries[k], x, training=training, rng=lrng, mask=fmask)
-                new_carries[k] = c_new
-                x = y
-            else:
-                if use_remat and i < n - 1:
-                    def _fwd(p_, s_, x_, lrng_, fmask_, _l=layer):
-                        return _l.forward(p_, s_, x_, training=True,
-                                          rng=lrng_, mask=fmask_)
-                    x, s_new = jax.checkpoint(_fwd)(p, s, x, lrng, fmask)
+            # the layer boundary of the device trace: every op below is
+            # named ``<layer key>.<LayerClass>/...`` (docs/observability.md)
+            with jax.named_scope(f"{k}.{type(layer).__name__}"):
+                if i in self.conf.preprocessors:
+                    x = self.conf.preprocessors[i].pre_process(x, fmask)
+                p = params.get(k, {})
+                s = model_state.get(k, {})
+                lrng = jax.random.fold_in(rng, i) if rng is not None else None
+                if training and getattr(layer, "weight_noise", None) is not None:
+                    from deeplearning4j_tpu.nn.constraints import apply_weight_noise
+                    p = apply_weight_noise(
+                        layer, p,
+                        None if lrng is None else jax.random.fold_in(lrng, 7919))
+                if i == n - 1 and hasattr(layer, "compute_loss"):
+                    x = layer._apply_input_dropout(x, layer._g, training, lrng)
+                    last_input = x
+                    x = layer.activate(p, x)
+                elif carries is not None and isinstance(layer, BaseRecurrentLayer):
+                    x = layer._apply_input_dropout(x, layer._g, training, lrng)
+                    y, c_new = layer.forward_with_carry(
+                        p, carries[k], x, training=training, rng=lrng, mask=fmask)
+                    new_carries[k] = c_new
+                    x = y
                 else:
-                    x, s_new = layer.forward(p, s, x, training=training,
-                                             rng=lrng, mask=fmask)
-                if s:
-                    new_state[k] = s_new
-            if fmask is not None and hasattr(layer, "transform_mask"):
-                # layers that change the time axis (crop/pad) realign the mask
-                fmask = layer.transform_mask(fmask)
+                    if use_remat and i < n - 1:
+                        def _fwd(p_, s_, x_, lrng_, fmask_, _l=layer):
+                            return _l.forward(p_, s_, x_, training=True,
+                                              rng=lrng_, mask=fmask_)
+                        x, s_new = jax.checkpoint(_fwd)(p, s, x, lrng, fmask)
+                    else:
+                        x, s_new = layer.forward(p, s, x, training=training,
+                                                 rng=lrng, mask=fmask)
+                    if s:
+                        new_state[k] = s_new
+                if fmask is not None and hasattr(layer, "transform_mask"):
+                    # layers that change the time axis (crop/pad) realign the mask
+                    fmask = layer.transform_mask(fmask)
         return x, last_input, new_state, new_carries
 
     def _loss(self, params, model_state, x, y, rng, fmask=None, lmask=None,
@@ -233,9 +236,10 @@ class MultiLayerNetwork:
             lrng = jax.random.fold_in(rng, len(self.layers) - 1)
             final_p = apply_weight_noise(final, final_p,
                                          jax.random.fold_in(lrng, 7919))
-        loss = final.compute_loss(final_p, last_in, y, mask=lmask,
-                                  state=model_state.get(k, {}))
-        loss = loss + self._reg_score(params)
+        with jax.named_scope("loss"):
+            loss = final.compute_loss(final_p, last_in, y, mask=lmask,
+                                      state=model_state.get(k, {}))
+            loss = loss + self._reg_score(params)
         # differentiable auxiliary losses surfaced by layers through the
         # state channel (e.g. MoE load balancing) — same trace, so grads
         # flow. Training-only: score() reports the data loss, not training
@@ -291,16 +295,21 @@ class MultiLayerNetwork:
         return out
 
     def _train_step_fn(self):
-        def train_step(ts: TrainState, x, y, rng, fmask, lmask):
+        # Renamed with the scopes (ISSUE 26): the persistent cache key holds
+        # the module's name but not its metadata, so the old name would be
+        # served a scope-less executable from an older cache. A renamed
+        # scope needs a cleared cache (docs/observability.md, "Training").
+        def mln_train_step(ts: TrainState, x, y, rng, fmask, lmask):
             (loss, (new_state, _)), grads = jax.value_and_grad(self._loss, has_aux=True)(
                 ts.params, ts.model_state, x, y, rng, fmask, lmask)
-            updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-            new_params = self._apply_constraints(
-                optax.apply_updates(ts.params, updates))
+            with jax.named_scope("updater"):
+                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
+                new_params = self._apply_constraints(
+                    optax.apply_updates(ts.params, updates))
             return TrainState(params=new_params, model_state=new_state,
                               opt_state=new_opt, step=ts.step + 1), loss
 
-        return train_step
+        return mln_train_step
 
     def _make_train_step(self):
         return jax.jit(self._train_step_fn(), donate_argnums=(0,))
@@ -313,25 +322,26 @@ class MultiLayerNetwork:
         packer = LeafPacker(self.train_state)
         raw = self._train_step_fn()
 
-        def packed_step(pts, x, y, rng, fmask, lmask):
+        def packed_train_step(pts, x, y, rng, fmask, lmask):
             new_ts, loss = raw(packer.unpack(pts), x, y, rng, fmask, lmask)
             return packer.pack(new_ts), loss
 
-        return jax.jit(packed_step, donate_argnums=(0,)), packer
+        return jax.jit(packed_train_step, donate_argnums=(0,)), packer
 
     def _make_tbptt_step(self):
         """Train step with explicit recurrent carries (truncated BPTT)."""
-        def step(ts: TrainState, carries, x, y, rng, fmask, lmask):
+        def tbptt_train_step(ts: TrainState, carries, x, y, rng, fmask, lmask):
             (loss, (new_state, new_carries)), grads = jax.value_and_grad(
                 self._loss, has_aux=True)(ts.params, ts.model_state, x, y, rng,
                                           fmask, lmask, carries)
-            updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-            new_params = optax.apply_updates(ts.params, updates)
+            with jax.named_scope("updater"):
+                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
+                new_params = optax.apply_updates(ts.params, updates)
             new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
             return (TrainState(params=new_params, model_state=new_state,
                                opt_state=new_opt, step=ts.step + 1), new_carries, loss)
 
-        return jax.jit(step, donate_argnums=(0, 1))
+        return jax.jit(tbptt_train_step, donate_argnums=(0, 1))
 
     def _jitted(self, name: str, factory):
         # remat is read at TRACE time, so flipping env.set_remat() must
@@ -393,6 +403,7 @@ class MultiLayerNetwork:
         else:
             iterator = data
         from deeplearning4j_tpu.runtime.state_packing import PackedStepLoop
+        from deeplearning4j_tpu.train.profiler import sync_timed
         ploop = PackedStepLoop.for_network(self)
         if profiler is not None:
             profiler.start()
@@ -403,7 +414,7 @@ class MultiLayerNetwork:
         finally:
             # any exit path (incl. KeyboardInterrupt / iterator errors) must
             # leave train_state reflecting every completed step
-            ploop.sync(release=True)
+            sync_timed(ploop, profiler)
             if profiler is not None:
                 profiler.stop()
         return self
@@ -458,7 +469,8 @@ class MultiLayerNetwork:
                     prefetch_buffer=0, profiler=None) -> None:
         from deeplearning4j_tpu.train.prefetch import (batch_source,
                                                        coerce_training_batch)
-        from deeplearning4j_tpu.train.profiler import submit_timed
+        from deeplearning4j_tpu.train.profiler import (drain_timed,
+                                                        submit_timed)
         for _ in range(epochs):
             for lst in self._listeners:
                 lst.on_epoch_start(self, self._epoch)
@@ -490,12 +502,11 @@ class MultiLayerNetwork:
                         loss = solver_fit_batch(self, x, y, fm, lm)
                         gd._deliver((x, y, None, fm, lm), loss)  # same bookkeeping
                         continue
-                    submit_timed(gd, (x, y, self.rng.next_key(), fm, lm),
-                                 profiler)
+                    submit_timed(gd, self.rng,
+                                 lambda key: (x, y, key, fm, lm), profiler)
             finally:
                 src.close()
-            gd.flush()
-            drain()  # on_epoch_end must observe every iteration_done
+            drain_timed(gd, drain, profiler)
             # no epoch-end sync: packing only runs when every listener is
             # stateless, so nothing reads train_state until fit() returns
             for lst in self._listeners:

@@ -46,27 +46,32 @@ def dot_product_attention(q, k, v, mask=None, use_flash: bool = True,
         from deeplearning4j_tpu.ops.pallas.flash_attention import (
             flash_attention, flash_attention_compatible)
         if flash_attention_compatible(q, k, v, mask, causal=causal):
-            return flash_attention(q, k, v, mask, causal=causal)
+            # one kernel does scores, softmax and context: one scope
+            with jax.named_scope("flash"):
+                return flash_attention(q, k, v, mask, causal=causal)
         # The short-T fused kernel (ops.pallas.fused_attention_short) is
         # never routed here: in isolation it measured parity with XLA
         # (0.98-1.01x) and in-model a net loss on v5e (38 -> 51 ms/step
         # for BERT-base) — each pallas_call boundary in the big traced
         # step costs ~0.5-0.7 ms of lost fusion/async overlap, x24 calls.
     d = q.shape[-1]
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(jnp.asarray(d, q.dtype))
-    if mask is not None:
-        if mask.ndim == 2:  # (batch, t_k) key-padding form
-            mask = mask[:, None, None, :]
-        scores = jnp.where(mask, scores, jnp.asarray(-1e9, scores.dtype))
-    if causal:
-        t_q, t_k = q.shape[2], k.shape[2]
-        # bottom-right aligned triangle: for KV-cache decode (t_q < t_k) the
-        # last query row attends every key (offset = t_k - t_q)
-        tri = jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q)
-        scores = jnp.where(tri[None, None], scores,
-                           jnp.asarray(-1e9, scores.dtype))
-    weights = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
+    with jax.named_scope("scores"):
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(jnp.asarray(d, q.dtype))
+        if mask is not None:
+            if mask.ndim == 2:  # (batch, t_k) key-padding form
+                mask = mask[:, None, None, :]
+            scores = jnp.where(mask, scores, jnp.asarray(-1e9, scores.dtype))
+        if causal:
+            t_q, t_k = q.shape[2], k.shape[2]
+            # bottom-right aligned triangle: for KV-cache decode (t_q < t_k) the
+            # last query row attends every key (offset = t_k - t_q)
+            tri = jnp.tril(jnp.ones((t_q, t_k), bool), k=t_k - t_q)
+            scores = jnp.where(tri[None, None], scores,
+                               jnp.asarray(-1e9, scores.dtype))
+    with jax.named_scope("softmax"):
+        weights = jax.nn.softmax(scores, axis=-1)
+    with jax.named_scope("context"):
+        return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
 
 
 @register_layer
@@ -111,16 +116,18 @@ class SelfAttentionLayer(Layer):
         # 39.1 ms/step on BERT-base) — the fused weight and its gradient
         # materialize as extra traffic while XLA already schedules the three
         # shared-LHS matmuls back-to-back. Kept unfused deliberately.
-        q = (x @ params["W_q"] + params["b_q"]).reshape(b, t, h, -1).transpose(0, 2, 1, 3)
-        k = (x @ params["W_k"] + params["b_k"]).reshape(b, t, h, -1).transpose(0, 2, 1, 3)
-        v = (x @ params["W_v"] + params["b_v"]).reshape(b, t, h, -1).transpose(0, 2, 1, 3)
+        with jax.named_scope("qkv"):
+            q = (x @ params["W_q"] + params["b_q"]).reshape(b, t, h, -1).transpose(0, 2, 1, 3)
+            k = (x @ params["W_k"] + params["b_k"]).reshape(b, t, h, -1).transpose(0, 2, 1, 3)
+            v = (x @ params["W_v"] + params["b_v"]).reshape(b, t, h, -1).transpose(0, 2, 1, 3)
         attn_mask = None
         if mask is not None:
             attn_mask = mask[:, None, None, :].astype(bool)  # key-side padding mask
         y = dot_product_attention(q, k, v, attn_mask)
-        y = y.transpose(0, 2, 1, 3).reshape(b, t, -1)
-        if self.with_projection:
-            y = y @ params["W_o"] + params["b_o"]
+        with jax.named_scope("out_proj"):
+            y = y.transpose(0, 2, 1, 3).reshape(b, t, -1)
+            if self.with_projection:
+                y = y @ params["W_o"] + params["b_o"]
         return y, state
 
 
@@ -172,12 +179,15 @@ class TransformerEncoderBlock(Layer):
         attn._g = self._g
         r1, r2 = (jax.random.split(rng) if rng is not None else (None, None))
         a, _ = attn.forward(params["attn"], {}, x, training=training, rng=None, mask=mask)
-        x = layer_norm(x + self._dropout_fn(a, training, r1),
-                       params["ln1_gamma"], params["ln1_beta"], self.layer_norm_eps)
-        h = get_activation("gelu")(x @ params["W_ff1"] + params["b_ff1"])
-        h = h @ params["W_ff2"] + params["b_ff2"]
-        x = layer_norm(x + self._dropout_fn(h, training, r2),
-                       params["ln2_gamma"], params["ln2_beta"], self.layer_norm_eps)
+        with jax.named_scope("ln1"):
+            x = layer_norm(x + self._dropout_fn(a, training, r1),
+                           params["ln1_gamma"], params["ln1_beta"], self.layer_norm_eps)
+        with jax.named_scope("ffn"):
+            h = get_activation("gelu")(x @ params["W_ff1"] + params["b_ff1"])
+            h = h @ params["W_ff2"] + params["b_ff2"]
+        with jax.named_scope("ln2"):
+            x = layer_norm(x + self._dropout_fn(h, training, r2),
+                           params["ln2_gamma"], params["ln2_beta"], self.layer_norm_eps)
         return x, state
 
     def regularizable_params(self):
@@ -299,9 +309,11 @@ class BertEmbeddingLayer(Layer):
     def forward(self, params, state, x, *, training=False, rng=None, mask=None):
         ids = x.astype(jnp.int32)
         t = ids.shape[1]
-        y = jnp.take(params["tok"], ids, axis=0)
-        y = y + params["pos"][None, :t, :] + params["seg"][0][None, None, :]
-        y = layer_norm(y, params["ln_gamma"], params["ln_beta"], self.layer_norm_eps)
+        with jax.named_scope("embed"):
+            y = jnp.take(params["tok"], ids, axis=0)
+            y = y + params["pos"][None, :t, :] + params["seg"][0][None, None, :]
+        with jax.named_scope("ln"):
+            y = layer_norm(y, params["ln_gamma"], params["ln_beta"], self.layer_norm_eps)
         if training and rng is not None and self.dropout_rate > 0:
             keep = 1.0 - self.dropout_rate
             keep_mask = dropout_mask(rng, keep, y.shape)

@@ -225,6 +225,7 @@ def _flash_fwd(q, k, v, bias, scale, causal, has_bias, save_residuals=True):
         functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                           has_bias=has_bias, causal=causal,
                           save_residuals=save_residuals),
+        name="flash_attention_fwd",
         out_shape=out_shape,
         grid=grid,
         in_specs=in_specs,
@@ -542,6 +543,7 @@ def _flash_bwd_chunked(q, k, v, bias, out, lse, g, scale, causal, has_bias):
         functools.partial(_bwd_dq_kernel_chunked, scale=scale,
                           block_k=block_k, has_bias=has_bias, causal=causal,
                           n_chunks=n_chunks_k),
+        name="flash_attention_bwd_dq_chunked",
         out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
         grid=(b * h, t_q // block_q, n_chunks_k),
         in_specs=in_specs,
@@ -576,6 +578,7 @@ def _flash_bwd_chunked(q, k, v, bias, out, lse, g, scale, causal, has_bias):
         functools.partial(_bwd_dkv_kernel_chunked, scale=scale,
                           block_q=block_q, has_bias=has_bias, causal=causal,
                           n_chunks=n_chunks_q),
+        name="flash_attention_bwd_dkv_chunked",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, t_k, d_v), v.dtype),
@@ -634,6 +637,7 @@ def _flash_bwd(q, k, v, bias, out, lse, g, scale, causal, has_bias):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           has_bias=has_bias, causal=causal),
+        name="flash_attention_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
         grid=(b * h, t_q // block_q),
         in_specs=in_specs,
@@ -657,6 +661,7 @@ def _flash_bwd(q, k, v, bias, out, lse, g, scale, causal, has_bias):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           has_bias=has_bias, causal=causal),
+        name="flash_attention_bwd_dkv",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, t_k, d_v), v.dtype),

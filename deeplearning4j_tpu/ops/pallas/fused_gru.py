@@ -144,6 +144,7 @@ def _gru_fwd(zx, w_rec, h0, save_residuals):
             hidden=h)
     res = pl.pallas_call(
         kernel,
+        name="fused_gru_fwd",
         out_shape=out_shape,
         grid=(t,),
         in_specs=[
@@ -222,6 +223,7 @@ def _gru_bwd_kernel_call(dys, dhT, gates, zhn, h_prev_seq, w_rec):
     rev = lambda i: (t - 1 - i, 0, 0)  # noqa: E731 — reverse-time index map
     dzx, dh0 = pl.pallas_call(
         functools.partial(_bwd_kernel, hidden=h),
+        name="fused_gru_bwd",
         out_shape=[
             jax.ShapeDtypeStruct((t, b, h3), dtype),
             jax.ShapeDtypeStruct((b, h), dtype),

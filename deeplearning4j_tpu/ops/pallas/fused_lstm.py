@@ -159,6 +159,7 @@ def _lstm_fwd(zx, w_rec, h0, c0, save_residuals):
             hidden=h)
     res = pl.pallas_call(
         kernel,
+        name="fused_lstm_fwd",
         out_shape=out_shape,
         grid=(t,),
         in_specs=[
@@ -240,6 +241,7 @@ def _lstm_bwd_kernel_call(dys, dhT, dcT, gates, c_prev_seq, w_rec):
     rev = lambda i: (t - 1 - i, 0, 0)  # noqa: E731 — reverse-time index map
     ds, dh0, dc0 = pl.pallas_call(
         functools.partial(_bwd_kernel, hidden=h),
+        name="fused_lstm_bwd",
         out_shape=[
             jax.ShapeDtypeStruct((t, b, h4), dtype),
             jax.ShapeDtypeStruct((b, h), dtype),

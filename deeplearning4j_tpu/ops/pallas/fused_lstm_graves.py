@@ -145,6 +145,7 @@ def _graves_fwd(zx, w_rec, peep, h0, c0, mask, save_residuals):
             hidden=h)
     res = pl.pallas_call(
         kernel,
+        name="fused_lstm_graves_fwd",
         out_shape=out_shape,
         grid=(t,),
         in_specs=[
@@ -241,6 +242,7 @@ def _graves_bwd_kernel_call(dys, dhT, dcT, gates, c_prev_seq, mask, w_rec,
     rev3 = lambda i: (t - 1 - i, 0, 0)  # noqa: E731
     ds, dh0, dc0 = pl.pallas_call(
         functools.partial(_bwd_kernel, hidden=h),
+        name="fused_lstm_graves_bwd",
         out_shape=[
             jax.ShapeDtypeStruct((t, b, h4), dtype),
             jax.ShapeDtypeStruct((b, h), dtype),

@@ -175,11 +175,10 @@ class ParallelWrapper:
         x, y, fm, lm = shard_batch(self.strategy, x, y, fm, lm)
         return (x, y, fm, lm), x.shape[0]
 
-    def _insert_rng(self, args):
-        """Step args with the NEXT rng key spliced in at dispatch time —
-        key order (and so the trajectory) follows submission order, never
-        prefetch completion order."""
-        rng = self.model.rng.next_key()
+    def _insert_rng(self, args, rng):
+        """Step args with the NEXT rng key (drawn at dispatch time) spliced
+        in — key order (and so the trajectory) follows submission order,
+        never prefetch completion order."""
         if hasattr(self.model, "_coerce_batch"):  # (inputs, labels, rng, masks)
             return (args[0], args[1], rng, args[2])
         return (args[0], args[1], rng, args[2], args[3])
@@ -248,7 +247,8 @@ class ParallelWrapper:
                                        self.prefetch_buffer, profiler)
                     try:
                         for args, n in src:
-                            args = self._insert_rng(args)
+                            args = self._insert_rng(args,
+                                                    model.rng.next_key())
                             if args[3] is not None:
                                 raise NotImplementedError(
                                     "feature masks are not supported under "
@@ -290,7 +290,8 @@ class ParallelWrapper:
         from deeplearning4j_tpu.train.prefetch import (AsyncLossDelivery,
                                                        batch_source,
                                                        stateless_listeners)
-        from deeplearning4j_tpu.train.profiler import submit_timed
+        from deeplearning4j_tpu.train.profiler import (drain_timed,
+                                                        submit_timed)
         self._ensure_sharded()
         model = self.model
         step_fn = model._jitted("train_step", model._make_train_step)
@@ -352,12 +353,13 @@ class ParallelWrapper:
                                        self.prefetch_buffer, profiler)
                     try:
                         for args, n in src:
-                            submit_timed(gd, (self._insert_rng(args), n),
-                                         profiler)
+                            submit_timed(
+                                gd, model.rng,
+                                lambda key: (self._insert_rng(args, key), n),
+                                profiler)
                     finally:
                         src.close()
-                    gd.flush()
-                    drain()  # on_epoch_end must observe every iteration
+                    drain_timed(gd, drain, profiler)
                     for lst in model._listeners:
                         lst.on_epoch_end(model, model._epoch)
                     model._epoch += 1

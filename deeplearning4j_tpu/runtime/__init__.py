@@ -30,7 +30,8 @@ from deeplearning4j_tpu.runtime.rng import RngManager, get_default_rng, set_defa
 from deeplearning4j_tpu.runtime.profiler import OpProfiler, ProfilerConfig
 # the jax device-trace context manager keeps its old spelling as
 # runtime.profiler.trace; the package-level name `trace` now names the
-# distributed-tracing module (ISSUE 9), re-exported here as device_trace
+# distributed-tracing module (ISSUE 9), re-exported here as device_trace:
+# the entry to the scope table (docs/observability.md "Training")
 from deeplearning4j_tpu.runtime.profiler import trace as device_trace
 from deeplearning4j_tpu.runtime import trace
 # the fleet event journal (ISSUE 15): the black box every control seam

@@ -306,6 +306,12 @@ class AotCache:
             self._entries.pop(k, None)
         return len(dead)
 
+    def hlo_texts(self):
+        """The optimized HLO of every cached executable: where
+        ``runtime.profiler.scope_times`` finds each instruction's
+        ``op_name``."""
+        return [entry.as_text() for entry in self._entries.values()]
+
     def call(self, key: Hashable, jitted, *args):
         if not aot_enabled():
             return jitted(*args)

@@ -196,7 +196,7 @@ def make_unrolled_packed_step(raw_step, packer, k: int):
     dispatch per array per group (the very overhead grouping removes).
     Shared by MultiLayerNetwork and ComputationGraph (both raw steps take
     ``(train_state, *step_args)`` and return ``(new_state, loss)``)."""
-    def unrolled(pts, args_list):
+    def unrolled_packed_train_steps(pts, args_list):
         ts = packer.unpack(pts)
         losses = []
         for i in range(k):
@@ -204,7 +204,7 @@ def make_unrolled_packed_step(raw_step, packer, k: int):
             losses.append(loss)
         return packer.pack(ts), jnp.stack(losses)
 
-    return jax.jit(unrolled, donate_argnums=(0,))
+    return jax.jit(unrolled_packed_train_steps, donate_argnums=(0,))
 
 
 def make_unrolled_step(raw_step, k: int):
@@ -214,14 +214,14 @@ def make_unrolled_step(raw_step, k: int):
     flat buffer would force a common sharding across leaves, see module
     docstring). Used by ``ParallelWrapper`` to honor
     ``env.dispatch_unroll`` on a mesh; state donated, losses stacked."""
-    def unrolled(ts, args_list):
+    def unrolled_train_steps(ts, args_list):
         losses = []
         for i in range(k):
             ts, loss = raw_step(ts, *args_list[i])
             losses.append(loss)
         return ts, jnp.stack(losses)
 
-    return jax.jit(unrolled, donate_argnums=(0,))
+    return jax.jit(unrolled_train_steps, donate_argnums=(0,))
 
 
 class GroupedDispatch:

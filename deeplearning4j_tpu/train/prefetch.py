@@ -99,16 +99,28 @@ class _SyncBatchSource:
         # and skip wrappers iterate from their current position)
         self._iterator.reset()
         it = iter(self._iterator)
+        prof = self._profiler
         while True:
-            t0 = time.perf_counter() if self._profiler else 0.0
+            if prof is None:
+                try:
+                    ds = next(it)
+                except StopIteration:
+                    return
+                chaos.inject(FETCH_POINT)
+                yield self._prepare(ds)
+                continue
+            fetched = prof.stage("next_batch")
             try:
-                ds = next(it)
+                with fetched:
+                    ds = next(it)
+                    chaos.inject(FETCH_POINT)
             except StopIteration:
+                # the probe that found the end: waited for, not a batch
+                prof.record_data_wait(fetched.seconds, counted=False)
                 return
-            chaos.inject(FETCH_POINT)
-            item = self._prepare(ds)
-            if self._profiler:
-                self._profiler.record_data_wait(time.perf_counter() - t0)
+            with prof.stage("h2d") as copied:
+                item = self._prepare(ds)
+            prof.record_data_wait(fetched.seconds + copied.seconds)
             yield item
 
     def close(self) -> None:
@@ -159,8 +171,12 @@ class DevicePrefetcher:
                 if self._stop.is_set():
                     return
                 chaos.inject(FETCH_POINT)
-                if not stop_aware_put(self._queue, self._prepare(ds),
-                                      self._stop):
+                if self._profiler is None:
+                    item = self._prepare(ds)
+                else:  # off the fit thread: in h2d's total, not in data_wait
+                    with self._profiler.stage("h2d"):
+                        item = self._prepare(ds)
+                if not stop_aware_put(self._queue, item, self._stop):
                     return
         except BaseException as e:  # surfaced on the consumer side
             self._error = e
@@ -179,10 +195,12 @@ class DevicePrefetcher:
             # staged behind it — the fit fails at the fault, not after
             # training the tail of the buffer
             self._raise_pending()
-            t0 = time.perf_counter() if self._profiler else 0.0
-            item = self._queue.get()
-            if self._profiler:
-                self._profiler.record_data_wait(time.perf_counter() - t0)
+            if self._profiler is None:
+                item = self._queue.get()
+            else:
+                with self._profiler.stage("next_batch") as waited:
+                    item = self._queue.get()
+                self._profiler.record_data_wait(waited.seconds)
             if item is _DONE:
                 self._raise_pending()
                 return
@@ -257,8 +275,8 @@ class AsyncLossDelivery:
                     continue  # keep draining so submit() can't deadlock
                 try:
                     if self._profiler is not None:
-                        jax.block_until_ready(loss)
-                        self._profiler.record_step(time.perf_counter() - t0)
+                        with self._profiler.stage("step", started=t0):
+                            jax.block_until_ready(loss)
                     self._deliver(args, loss)
                 except BaseException as e:
                     self._error = e

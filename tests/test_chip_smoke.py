@@ -69,6 +69,9 @@ def test_checks_rehearse_at_a_tiny_preset(tmp_path, monkeypatch):
         rnn_t=8, rnn_b=8, rnn_h=128, rnn_vocab=20, rnn_steps=3,
         attn_shape=(1, 1, 128, 64), attn_long=(1, 1, 512, 64))
     report = {"phases": {}}
+    # a fallback an earlier test of this process left behind is not this
+    # run's: the smoke reads the counter's change over its own run
+    compile_cache.STATS.record("aot_fallbacks")
     try:
         chip_smoke.run(preset, report, str(tmp_path))
     finally:

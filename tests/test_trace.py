@@ -670,3 +670,201 @@ def test_hedged_sigkill_drill_yields_one_merged_trace(tmp_path):
             sup.check()
         finally:
             router.stop()
+
+
+# ----------------------------------------------------------------- ISSUE 26
+# Scopes in the step program and their reduction (runtime/profiler.py). The
+# request tracer above is untouched; these sit here because this is the
+# tracing file.
+
+def _lowered_names(lowered):
+    """Every ``op_name``-like location of a lowered program."""
+    return lowered.as_text(debug_info=True)
+
+
+def _mln_step_text(net, x, y, fm=None):
+    import jax
+    step = jax.jit(net._train_step_fn())
+    return _lowered_names(step.lower(net.train_state, x, y,
+                                     net.rng.next_key(), fm, None))
+
+
+def test_scopes_name_layers_loss_and_updater_in_the_mln_step():
+    from deeplearning4j_tpu.train import Sgd
+    conf = (NeuralNetConfiguration.builder().seed(3).updater(Sgd(0.1)).list()
+            .layer(DenseLayer(n_out=16, activation="tanh"))
+            .layer(OutputLayer(n_out=4, activation="softmax"))
+            .set_input_type(InputType.feed_forward(8)).build())
+    net = MultiLayerNetwork(conf).init()
+    y = np.eye(4, dtype=np.float32)[RNG.integers(0, 4, 16)]
+    text = _mln_step_text(net, X, y)
+    # the output layer's activation is dead code in a training step: its
+    # work is the loss's
+    for scope in ("jvp(layer_0.DenseLayer)", "transpose(jvp(layer_0.DenseLayer))",
+                  "jvp(loss)", "transpose(jvp(loss))", "updater"):
+        assert scope in text, scope
+    assert net._train_step_fn().__name__ == "mln_train_step"
+    assert net._jitted_packed()[0].__name__ == "packed_train_step"
+
+
+def test_scopes_name_nodes_loss_and_updater_in_the_graph_step():
+    import jax
+    from deeplearning4j_tpu.models import ComputationGraph
+    from deeplearning4j_tpu.nn.graph_vertices import ElementWiseVertex
+    from deeplearning4j_tpu.train import Sgd
+    g = (NeuralNetConfiguration.builder().seed(3).updater(Sgd(0.1))
+         .graph_builder().add_inputs("in"))
+    g.add_layer("d1", DenseLayer(n_out=8, activation="relu"), "in")
+    g.add_vertex("add", ElementWiseVertex(op="add"), "in", "d1")
+    g.add_layer("out", OutputLayer(n_out=4, activation="softmax", loss="mcxent"), "add")
+    g.set_outputs("out")
+    g.set_input_types(InputType.feed_forward(8))
+    net = ComputationGraph(g.build()).init()
+    y = np.eye(4, dtype=np.float32)[RNG.integers(0, 4, 16)]
+    text = _lowered_names(jax.jit(net._train_step_fn()).lower(
+        net.train_state, {"in": X}, [y], net.rng.next_key(), None))
+    for scope in ("jvp(d1.DenseLayer)", "jvp(add.ElementWiseVertex)",
+                  "transpose(jvp(d1.DenseLayer))", "jvp(loss)", "updater"):
+        assert scope in text, scope
+
+
+def test_attention_sub_scopes_in_a_one_block_transformer():
+    from deeplearning4j_tpu.zoo import Bert
+    net = Bert(vocab_size=50, d_model=16, n_layers=1, n_heads=2, ffn_size=32,
+               max_len=8, dropout_rate=0.0).init()
+    ids = RNG.integers(0, 50, (2, 8)).astype(np.int32)
+    y = np.eye(2, dtype=np.float32)[[0, 1]]
+    text = _mln_step_text(net, ids, y, np.ones((2, 8), np.float32))
+    block = "jvp(layer_1.TransformerEncoderBlock)/"
+    for sub in ("qkv", "scores", "softmax", "context", "out_proj", "ln1", "ffn", "ln2"):
+        assert block + sub + "/" in text, sub
+    for sub in ("embed", "ln"):
+        assert f"jvp(layer_0.BertEmbeddingLayer)/{sub}/" in text, sub
+    assert "transpose(jvp(layer_1.TransformerEncoderBlock))/scores/" in text
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(s)/jvp(layer_3.Block)/qkv/dot_general", ("forward", ("layer_3.Block", "qkv"))),
+    ("jit(s)/transpose(jvp(layer_3.Block))/scores/bhqd,bhkd->bhqk/dot_general",
+     ("backward", ("layer_3.Block", "scores", "bhqd,bhkd->bhqk"))),
+    ("jit(s)/jvp(layer_0.Embed)/embed/jit(_take)/gather", ("forward", ("layer_0.Embed", "embed"))),
+    ("jit(s)/updater/mul", ("optimizer", ("updater",))),
+    ("jit(s)/transpose(jvp(loss))/mul", ("backward", ("loss",))),
+    ("jit(s)/layer_1.Dense/dot_general", ("forward", ("layer_1.Dense",))),  # inference
+    ("jit(s)/jvp()/convert_element_type", None),   # the cast before the layers
+    ("jit(s)/slice", None),                        # the packer's unpack
+    ("reduce_sum", None),
+])
+def test_classify_op_name(op_name, want):
+    from deeplearning4j_tpu.runtime import profiler
+    assert profiler.classify_op_name(op_name) == want
+
+
+_HLO = """HloModule jit_packed_train_step, is_scheduled=true
+
+%fused_computation.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  ROOT %inner.1 = f32[4]{0} add(%p, %p), metadata={op_name="jit(s)/updater/add"}
+}
+
+ENTRY %main.9 (a: f32[4]) -> f32[4] {
+  %a = f32[4]{0} parameter(0)
+  %slice.1 = f32[2]{0} slice(%a), slice={[0:2]}, metadata={op_name="jit(s)/slice"}
+  %fusion.1 = f32[4]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(s)/jvp(layer_0.Embed)/embed/add"}
+  %fusion.2 = f32[4]{0} fusion(%a), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(s)/jvp(layer_1.Block)/qkv/dot_general"}
+  %fusion.3 = f32[4]{0} fusion(%a), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(s)/transpose(jvp(layer_1.Block))/qkv/dot_general"}
+  %fusion.4 = f32[4]{0} fusion(%a), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(s)/transpose(jvp(layer_2.Block))/qkv/dot_general"}
+  %copy-done.7 = f32[4]{0} copy-done(%a)
+  ROOT %fusion.5 = f32[4]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(s)/updater/add"}
+}
+"""
+
+
+def _scope_planes(names=("slice.1", "fusion.1", "fusion.2", "fusion.3", "fusion.4",
+                         "copy-done.7", "fusion.5")):
+    """Two whole runs of a 100 us step program (ops of 2, 10, 20, 30, 5, 3
+    and 8 us, 22 us of gaps), one tiny other program between them, and one
+    op of the step program's names outside any run."""
+    us = 1000
+    durations = (2, 10, 20, 30, 5, 3, 8)
+    ops, modules = [], []
+    for base in (0, 200 * us):
+        modules.append(("jit_packed_train_step(7)", base, 100 * us))
+        at = base + 1 * us
+        for name, d in zip(names, durations):
+            ops.append((f"%{name} = f32[4]{{0}} fusion(f32[4]{{0}} %a), kind=kLoop", at, d * us))
+            at += (d + 3) * us
+    modules.append(("jit_tiny(2)", 120 * us, 4 * us))
+    ops.append(("%fusion.1 = f32[1]{0} fusion()", 120 * us, 4 * us))
+    return [("/device:TPU:0", [("XLA Modules", modules), ("XLA Ops", ops), ("Steps", [])]),
+            ("/host:CPU", [("python", [("fit.dispatch", 0, 5)])])]
+
+
+def test_scope_times_by_hand():
+    from deeplearning4j_tpu.runtime import profiler
+    table = profiler.scope_times(_scope_planes(), ["HloModule other\n", _HLO], depth=2)
+    assert table["program"] == "jit_packed_train_step(7)" and table["runs"] == 2
+    assert table["step_s"] == pytest.approx(100e-6)
+    assert table["scopes"]["forward"] == pytest.approx(
+        {"layer_1.Block/qkv": 20e-6, "layer_0.Embed/embed": 10e-6})
+    assert table["scopes"]["backward"] == pytest.approx(
+        {"layer_1.Block/qkv": 30e-6, "layer_2.Block/qkv": 5e-6})
+    assert table["scopes"]["optimizer"] == pytest.approx({"updater": 8e-6})
+    assert table["unattributed"] == pytest.approx({"copy-done": 3e-6, "slice [slice]": 2e-6})
+    assert table["phases"] == pytest.approx(
+        {"forward": 30e-6, "backward": 35e-6, "optimizer": 8e-6, "other": 27e-6})
+    assert sum(table["phases"].values()) == pytest.approx(table["step_s"])
+    assert table["attributed_fraction"] == pytest.approx(0.73)
+    # the depth cut and the layers of one class added up
+    assert profiler.scope_times(_scope_planes(), [_HLO], depth=1)["scopes"]["backward"] == \
+        pytest.approx({"layer_1.Block": 30e-6, "layer_2.Block": 5e-6})
+    merged = profiler.scope_times(_scope_planes(), [_HLO], depth=2, merge_layers=True)
+    assert merged["scopes"]["backward"] == pytest.approx({"Block/qkv": 35e-6})
+    text = profiler.format_scope_times(merged)
+    assert "Block/qkv" in text and "unattributed copy-done" in text
+
+
+def test_scope_times_raises_on_a_scope_less_step_program():
+    """An executable out of a cache written before the scopes existed has
+    every instruction and no scope: a raise naming the cause, never an
+    empty table. The same for a CPU trace, which has no device plane."""
+    import re
+    from deeplearning4j_tpu.runtime import profiler
+    stale = re.sub(r'op_name="jit\(s\)/[^"]*/(\w+)"', r'op_name="jit(s)/\1"', _HLO)
+    with pytest.raises(RuntimeError, match="cleared cache"):
+        profiler.scope_times(_scope_planes(), [stale])
+    with pytest.raises(RuntimeError, match="0 of 7 instruction names"):
+        profiler.scope_times(_scope_planes(), ["HloModule other\n"])
+    with pytest.raises(RuntimeError, match="no device plane"):
+        profiler.scope_times(_scope_planes()[1:], [_HLO])
+
+
+def test_device_trace_yields_the_reduction_and_a_cpu_trace_raises(tmp_path):
+    """``profiler.trace`` is the operator's entry: it yields the object
+    that owns ``scope_times``; the fit stages land on the host plane of its
+    session as ``fit.*`` annotations, dispatch with its ``step_num``."""
+    from jax.profiler import ProfileData
+    from deeplearning4j_tpu.data import NumpyDataSetIterator
+    from deeplearning4j_tpu.runtime import profiler
+    from deeplearning4j_tpu.train import Sgd, TrainingProfiler
+    conf = (NeuralNetConfiguration.builder().seed(3).updater(Sgd(0.1)).list()
+            .layer(DenseLayer(n_out=16, activation="tanh"))
+            .layer(OutputLayer(n_out=4, activation="softmax"))
+            .set_input_type(InputType.feed_forward(8)).build())
+    net = MultiLayerNetwork(conf).init()
+    y = np.eye(4, dtype=np.float32)[RNG.integers(0, 4, 16)]
+    with profiler.trace(str(tmp_path)) as t:
+        net.fit(NumpyDataSetIterator(X, y, batch_size=4), profiler=TrainingProfiler())
+    assert any("op_name=" in text for text in net._jit_cache["__aot__"].hlo_texts())
+    with pytest.raises(RuntimeError, match="no device plane"):
+        t.scope_times(net)
+    path = next(tmp_path.glob("**/*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("fit."):
+                    host.setdefault(e.name, []).append(dict(e.stats))
+    assert {"fit.next_batch", "fit.h2d", "fit.rng", "fit.dispatch", "fit.drain",
+            "fit.sync", "fit.step"} <= set(host)
+    assert sorted(int(s["step_num"]) for s in host["fit.dispatch"]) == [0, 1, 2, 3]

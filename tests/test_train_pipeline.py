@@ -369,3 +369,112 @@ def test_async_iterator_close_is_idempotent_and_restartable():
         n += 1
     assert n == 8
     ait.close()
+
+
+# ----------------------------------------------------------------- ISSUE 26
+# Fit stages that tile the loop (train/profiler.py ``stage``).
+
+def _wide_conf(seed=7):
+    """Steps heavy enough (a few ms on the CPU) that the loop's own
+    bookkeeping between stages is small beside them."""
+    return (NeuralNetConfiguration.builder().seed(seed).updater(Sgd(0.1)).list()
+            .layer(DenseLayer(n_out=1024, activation="tanh"))
+            .layer(DenseLayer(n_out=1024, activation="tanh"))
+            .layer(OutputLayer(n_out=4, activation="softmax"))
+            .set_input_type(InputType.feed_forward(8)).build())
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_stages_tile_the_fit_loop(prefetch):
+    x, y = _data(512)
+    net = MultiLayerNetwork(_wide_conf()).init()
+    net.fit(NumpyDataSetIterator(x, y, batch_size=64), epochs=1)  # compiles
+    prof = TrainingProfiler()
+    net.fit(NumpyDataSetIterator(x, y, batch_size=64), epochs=2,
+            prefetch_buffer=prefetch, profiler=prof)
+    r = prof.report()
+    steps = 16
+    assert r["iterations"] == steps
+    with prof._lock:
+        counts, totals = dict(prof._counts), dict(prof._totals)
+    assert counts["dispatch"] == counts["rng"] == counts["h2d"] == counts["step"] == steps
+    assert counts["drain"] == 2 and counts["sync"] == 1
+    if prefetch:
+        # the queue wait alone; the copy runs on the prefetch worker
+        assert totals["data_wait"] == pytest.approx(totals["next_batch"])
+        assert counts["next_batch"] == steps + 2  # and each epoch's end
+    else:
+        assert totals["data_wait"] == pytest.approx(totals["next_batch"] + totals["h2d"])
+        assert counts["next_batch"] == counts["data_wait"] == steps
+    tiled = sum(totals[s] for s in TrainingProfiler.TILE)
+    assert r["fit_total_s"] == r["elapsed_s"] > 0
+    assert r["unattributed_s"] == pytest.approx(r["fit_total_s"] - tiled, abs=2e-4)
+    assert 0.0 <= r["unattributed_fraction"] < 0.25
+    assert r["aot_compiles"] == 0 and r["aot_fallbacks"] == 0 and r["compile_in_fit_s"] == 0
+    for stage in ("next_batch", "h2d", "rng", "drain", "sync"):
+        assert {f"{stage}_total_s", f"{stage}_mean_ms", f"{stage}_p99_ms"} <= set(r)
+
+
+def test_profiler_reports_compiles_inside_fit():
+    """``aot_compiles`` / ``compile_in_fit_s`` are the change of
+    ``compile_cache.stats()`` over the fit call: a first fit compiles."""
+    x, y = _data(32)
+    prof = TrainingProfiler()
+    MultiLayerNetwork(_conf()).init().fit(
+        NumpyDataSetIterator(x, y, batch_size=16), profiler=prof)
+    r = prof.report()
+    assert r["aot_compiles"] == 1 and r["aot_fallbacks"] == 0
+    assert r["compile_in_fit_s"] > 0
+
+
+def test_without_a_profiler_no_stage_is_entered(monkeypatch):
+    """``profiler=None`` costs one ``is None`` branch a site: no stage, no
+    annotation."""
+    import jax
+    from deeplearning4j_tpu.models import ComputationGraph
+    from deeplearning4j_tpu.train import profiler as train_profiler
+
+    def boom(*a, **k):
+        raise AssertionError("a stage was entered without a profiler")
+
+    monkeypatch.setattr(train_profiler.TrainingProfiler, "stage", boom)
+    monkeypatch.setattr(train_profiler, "_Stage", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", boom)
+    x, y = _data(32)
+    it = lambda: NumpyDataSetIterator(x, y, batch_size=16)
+    MultiLayerNetwork(_conf()).init().fit(it(), epochs=1)
+    MultiLayerNetwork(_conf()).init().fit(it(), epochs=1, prefetch_buffer=2)
+    ParallelWrapper.builder(MultiLayerNetwork(_conf()).init()).build().fit(it())
+    g = (NeuralNetConfiguration.builder().seed(1).updater(Sgd(0.1))
+         .graph_builder().add_inputs("in"))
+    g.add_layer("d", DenseLayer(n_out=8, activation="tanh"), "in")
+    g.add_layer("out", OutputLayer(n_out=4, activation="softmax"), "d")
+    g.set_outputs("out")
+    g.set_input_types(InputType.feed_forward(8))
+    ComputationGraph(g.build()).init().fit(it())
+
+
+def _reader(name):
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parent.parent / "benchmark" / "readers" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location("reader_" + name.replace(".", "_"), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+@pytest.mark.parametrize("name,report,want", [
+    ("fit_unattributed_share.train", {"iterations": 232, "unattributed_fraction": 0.0123}, 1.23),
+    ("h2d_ms.train", {"iterations": 232, "h2d_mean_ms": 0.31}, 0.31),
+    ("fit_unattributed_share.train", {"iterations": 0, "unattributed_fraction": 0.0}, None),
+    ("h2d_ms.train", {"iterations": 0, "h2d_mean_ms": 0.0}, None),
+    # the parent's report has no such key: nothing to read, no raise
+    ("fit_unattributed_share.train", {"iterations": 232, "data_wait_fraction": 0.01}, None),
+    ("h2d_ms.train", {"iterations": 232, "dispatch_mean_ms": 1.4}, None),
+    ("h2d_ms.train", None, None),
+])
+def test_stage_readers_of_the_benchmark(name, report, want):
+    got = _reader(name)({"profiler": report}, None, None, None)
+    assert got == (None if want is None else pytest.approx(want))
