@@ -457,45 +457,6 @@ def verify_kernels():
     _log(f"[kernels] fused GRU: fwd_err={err_f:.2e} bwd_err={err_b:.2e} "
          f"grad speedup {sp:.2f}x ±{spread:.2f}")
 
-    # ---- short-T fused attention (opt-in; verify correctness on-device) ----
-    from deeplearning4j_tpu.ops.pallas.fused_attention_short import (
-        short_attention, short_attention_compatible)
-    Bs, Hs, Ts, Ds = 64, 12, 128, 64
-    qs = jnp.asarray(rng.normal(0, 1, (Bs, Hs, Ts, Ds)), jnp.bfloat16)
-    ks_ = jnp.asarray(rng.normal(0, 1, (Bs, Hs, Ts, Ds)), jnp.bfloat16)
-    vs = jnp.asarray(rng.normal(0, 1, (Bs, Hs, Ts, Ds)), jnp.bfloat16)
-    assert short_attention_compatible(qs, ks_, vs)
-
-    def xla_short(q, k, v):
-        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                       k.astype(jnp.float32)) / np.sqrt(Ds)
-        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
-                          v.astype(jnp.float32)).astype(q.dtype)
-
-    yk = jax.jit(lambda q, k, v: short_attention(q, k, v))(qs, ks_, vs)
-    yx = jax.jit(xla_short)(qs, ks_, vs)
-    err_f = float(jnp.max(jnp.abs(yk.astype(jnp.float32)
-                                  - yx.astype(jnp.float32))))
-    gk2 = jax.jit(jax.grad(lambda q: jnp.sum(
-        short_attention(q, ks_, vs).astype(jnp.float32) ** 2)))
-    gx2 = jax.jit(jax.grad(lambda q: jnp.sum(
-        xla_short(q, ks_, vs).astype(jnp.float32) ** 2)))
-    dk2, dx2 = gk2(qs), gx2(qs)
-    gscale = float(jnp.max(jnp.abs(dx2.astype(jnp.float32))))
-    err_b = float(jnp.max(jnp.abs(dk2.astype(jnp.float32)
-                                  - dx2.astype(jnp.float32))))
-    assert err_f <= 0.05, f"short attention fwd mismatch: {err_f}"
-    assert err_b <= 0.05 * max(gscale, 1.0), \
-        f"short attention bwd mismatch: {err_b}"
-    sp, spread, tk, tx = ab_speedup(lambda: gk2(qs), lambda: gx2(qs))
-    out["short_attn_fwd_max_err"] = err_f
-    out["short_attn_bwd_max_err"] = err_b
-    out["short_attn_isolated_speedup_vs_xla"] = round(sp, 3)
-    out["short_attn_speedup_spread"] = round(spread, 3)
-    _log(f"[kernels] short-T attention (opt-in): fwd_err={err_f:.4f} "
-         f"bwd_err={err_b:.4f} isolated grad speedup {sp:.2f}x ±{spread:.2f} "
-         f"(NOT auto-routed: in-model pallas boundary cost exceeds the win)")
-
     # ---- fused dropout (opt-in; mask statistics + fwd/bwd consistency) ----
     from deeplearning4j_tpu.ops.pallas.fused_dropout import (
         fused_dropout, fused_dropout_compatible, seed_from_key)

@@ -14,7 +14,10 @@ vocab 30522; batch 64 x seq 128, bf16 compute, library defaults otherwise):
 1. *kernels*: ``fused_lstm`` / ``fused_lstm_graves`` / ``fused_gru`` fwd+bwd
    at (T 256, B 64, H 512) against an XLA scan, and
    ``dot_product_attention`` fwd+bwd at T=2048 (padding mask; causal) and
-   causal T=16384 (the chunked backward) against its own XLA softmax path —
+   causal T=16384 (the chunked backward) against its own XLA softmax path,
+   and ``fused_attention`` fwd+bwd at the shape ``SelfAttentionLayer``
+   routes to it (T=512, 12 heads x 64, ragged key-padding mask, bf16;
+   2 Mosaic calls a block) against the same XLA form on transposed operands —
    at the tolerances ``bench.verify_kernels`` uses, no timing. Each compiled
    program must contain a Mosaic custom call: the kernel was compiled, not
    routed around.
@@ -95,6 +98,8 @@ class Preset:
     # attention: (batch, heads, T, d) resident-backward shape + long T
     attn_shape: tuple = (4, 8, 2048, 64)
     attn_long: tuple = (1, 1, 16384, 64)
+    # the resident fused kernel's routed shape: (batch, T, heads, d)
+    attn_fused: tuple = (8, 512, 12, 64)
 
 
 # ------------------------------------------------------------------ helpers
@@ -240,6 +245,8 @@ def kernel_checks(p: Preset):
     from deeplearning4j_tpu.nn.attention_layers import dot_product_attention
     from deeplearning4j_tpu.ops.pallas.flash_attention import (
         BWD_CHUNK_THRESHOLD, flash_attention_compatible)
+    from deeplearning4j_tpu.ops.pallas.fused_attention import (
+        fused_attention, fused_attention_compatible)
     from deeplearning4j_tpu.ops.pallas.fused_gru import (fused_gru,
                                                          fused_gru_compatible)
     from deeplearning4j_tpu.ops.pallas.fused_lstm import (
@@ -319,6 +326,32 @@ def kernel_checks(p: Preset):
                 p, tol, tol, calls=3)
         return check
 
+    def fused():
+        # the shape SelfAttentionLayer routes: operands as the projections
+        # write them, ragged key-padding mask; the XLA softmax form on
+        # transposed f32 copies is the reference. One forward and one
+        # backward kernel: 2 Mosaic calls a block.
+        rng = np.random.default_rng(0)
+        b, t, h, d = p.attn_fused
+        q, k, v = (jnp.asarray(rng.normal(0, 1, (b, t, h * d)), jnp.bfloat16)
+                   for _ in range(3))
+        mask = jnp.asarray(np.arange(t)[None, :]
+                           < rng.integers(t // 4, t + 1, b)[:, None])
+        assert fused_attention_compatible(q, mask, heads=h), \
+            "fused_attention ineligible"
+
+        def xla(q, k, v):
+            split = lambda x: x.reshape(b, t, h, d).transpose(0, 2, 1, 3)  # noqa: E731
+            y = dot_product_attention(split(q), split(k), split(v), mask,
+                                      use_flash=False)
+            return y.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+        return _compare(
+            "fused_attention",
+            lambda q, k, v: fused_attention(q, k, v, mask, h), xla, (q, k, v),
+            tuple(x.astype(jnp.float32) for x in (q, k, v)), (0, 1, 2),
+            p, flash_tol, flash_tol, calls=2)
+
     return [
         ("fused_lstm", lstm),
         ("fused_lstm_graves", graves),
@@ -334,6 +367,7 @@ def kernel_checks(p: Preset):
         ("flash_causal_chunked",
          flash("flash_causal_chunked", p.attn_long, True, False, long_tol,
                chunked=True)),
+        ("fused_attention", fused),
     ]
 
 

@@ -49,11 +49,6 @@ def dot_product_attention(q, k, v, mask=None, use_flash: bool = True,
             # one kernel does scores, softmax and context: one scope
             with jax.named_scope("flash"):
                 return flash_attention(q, k, v, mask, causal=causal)
-        # The short-T fused kernel (ops.pallas.fused_attention_short) is
-        # never routed here: in isolation it measured parity with XLA
-        # (0.98-1.01x) and in-model a net loss on v5e (38 -> 51 ms/step
-        # for BERT-base) — each pallas_call boundary in the big traced
-        # step costs ~0.5-0.7 ms of lost fusion/async overlap, x24 calls.
     d = q.shape[-1]
     with jax.named_scope("scores"):
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(jnp.asarray(d, q.dtype))
@@ -109,8 +104,29 @@ class SelfAttentionLayer(Layer):
         return params, {}
 
     def forward(self, params, state, x, *, training=False, rng=None, mask=None):
+        from deeplearning4j_tpu.ops.pallas.fused_attention import (
+            fused_attention, fused_attention_compatible)
         b, t, _ = x.shape
         h = self.n_heads
+        # A sequence that fits one block (T <= 512) goes to the resident
+        # fused kernel as the projections write it and W_o reads it:
+        # decided here, from shapes, dtype and mask form, before anything
+        # is split into (b, h, t, d), so that no head transpose is emitted
+        inner = jax.ShapeDtypeStruct(
+            (b, t, params["W_q"].shape[1]),
+            jnp.result_type(x.dtype, params["W_q"].dtype,
+                            params["b_q"].dtype))
+        if fused_attention_compatible(inner, mask, heads=h):
+            with jax.named_scope("qkv"):
+                q = x @ params["W_q"] + params["b_q"]
+                k = x @ params["W_k"] + params["b_k"]
+                v = x @ params["W_v"] + params["b_v"]
+            with jax.named_scope("fused_attention"):
+                y = fused_attention(q, k, v, mask, h)
+            with jax.named_scope("out_proj"):
+                if self.with_projection:
+                    y = y @ params["W_o"] + params["b_o"]
+            return y, state
         # NOTE on fused QKV: concatenating W_q|W_k|W_v into one matmul was
         # measured SLOWER on v5e (43.7 GB vs 40.5 GB accessed, 40.4 vs
         # 39.1 ms/step on BERT-base) — the fused weight and its gradient

@@ -67,7 +67,8 @@ def test_checks_rehearse_at_a_tiny_preset(tmp_path, monkeypatch):
         batch=8, seq=16, train_batches=4, train_epochs=2,
         serve_rows=(1,), max_batch_size=1,
         rnn_t=8, rnn_b=8, rnn_h=128, rnn_vocab=20, rnn_steps=3,
-        attn_shape=(1, 1, 128, 64), attn_long=(1, 1, 512, 64))
+        attn_shape=(1, 1, 128, 64), attn_long=(1, 1, 512, 64),
+        attn_fused=(2, 128, 2, 64))
     report = {"phases": {}}
     # a fallback an earlier test of this process left behind is not this
     # run's: the smoke reads the counter's change over its own run
@@ -81,7 +82,7 @@ def test_checks_rehearse_at_a_tiny_preset(tmp_path, monkeypatch):
                                      "bert_serve", "four_chips"}
     assert set(report["kernels"]) == {
         "fused_lstm", "fused_lstm_graves", "fused_gru", "flash_padding_mask",
-        "flash_causal", "flash_causal_chunked"}
+        "flash_causal", "flash_causal_chunked", "fused_attention"}
     assert report["bert_serve"]["replicas"] == 8
     assert all(n > 0 for n in report["bert_serve"]["replica_batches"])
     assert report["compile_cache"]["aot_fallbacks"] == 0
