@@ -105,6 +105,29 @@ def least_bytes_per_step(config: dict, traffic: dict) -> float:
     return 2.0 * state + batch
 
 
+def attention_kernel_flops(config: dict, traffic: dict) -> dict:
+    """FLOPs of one run of each kernel of the program's resident fused
+    attention pair (``ops/pallas/fused_attention.py``: one run a block and a
+    pass), by the kernel's name, from the cell's shapes. Forward: K Q^T and
+    V E, two (T, T, d) matmuls a head, 4 b T^2 D. Backward: the scores once
+    more, dP, dQ, dK and dV, five such matmuls: 2.5 times the forward.
+    Softmax, scaling and masking count nothing."""
+    forward = 4.0 * traffic["batch"] * traffic["seq_len"] ** 2 * config["hidden_size"]
+    return {"fused_attention_fwd": forward, "fused_attention_bwd": 2.5 * forward}
+
+
+def attention_kernel_bytes(config: dict, traffic: dict) -> dict:
+    """Least HBM bytes of one run of each kernel: every (b, T, D) operand
+    read once and every result written once in the compute type (forward q,
+    k, v in and o out; backward q, k, v, o, dO in and dq, dk, dv out), and
+    the key mask once as a float32 (b, T) column. No (T, T) tensor leaves
+    the chip's fast memory."""
+    b, t = traffic["batch"], traffic["seq_len"]
+    operand = b * t * config["hidden_size"] * jnp.dtype(config["precision"]["compute"]).itemsize
+    mask = 4.0 * b * t
+    return {"fused_attention_fwd": 4.0 * operand + mask, "fused_attention_bwd": 8.0 * operand + mask}
+
+
 def _layer_norm(x, gamma, beta, eps):
     mean = jnp.mean(x, -1, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), -1, keepdims=True)

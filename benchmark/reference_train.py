@@ -91,34 +91,79 @@ def _nesterov(opt, params, grads, moments, t):
 OPTIMIZERS = {"adam": (_adam, 2), "nesterov": (_nesterov, 1)}
 
 
-def _norms(tree):
-    return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-                      for x in jax.tree.leaves(tree)])
+def _f32(leaf) -> np.ndarray:
+    """One leaf on the host in float32, from the host or from the device."""
+    return np.asarray(leaf, np.float32)
+
+
+def _norm(x: np.ndarray) -> float:
+    flat = x.ravel().astype(np.float64)
+    return float(np.sqrt(flat @ flat))
 
 
 def leaf_norms(tree) -> np.ndarray:
-    """Euclidean norm of every leaf, in the tree's flattening order."""
-    return np.asarray(jax.device_get(jax.jit(_norms)(tree)), np.float64)
+    """Euclidean norm of every leaf, in the tree's flattening order. The
+    tree may sit on the host or on the device; it is read one leaf at a time
+    and worked on the host, so comparing costs the device nothing."""
+    return np.array([_norm(_f32(x)) for x in jax.tree.leaves(tree)], np.float64)
 
 
 def diff_norms(a, b) -> np.ndarray:
-    """Norm of ``a - b`` leaf by leaf."""
-    return np.asarray(jax.device_get(jax.jit(lambda x, y: _norms(jax.tree.map(
-        lambda u, v: u.astype(jnp.float32) - v.astype(jnp.float32), x, y)))(a, b)), np.float64)
+    """Norm of ``a - b`` leaf by leaf, streamed like ``leaf_norms``."""
+    return np.array([_norm(_f32(x) - _f32(y)) for x, y in
+                     zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True)], np.float64)
+
+
+def _start_copies(leaves):
+    for leaf in leaves:
+        if hasattr(leaf, "copy_to_host_async"):
+            leaf.copy_to_host_async()  # all copies in flight before the first is waited for
+
+
+def to_host(tree):
+    """A copy of ``tree`` on the host that shares nothing with the device's
+    arrays (on the CPU backend ``device_get`` hands out a view, and a buffer
+    with a view on it cannot be donated)."""
+    leaves, treedef = jax.tree.flatten(tree)
+    _start_copies(leaves)
+    return jax.tree.unflatten(treedef, [np.array(leaf) for leaf in leaves])
 
 
 def change(new, old):
-    """``new - old`` leaf by leaf, float32."""
-    return jax.jit(lambda x, y: jax.tree.map(
-        lambda u, v: u.astype(jnp.float32) - v.astype(jnp.float32), x, y))(new, old)
+    """``new - old`` leaf by leaf as float32 host arrays, in the tree of ``new``.
+
+    Where both leaves sit on the device the difference is taken there and
+    only it comes to the host, one leaf at a time: reading the program's own
+    arrays would leave a host copy cached on each of them, and the step that
+    later donates them pays for freeing it (0.4 GB for BERT-base, at the
+    window's first step, when the device has nothing queued: 60-80 ms idle,
+    a step lost; my chip run, PR 28). Otherwise both are read to the host."""
+    leaves, treedef = jax.tree.flatten(new)
+    pairs = list(zip(leaves, jax.tree.leaves(old), strict=True))
+    on_device = [isinstance(u, jax.Array) and isinstance(v, jax.Array) for u, v in pairs]
+    _start_copies(x for pair, there in zip(pairs, on_device) if not there for x in pair)
+    return jax.tree.unflatten(treedef, [
+        np.asarray(u.astype(jnp.float32) - v.astype(jnp.float32)) if there else _f32(u) - _f32(v)
+        for (u, v), there in zip(pairs, on_device)])
 
 
 def follow(loss_fn, params, state, batches, opt, precision="float32", transform=None):
-    """Drive ``loss_fn`` through ``len(batches)`` steps from ``params``.
+    """Drive ``loss_fn`` through ``len(batches)`` steps from ``params``, in place.
 
-    Returns the readings of ``compare``: each step's loss, the first
-    gradient, and the change of (params, state) after the last step. ``transform(step)`` lets a test
-    plant a fault in this side (see tests/yardstick).
+    Returns the readings of ``compare`` as host arrays: each step's loss,
+    the first gradient, and the change of (params, state) after the last
+    step. On the device this holds at its peak the parameters, the moments
+    and one gradient (16 bytes a parameter under Adam) plus one step's
+    activations: ``params`` and ``state`` are **donated** (the caller's
+    arrays are gone when this returns), the start goes to the host before the
+    first step, and each step's gradient leaves the device before the next
+    step runs. The start is copied rather than made again from the seed so
+    that this file needs no family and no second ``init_params`` program.
+
+    ``transform(step)`` wraps the compiled step, ``step(params, state,
+    moments, batch, t) -> (params, state, moments, loss, grads)``, and is
+    called once a step outside ``jit``: a test plants a fault in this side
+    with it, or reads what the device holds between steps (tests/yardstick).
     """
     mm, conv = contractions(precision)
     update, n_moments = OPTIMIZERS[opt["name"]]
@@ -129,18 +174,19 @@ def follow(loss_fn, params, state, batches, opt, precision="float32", transform=
         new_params, moments = update(opt, params, grads, moments, t)
         return new_params, new_state, moments, loss, grads
 
+    step = jax.jit(step, donate_argnums=(0, 1, 2))
     if transform is not None:
         step = transform(step)
-    step = jax.jit(step)
-    p, s = params, state
+    start = to_host((params, state))
     moments = tuple(jax.tree.map(jnp.zeros_like, params) for _ in range(n_moments))
     losses, first = [], None
     for t, batch in enumerate(batches, start=1):
-        p, s, moments, loss, grads = step(p, s, moments, batch, jnp.float32(t))
+        params, state, moments, loss, grads = step(params, state, moments, batch, jnp.float32(t))
         losses.append(float(loss))
-        first = grads if first is None else first
+        if first is None:
+            first = to_host(grads)
         del grads
-    return {"losses": losses, "grad": first, "delta": change((p, s), (params, state))}
+    return {"losses": losses, "grad": first, "delta": change((params, state), start)}
 
 
 def leaf_table(got: dict, want: dict) -> dict:
@@ -161,6 +207,7 @@ def leaf_table(got: dict, want: dict) -> dict:
             "grad_diff": diff_norms(got["grad"], want["grad"]) / g_floor,
             "delta_gap": np.abs(leaf_norms(got["delta"]) - d_ref) / d_floor,
             "delta_diff": diff_norms(got["delta"], want["delta"]) / d_floor,
+            "grad_floor": g_floor,
             "keep": np.concatenate([live, np.ones(len(d_ref) - len(live), bool)]),
             "is_state": np.arange(len(d_ref)) >= len(g_ref)}
 
@@ -189,7 +236,6 @@ def compare(got: dict, want: dict) -> dict:
         # the same difference counted in roundings: over what the reference itself moves by when
         # its operands are rounded to the configuration's compute type, leaf by leaf. How far a
         # gradient sits above its own noise differs from seed to seed; this does not.
-        noise = diff_norms(want["grad_rounded"], want["grad"]) / np.maximum(
-            leaf_norms(want["grad"]), np.median(leaf_norms(want["grad"])))
+        noise = diff_norms(want["grad_rounded"], want["grad"]) / t["grad_floor"]
         out["grad_diff_roundings"] = np.median(t["grad_diff"] / np.maximum(noise, max(1e-3 * np.median(noise), 1e-30)))
     return {name: float(value) for name, value in out.items()}

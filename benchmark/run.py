@@ -98,13 +98,20 @@ def run_cell(cell, seed, seconds, trace, device, peak, process_start=PROCESS_STA
               "device": dict(device, memory_peak_bytes=run["memory_peak_bytes"])}
     if trace:
         from benchmark import trace_reduce
-        reduced = trace_reduce.reduce_dir(trace_dir)
+        # the step program's HLO text names each op's scope; it is megabytes, and only the reduction reads it
+        reduced = trace_reduce.reduce_dir(trace_dir, run.pop("hlo_texts", None))
         shutil.rmtree(trace_dir, ignore_errors=True)
         print(f"trace reduced at {time.time() - process_start:.2f} s", flush=True)
         if reduced is None or not reduced["busy_s"] > 0 or not run["traced_s"]:
             sys.exit("benchmark: the trace holds no device operation after a 'measure' mark")
         for gap in reduced["gap_hosts"]:
             print(f"idle gap {json.dumps(gap)}", flush=True)
+        if reduced["scopes"]:
+            table = reduced["scopes"]
+            print(f"scopes of {table['program']}: {table['step_s'] * 1e3:.3f} ms a step over {table['runs']} whole runs, "
+                  f"{100 * table['attributed_fraction']:.2f}% in named scopes; ms by phase and scope: "
+                  + json.dumps({phase: {scope: round(s * 1e3, 3) for scope, s in per.items()}
+                                for phase, per in table["scopes"].items()}), flush=True)
         for metric in cell.per_layer:
             value = load_module("readers", metric["name"]).read(run, reduced, cell, peak)
             if value is not None:

@@ -155,12 +155,15 @@ def program_readings(ctx, net, fitter, datasets):
             del moment
     start = ctx.family.init_params(ctx.config, ctx.seed)
     ts = net.train_state
-    delta = jax.device_get(reference_train.change((ts.params, ts.model_state), start))
+    delta = reference_train.change((ts.params, ts.model_state), start)  # taken on the device a leaf at a time
     del start
     return {"losses": losses, "grad": grad, "delta": delta}
 
 
 def reference_readings(ctx, precision="float32", transform=None, steps=None):
+    """The plain reference's readings, as host arrays (``follow`` works in
+    place: on the device it costs the parameters, the moments and one
+    gradient, 16 bytes a parameter under Adam, plus a step's activations)."""
     start = ctx.family.init_params(ctx.config, ctx.seed)
     host = ctx.family.batches(ctx.config, ctx.traffic, ctx.seed)[:steps or ctx.traffic["check_steps"]]
     device = [tuple(None if a is None else jnp.asarray(a) for a in b) for b in host]
@@ -215,6 +218,10 @@ def run(ctx) -> dict:
     last_loss = float(net.score())
     steps = feed.count
     peak = max(memory_peak(d) for d in jax.local_devices())
+    # a traced run joins each device op with its scope through the step program's optimized HLO
+    # text, which goes with the net: read it off the AOT executables first
+    hlo_texts = [text for cache in net._jit_cache.values() if hasattr(cache, "hlo_texts")
+                 for text in cache.hlo_texts()] if ctx.trace else None
 
     # the program goes before the reference comes: its peak has been read,
     # its state is freed
@@ -228,7 +235,7 @@ def run(ctx) -> dict:
         "end_to_end": {"train_samples_per_s": samples / window_s, "setup_s": setup_s},
         "attempted": steps, "failed": 0 if math.isfinite(last_loss) else steps,
         "checks": checks, "memory_peak_bytes": peak,
-        "steps": steps, "window_s": window_s, "traced_s": traced_s,
+        "steps": steps, "window_s": window_s, "traced_s": traced_s, "hlo_texts": hlo_texts,
         "profiler": profiler.report() if profiler else None,
         "compiles_in_window": sum(after[k] - before[k] for k in ("hits", "misses", "corrupt_entries")),
     }

@@ -7,9 +7,14 @@ idle and a gap's label off a small synthetic trace; a run without a listed
 TPU fails rather than falls back; a toy run prints the contract's last
 line with ``correct`` true; the control (the reference in fp8 put in the
 program's place) and each fault a training cell can have come out as not
-correct.
+correct; the reference follows its steps in place (four trees of the
+parameters' size on the device, no more) and reads what the formulas worked
+by hand read; the reduced trace holds every op, every kind and every scope
+after the mark, as the program's own scope table has them; and each reader
+of those reads a hand-made trace.
 """
 
+import gc
 import json
 import os
 import re
@@ -300,10 +305,220 @@ def compare_leaves_out_leaves_with_no_gradient():
     assert checks["delta_norm_gap"] == pytest.approx(0.5) and checks["state_diff_median"] == pytest.approx(0.5)
 
 
+# ---------------------------------------------- the reference, in place
+
+def _toy_problem(seed=0, width=256, rows=8):
+    """A 2-leaf model whose gradient can be written down: loss = mean((x w + b - y)^2)."""
+    rng = np.random.default_rng(seed)
+    params = {"b": rng.normal(size=(width,)).astype(np.float32),
+              "w": (rng.normal(size=(width, width)) / 16).astype(np.float32)}
+    batches = [(rng.normal(size=(rows, width)).astype(np.float32), rng.normal(size=(rows, width)).astype(np.float32))
+               for _ in range(3)]
+
+    def loss_fn(p, state, batch, mm, conv):
+        x, y = batch
+        return ((mm(x, p["w"]) + p["b"] - y) ** 2).mean(), state
+
+    return params, batches, loss_fn
+
+
+def _by_hand(params, batches, opt):
+    """The same steps in float64 numpy: the gradient from the residual, then
+    Adam (Kingma & Ba 2014, algorithm 1) or Nesterov momentum written out."""
+    p = {k: v.astype(np.float64) for k, v in params.items()}
+    moments = [{k: np.zeros_like(v) for k, v in p.items()} for _ in range(2)]
+    losses, first = [], None
+    for t, (x, y) in enumerate(batches, start=1):
+        r = x.astype(np.float64) @ p["w"] + p["b"] - y
+        losses.append((r ** 2).mean())
+        g = {"w": 2 * x.T.astype(np.float64) @ r / r.size, "b": 2 * r.sum(0) / r.size}
+        first = first or g
+        for k in p:
+            if opt["name"] == "adam":
+                m = moments[0][k] = opt["b1"] * moments[0][k] + (1 - opt["b1"]) * g[k]
+                v = moments[1][k] = opt["b2"] * moments[1][k] + (1 - opt["b2"]) * g[k] ** 2
+                p[k] = p[k] - opt["lr"] * (m / (1 - opt["b1"] ** t)) / (np.sqrt(v / (1 - opt["b2"] ** t)) + opt["eps"])
+            else:
+                tr = moments[0][k] = g[k] + opt["momentum"] * moments[0][k]
+                p[k] = p[k] - opt["lr"] * (g[k] + opt["momentum"] * tr)
+    return losses, first, {k: p[k] - params[k] for k in p}
+
+
+def follow_holds_four_trees_and_reads_what_the_formulas_read(opt):
+    import jax
+    import jax.numpy as jnp
+    params, batches, loss_fn = _toy_problem()
+    param_bytes = sum(a.nbytes for a in params.values())
+    batch_bytes = sum(a.nbytes for b in batches for a in b)
+    gc.collect()
+    before = sum(a.nbytes for a in jax.live_arrays())  # whatever earlier tests of this process left
+    held = []
+
+    def watch(step):
+        def watched(*args):
+            held.append(sum(a.nbytes for a in jax.live_arrays()))
+            out = step(*args)
+            jax.block_until_ready(out)
+            held.append(sum(a.nbytes for a in jax.live_arrays()))  # with the step's results, gradient and all
+            return out
+        return watched
+
+    got = reference_train.follow(loss_fn, jax.tree.map(jnp.asarray, params), {},
+                                 [tuple(jnp.asarray(a) for a in b) for b in batches], opt, transform=watch)
+    assert len(held) == 6
+    # parameters, moments and one gradient: never the start, the first gradient or a second copy beside them
+    assert max(held) - before <= 4.5 * param_bytes + batch_bytes, (held, before, param_bytes)
+    assert sum(a.nbytes for a in jax.live_arrays()) - before <= 1024  # and nothing stays behind
+    assert all(isinstance(a, np.ndarray) for a in jax.tree.leaves((got["grad"], got["delta"])))
+    losses, grad, delta = _by_hand(params, batches, opt)
+    assert got["losses"] == pytest.approx(losses, rel=1e-5)
+    for k in params:
+        assert got["grad"][k] == pytest.approx(grad[k], rel=1e-4, abs=1e-7)
+        # a float32 parameter of size ~1 holds its change to ~1e-7; Adam's three steps move it by ~3e-3
+        assert got["delta"][0][k] == pytest.approx(delta[k], rel=1e-3, abs=5e-7)
+    assert got["delta"][1] == {}
+    # the comparison takes trees on the host or on the device alike, a leaf at a time
+    same = reference_train.compare(got, dict(got, grad=jax.tree.map(jnp.asarray, got["grad"])))
+    assert same["grad_diff_worst"] == 0.0 and same["delta_norm_gap"] == 0.0 and same["loss_gap"] == 0.0
+
+
+# ------------------------------------------------ every op, kind and scope
+
+MS = 1_000_000
+STEP_OPS = [("fusion.1", 4), ("fusion.2", 6), ("fused_attention_fwd.3", 2), ("copy.4", 1), ("fusion.5", 3)]
+STEP_HLO = """HloModule jit_step, is_scheduled=true
+ENTRY %main.9 (p0: f32[8]) -> f32[8] {
+  %fusion.1 = f32[8]{0} fusion(%p0), kind=kOutput, calls=%c1, metadata={op_name="jit(step)/jit(main)/jvp(layer_1.Block)/ffn/dot_general" source_file="a.py" source_line=3}
+  %fusion.2 = f32[8]{0} fusion(%fusion.1), kind=kOutput, calls=%c2, metadata={op_name="jit(step)/jit(main)/transpose(jvp(layer_1.Block))/ffn/dot_general"}
+  %fused_attention_fwd.3 = f32[8]{0} custom-call(%fusion.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jit(main)/jvp(layer_2.Block)/fused_attention/pallas_call"}
+  %copy.4 = f32[8]{0} copy(%fused_attention_fwd.3), metadata={op_name="jit(step)/jit(main)/convert_element_type"}
+  ROOT %fusion.5 = f32[8]{0} fusion(%copy.4), kind=kLoop, calls=%c3, metadata={op_name="jit(step)/jit(main)/updater/add"}
+}
+"""
+
+
+def _step_planes(mark_ms, devices=1):
+    """Three runs of a 20 ms step program (16 ms of ops, back to back from
+    each run's start) at 0, 30 and 60 ms on each device, a 1 ms program
+    between them, and a ``measure`` mark on the host."""
+    ops, modules = [], []
+    for start in (0, 30, 60):
+        modules.append(("jit_step(1)", start * MS, 20 * MS))
+        at = start
+        for name, ms in STEP_OPS:
+            ops.append((name + " = f32[8]{0} fusion(...)", at * MS, ms * MS))  # the trace gives the whole text
+            at += ms
+    modules.append(("jit_tiny(2)", 25 * MS, 1 * MS))
+    ops.append(("copy.9", 25 * MS, 1 * MS))
+    device = [("XLA Ops", ops), ("XLA Modules", modules), ("Steps", [("0", 0, 80 * MS)])]
+    return ([(f"/device:TPU:{i}", device) for i in range(devices)]
+            + [("/host:CPU", [("python3", [("measure", mark_ms * MS, 0)])])])
+
+
+def the_reduced_trace_holds_every_op_kind_and_scope_after_the_mark():
+    # the mark falls 1 ms into the first run's kernel: that run is no whole run, its ops count by their part
+    out = trace_reduce.reduce_planes(_step_planes(mark_ms=11), [STEP_HLO])
+    assert out["program"] == "jit_step(1)" and out["program_runs"] == pytest.approx(2 + 9 / 20)
+    assert out["op_seconds"] == pytest.approx({"fusion.1": 0.008, "fusion.2": 0.012, "fused_attention_fwd.3": 0.005,
+                                               "copy.4": 0.003, "fusion.5": 0.009, "copy.9": 0.001})
+    assert out["kind_seconds"]["fusion"] == pytest.approx([0.029, 7])
+    assert out["kind_seconds"]["fused_attention_fwd"] == pytest.approx([0.005, 2.5])
+    assert out["kind_seconds"]["copy"] == pytest.approx([0.004, 4])
+    scopes = out["scopes"]
+    assert scopes["runs"] == 2 and scopes["step_s"] == pytest.approx(0.020)
+    assert scopes["scopes"] == {"forward": pytest.approx({"Block/ffn": 0.004, "Block/fused_attention": 0.002}),
+                                "backward": pytest.approx({"Block/ffn": 0.006}),
+                                "optimizer": pytest.approx({"updater": 0.003})}
+    assert scopes["unattributed"] == pytest.approx({"copy [convert_element_type]": 0.001})
+    assert scopes["phases"] == pytest.approx({"forward": 0.006, "backward": 0.006, "optimizer": 0.003, "other": 0.005})
+    assert scopes["attributed_fraction"] == pytest.approx(0.75)
+    # scopes first (their seconds in the whole runs), then kinds
+    assert out["device_ops"][:4] == [["Block/ffn bwd", pytest.approx(0.012)], ["Block/ffn fwd", pytest.approx(0.008)],
+                                     ["updater opt", pytest.approx(0.006)],
+                                     ["Block/fused_attention fwd", pytest.approx(0.004)]]
+    assert out["device_ops"][4] == ["fusion.*x3", pytest.approx(0.029)] and len(out["device_ops"]) <= 10
+    # two devices: everything is per device
+    two = trace_reduce.reduce_planes(_step_planes(mark_ms=11, devices=2), [STEP_HLO])
+    assert two["op_seconds"] == pytest.approx(out["op_seconds"]) and two["scopes"]["runs"] == 4
+    assert two["scopes"]["scopes"]["backward"] == pytest.approx({"Block/ffn": 0.006})
+    assert two["kind_seconds"]["fused_attention_fwd"] == pytest.approx([0.005, 2.5])
+    # no HLO text from the runner: no scope table, the line falls back to kinds and single ops
+    bare = trace_reduce.reduce_planes(_step_planes(mark_ms=11))
+    assert bare["scopes"] is None and bare["device_ops"][0] == ["fusion.*x3", pytest.approx(0.029)]
+    # a step program none of whose ops carries a scope (a stale compile cache) fails the traced run
+    with pytest.raises(RuntimeError, match="carries a scope"):
+        trace_reduce.reduce_planes(_step_planes(mark_ms=11),
+                                   [re.sub(r'op_name="[^"]*/', 'op_name="jit(step)/jit(main)/', STEP_HLO)])
+
+
+def the_yardsticks_scope_table_is_the_programs():
+    """``trace_reduce`` keeps its own copy of the join (a PR that claims a
+    gain cannot edit it); with the mark before everything it reads what
+    ``runtime.profiler.scope_times`` reads off the same planes."""
+    from deeplearning4j_tpu.runtime import profiler
+    planes = _step_planes(mark_ms=0)
+    ours = trace_reduce.reduce_planes(planes, [STEP_HLO])["scopes"]
+    theirs = profiler.scope_times([p for p in planes if p[0].startswith("/device:")], [STEP_HLO],
+                                  depth=2, merge_layers=True)
+    assert set(ours) == set(theirs) and ours["runs"] == theirs["runs"] == 3
+    for key in ("program", "step_s", "attributed_fraction"):
+        assert ours[key] == pytest.approx(theirs[key])
+    for key in ("phases", "unattributed"):
+        assert ours[key] == pytest.approx(theirs[key])
+    for phase in theirs["scopes"]:
+        assert ours["scopes"][phase] == pytest.approx(dict(theirs["scopes"][phase]))
+    for op_name in ("jit(s)/jit(main)/transpose(jvp(layer_3.Block))/qkv/dot_general", "jit(s)/jit(main)/mul",
+                    "jit(s)/loss/reduce_sum", "jit(s)/jit(main)/jvp(jit(gelu))/layer_0.Embed/ln/add"):
+        assert trace_reduce.classify_op_name(op_name) == profiler.classify_op_name(op_name)
+
+
+# ------------------------------------------------------- the new readers
+
+def the_new_readers_read_a_trace_made_by_hand():
+    cell = bench.resolve("bert-base-ft-b32-s512")
+    peak = bench.load_json(os.path.join(ROOT, "benchmark", "peaks.json"))["TPU v5 lite"]
+    # the kernel pair at the cell's shape, by hand: forward K Q^T and V E, 2 x 2 x 512 x 512 x 64 FLOPs a head, 12 heads,
+    # 32 rows; backward five such matmuls for the forward's two. Bytes: 25.2 MB an operand in bf16, 4 and 8 of them.
+    flops = cell.family.attention_kernel_flops(cell.config, cell.traffic)
+    least = cell.family.attention_kernel_bytes(cell.config, cell.traffic)
+    assert flops == {"fused_attention_fwd": 32 * 12 * 2 * (2 * 512 * 512 * 64),
+                     "fused_attention_bwd": 32 * 12 * 5 * (2 * 512 * 512 * 64)}
+    operand = 32 * 512 * 768 * 2
+    assert least == {"fused_attention_fwd": 4 * operand + 32 * 512 * 4, "fused_attention_bwd": 8 * operand + 32 * 512 * 4}
+    read = {name: bench.load_module("readers", name).read
+            for name in ("fused_attention_roofline", "attention_share.train", "ffn_share.train")}
+    # twelve runs of each kernel at the times PERF.md section 5 gives (PR 27): both bound by FLOPs,
+    # (0.1308 + 0.3270) ms at peak over (0.348 + 0.624) ms taken
+    trace = {"kind_seconds": {"fused_attention_fwd": [12 * 0.348e-3, 12], "fused_attention_bwd": [12 * 0.624e-3, 12],
+                              "fusion": [2.4, 17930]},
+             "scopes": {"step_s": 0.0673, "scopes": {
+                 "forward": {"TransformerEncoderBlock/ffn": 0.01058, "TransformerEncoderBlock/fused_attention": 0.0042,
+                             "TransformerEncoderBlock/qkv": 0.00412, "loss": 0.0001},
+                 "backward": {"TransformerEncoderBlock/ffn": 0.02107, "TransformerEncoderBlock/fused_attention": 0.00749},
+                 "optimizer": {"updater": 0.00067}}}}
+    by_hand = 100 * (3.5 * 4 * 32 * 512 * 512 * 768 / 197e12) / (0.348e-3 + 0.624e-3)
+    assert read["fused_attention_roofline"](None, trace, cell, peak) == pytest.approx(by_hand, rel=1e-9)
+    assert by_hand == pytest.approx(47.1, abs=0.05)
+    assert read["attention_share.train"](None, trace, cell, peak) == pytest.approx(100 * 0.01169 / 0.0673)
+    assert read["ffn_share.train"](None, trace, cell, peak) == pytest.approx(100 * 0.03165 / 0.0673)
+    # a bandwidth-starved chip: the bytes bound holds and the reader takes it
+    slow = dict(peak, hbm_bytes_per_s=peak["hbm_bytes_per_s"] / 10)
+    by_bytes = 100 * (least["fused_attention_fwd"] + least["fused_attention_bwd"]) / slow["hbm_bytes_per_s"] / 0.972e-3
+    assert read["fused_attention_roofline"](None, trace, cell, slow) == pytest.approx(by_bytes, rel=1e-9)
+    # the XLA form's scopes count as attention too; nothing to read returns nothing, never 0
+    xla = {"kind_seconds": {"fusion": [2.4, 17930]}, "scopes": {"step_s": 0.1, "scopes": {
+        "forward": {"SelfAttentionLayer/scores": 0.01, "SelfAttentionLayer/softmax": 0.005}, "backward":
+        {"SelfAttentionLayer/context": 0.005}, "optimizer": {}}}}
+    assert read["fused_attention_roofline"](None, xla, cell, peak) is None
+    assert read["attention_share.train"](None, xla, cell, peak) == pytest.approx(20.0)
+    assert read["ffn_share.train"](None, xla, cell, peak) is None
+    assert read["attention_share.train"](None, {"kind_seconds": {}, "scopes": None}, cell, peak) is None
+
+
 # ------------------------------------------------------------------ the tests
-# Five tests, no more, in a directory that is collected last: xdist hands files out by their
-# number of tests, largest first, and this file then goes out after every other one, so that
-# which worker runs which of the suite's older files stays as it was before this file came.
+# Few tests, each of several cases, in a directory that is collected last: xdist hands files out by
+# their number of tests, largest first, so the fewer this file has, the fewer of the suite's older
+# files change the worker they run on (PERF.md section 7 row 10: the hang that made it matter is cured).
 
 def test_manifest_and_resolution_from_a_cells_name():
     manifest_keeps_to_the_contract()
@@ -336,3 +551,18 @@ def test_the_control_and_each_fault_come_out_as_not_correct(monkeypatch):
     for fault in (_state_unchanged, _half_batch):
         with monkeypatch.context() as patch:
             a_run_with_the_timed_path_broken_is_not_correct(patch, fault)
+
+
+def test_the_reference_follows_in_place_and_reads_what_the_formulas_read():
+    follow_holds_four_trees_and_reads_what_the_formulas_read(
+        {"name": "adam", "lr": 1e-3, "b1": 0.9, "b2": 0.999, "eps": 1e-8})
+    follow_holds_four_trees_and_reads_what_the_formulas_read({"name": "nesterov", "lr": 0.01, "momentum": 0.9})
+
+
+def test_the_reduced_trace_holds_every_op_kind_and_scope():
+    the_reduced_trace_holds_every_op_kind_and_scope_after_the_mark()
+    the_yardsticks_scope_table_is_the_programs()
+
+
+def test_the_new_readers_read_a_trace_made_by_hand():
+    the_new_readers_read_a_trace_made_by_hand()
