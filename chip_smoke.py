@@ -25,7 +25,11 @@ vocab 30522; batch 64 x seq 128, bf16 compute, library defaults otherwise):
    graves=True).fit`` at B=64, T=256 (routes ``fused_lstm_graves`` in its
    model).
 3. *trainer*: ``Bert.base().fit(ListDataSetIterator(...))`` on seeded
-   synthetic SST-2-shaped batches with a padded tail.
+   synthetic SST-2-shaped batches with a padded tail; then *kimi_linear*:
+   ``KimiLinear.tiny(...)`` at the published head sizes (KDA 128; latent
+   attention 128 + 64 against 128) trained on next tokens at T=1024, where
+   ``dot_product_attention`` routes the flash kernel with two head sizes
+   and the experts their grouped matmul; no assignment beyond the buffer.
 4. *server*: ``ModelSerializer.write_model`` -> ``ModelRegistry.load`` with
    one replica per device -> ``ModelServer`` -> ``POST
    /v1/models/bert/predict`` with mixed row counts, ``/healthz``,
@@ -52,6 +56,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -100,6 +105,12 @@ class Preset:
     attn_long: tuple = (1, 1, 16384, 64)
     # the resident fused kernel's routed shape: (batch, T, heads, d)
     attn_fused: tuple = (8, 512, 12, 64)
+    # KimiLinear.tiny at the published head sizes; T routes the flash kernel
+    kimi: Dict[str, Any] = dataclasses.field(default_factory=lambda: dict(
+        d_model=256, kda_head_dim=128, qk_nope_dim=128, qk_shared_dim=64,
+        v_dim=128, kv_rank=128, vocab_size=512))
+    kimi_batch: int = 2
+    kimi_seq: int = 1024
 
 
 # ------------------------------------------------------------------ helpers
@@ -478,6 +489,62 @@ def check_bert_train(p: Preset):
                  "compiles_after_warmup": 0}
 
 
+def _recomputing(check):
+    """``check`` under ``Environment.set_remat(True)``, as the benchmark's
+    cell runs the decoder: each named scope recomputed; put back after."""
+    @functools.wraps(check)
+    def run(p: Preset) -> Dict[str, Any]:
+        from deeplearning4j_tpu.runtime.environment import get_environment
+        env = get_environment()
+        was = env.remat_segments
+        env.set_remat(True)
+        try:
+            return check(p)
+        finally:
+            env.set_remat(was)
+    return run
+
+
+@_recomputing
+def check_kimi_linear(p: Preset) -> Dict[str, Any]:
+    """A few next-token steps of a small ``KimiLinear`` through ``fit``:
+    KDA's chunked scan, latent attention through the flash kernel (a q.k
+    head of two parts against a smaller v head), experts through the
+    grouped matmul, held to: finite falling loss, nothing compiled after
+    warm-up, the kernels in the compiled step, no assignment left out."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.train.listeners import CollectScoresListener
+    from deeplearning4j_tpu.zoo import KimiLinear
+
+    net = KimiLinear.tiny(**p.kimi).init()
+    scores = CollectScoresListener()
+    net.set_listeners(scores)
+    ids = np.random.default_rng(0).integers(
+        0, net.layers[0].n_in, (p.kimi_batch, p.kimi_seq + 1), dtype=np.int32)
+    batch = DataSet(np.ascontiguousarray(ids[:, :-1]),
+                    np.ascontiguousarray(ids[:, 1:]))
+    losses = _fit_counted(net.fit, [batch], [batch], p.kimi_batch, 6, scores,
+                          "KimiLinear fit")
+    assert _on_platform(net.train_state, p.platform), \
+        "KimiLinear train state is not on the device"
+    overflow = {k: float(s["mlp"]["overflow"])
+                for k, s in net.train_state.model_state.items()}
+    assert overflow and not any(overflow.values()), \
+        f"KimiLinear: assignments beyond the experts' buffer {overflow}"
+    step, packer = net._jitted_packed()
+    compiled = step.lower(
+        packer.pack_device(net.train_state), jnp.asarray(batch.features),
+        jnp.asarray(batch.labels), jax.random.PRNGKey(0), None, None).compile()
+    # flash forward and its two backward passes, at the least
+    n_calls = _mosaic_calls(compiled, p, 3, "KimiLinear train step")
+    if p.expect_mosaic:
+        assert "flash_attention_fwd" in compiled.as_text(), \
+            "KimiLinear train step: the flash kernel was routed around"
+    _log(f"  KimiLinear train step: mosaic_calls={n_calls}")
+    return {"steps": len(losses), "first_loss": losses[0],
+            "last_loss": losses[-1], "mosaic_calls": n_calls}
+
+
 # ----------------------------------------------------------- phase 4: server
 def _pad_rows(x: np.ndarray, bucket: int) -> np.ndarray:
     return np.concatenate(
@@ -624,6 +691,9 @@ def run(p: Preset, report: Dict[str, Any], workdir: str) -> None:
     with _phase(report, "bert_serve"):
         report["bert_serve"] = check_bert_serve(p, net, workdir)
     del net
+    gc.collect()
+    with _phase(report, "kimi_linear"):
+        report["kimi_linear"] = check_kimi_linear(p)
     gc.collect()
     if len(jax.devices()) >= 4:
         with _phase(report, "four_chips"):

@@ -174,6 +174,8 @@ class MultiLayerNetwork:
         # env.remat_segments on, each hidden layer's activations are
         # recomputed in the backward pass instead of saved — HBM traffic
         # traded for FLOPs (same policy as ComputationGraph._forward_remat).
+        # A layer that sets ``remat_in_scopes`` recomputes inside each of its
+        # named scopes instead (``nn.attention_layers.scoped``).
         use_remat = (env.remat_segments and training and carries is None
                      and n > 2)
         for i, layer in enumerate(self.layers):
@@ -202,7 +204,7 @@ class MultiLayerNetwork:
                     new_carries[k] = c_new
                     x = y
                 else:
-                    if use_remat and i < n - 1:
+                    if use_remat and i < n - 1 and not getattr(layer, "remat_in_scopes", False):
                         def _fwd(p_, s_, x_, lrng_, fmask_, _l=layer):
                             return _l.forward(p_, s_, x_, training=True,
                                               rng=lrng_, mask=fmask_)

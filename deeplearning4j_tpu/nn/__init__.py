@@ -61,7 +61,12 @@ from deeplearning4j_tpu.nn.attention_layers import (
     LearnedPositionalEmbeddingLayer,
     SelfAttentionLayer,
     TransformerEncoderBlock,
+    DecoderBlock,
+    GatedMLP,
+    LatentAttention,
+    RMSNormLayer,
 )
+from deeplearning4j_tpu.nn.linear_attention_layers import KimiDeltaAttention
 from deeplearning4j_tpu.nn.extra_layers import (
     CenterLossOutputLayer,
     Convolution3D,
@@ -130,6 +135,11 @@ __all__ = [
     "RnnOutputLayer",
     "SelfAttentionLayer",
     "TransformerEncoderBlock",
+    "DecoderBlock",
+    "GatedMLP",
+    "LatentAttention",
+    "RMSNormLayer",
+    "KimiDeltaAttention",
     "LearnedPositionalEmbeddingLayer",
     "BertEmbeddingLayer",
     "ClsPoolingLayer",

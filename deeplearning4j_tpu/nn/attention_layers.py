@@ -24,6 +24,7 @@ from deeplearning4j_tpu.nn.base import (GlobalConfig, Layer, dropout_mask,
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.ops.initializers import init_weights
+from deeplearning4j_tpu.runtime.environment import get_environment
 
 
 def layer_norm(x, gamma, beta, eps=1e-12):
@@ -371,3 +372,184 @@ class LearnedPositionalEmbeddingLayer(Layer):
     def forward(self, params, state, x, *, training=False, rng=None, mask=None):
         t = x.shape[1]
         return x + params["P"][None, :t, :], state
+
+
+# ---------------------------------------------------------------------------
+# Pre-norm decoder layers (RMSNorm, SwiGLU, latent attention, the block)
+# ---------------------------------------------------------------------------
+
+
+def rms_norm(x, w, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * w`` over the last axis; statistics in
+    float32, result in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def scoped(name: str, fn, *args):
+    """``fn(*args)`` under ``jax.named_scope(name)``. Under
+    ``Environment.set_remat`` the call is a ``jax.checkpoint`` made *inside*
+    the scope: only ``args`` are kept for the backward pass, and every op of
+    it, recomputed or not, still carries ``<layer>/<name>`` as its first two
+    scopes. A layer whose work all goes through here sets
+    ``remat_in_scopes``, and the network then leaves out its own checkpoint
+    around that layer (which would recompute the layer a second time and
+    name its backward pass ``<layer>/<layer>/checkpoint/...``)."""
+    with jax.named_scope(name):
+        return (jax.checkpoint(fn) if get_environment().remat_segments else fn)(*args)
+
+
+def _sub_init(layer: Layer, key, input_type, g):
+    layer._g = g
+    return layer.init(key, input_type, g)
+
+
+@register_layer
+@dataclasses.dataclass
+class RMSNormLayer(Layer):
+    """Root-mean-square norm with a learned scale over the feature axis."""
+
+    eps: float = 1e-5
+    remat_in_scopes = True
+
+    def init(self, key, input_type, g: GlobalConfig):
+        return {"w": jnp.ones((input_type.size,), g.dtype or jnp.float32)}, {}
+
+    def forward(self, params, state, x, *, training=False, rng=None, mask=None):
+        return scoped("norm", lambda x_, w: rms_norm(x_, w, self.eps), x, params["w"]), state
+
+    def regularizable_params(self):
+        return ()
+
+
+@register_layer
+@dataclasses.dataclass
+class GatedMLP(Layer):
+    """SwiGLU feed-forward: ``W_d (SiLU(x W_g) * x W_u)``, no biases."""
+
+    hidden_size: int = 0
+
+    def init(self, key, input_type, g: GlobalConfig):
+        d, f = input_type.size, self.hidden_size
+        kg, ku, kd = jax.random.split(key, 3)
+        w = self._winit(g)
+        return {"W_g": init_weights(kg, (d, f), w, fan=(d, f), dtype=g.dtype),
+                "W_u": init_weights(ku, (d, f), w, fan=(d, f), dtype=g.dtype),
+                "W_d": init_weights(kd, (f, d), w, fan=(f, d), dtype=g.dtype)}, {}
+
+    @staticmethod
+    def apply(p, x):
+        return (jax.nn.silu(x @ p["W_g"]) * (x @ p["W_u"])) @ p["W_d"]
+
+    def forward(self, params, state, x, *, training=False, rng=None, mask=None):
+        return scoped("mlp", self.apply, params, x), state
+
+    def regularizable_params(self):
+        return ("W_g", "W_u", "W_d")
+
+
+@register_layer
+@dataclasses.dataclass
+class LatentAttention(Layer):
+    """Multi-head latent attention without rotary (MLA, NoPE), causal.
+
+    Keys and values come up from one ``kv_rank``-wide normed latent per
+    token; each head's key is its own ``qk_nope_dim`` part beside one
+    ``qk_shared_dim`` part that all heads share. The T x T part goes through
+    ``dot_product_attention`` and the routing it owns (the flash kernel takes
+    the q.k head of ``qk_nope_dim + qk_shared_dim`` against a v head of
+    ``v_dim``: two sizes, nothing padded)."""
+
+    n_heads: int = 32
+    kv_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_shared_dim: int = 64
+    v_dim: int = 128
+    eps: float = 1e-5
+
+    def init(self, key, input_type, g: GlobalConfig):
+        d, h = input_type.size, self.n_heads
+        qk = self.qk_nope_dim + self.qk_shared_dim
+        shapes = {"W_q": (d, h * qk), "W_kva": (d, self.kv_rank + self.qk_shared_dim),
+                  "W_kvb": (self.kv_rank, h * (self.qk_nope_dim + self.v_dim)),
+                  "W_o": (h * self.v_dim, d)}
+        params = {name: init_weights(k, shape, self._winit(g), fan=shape, dtype=g.dtype)
+                  for (name, shape), k in zip(shapes.items(), jax.random.split(key, len(shapes)))}
+        params["kv_norm"] = jnp.ones((self.kv_rank,), g.dtype or jnp.float32)
+        return params, {}
+
+    def _qkv(self, p, x):
+        b, t, _ = x.shape
+        h, dn, dr = self.n_heads, self.qk_nope_dim, self.qk_shared_dim
+        q = (x @ p["W_q"]).reshape(b, t, h, dn + dr)
+        latent = x @ p["W_kva"]
+        c, k_shared = latent[..., :self.kv_rank], latent[..., self.kv_rank:]
+        kv = (rms_norm(c, p["kv_norm"], self.eps) @ p["W_kvb"]).reshape(b, t, h, dn + self.v_dim)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (b, t, h, dr))], -1)
+        return tuple(a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
+
+    def forward(self, params, state, x, *, training=False, rng=None, mask=None):
+        q, k, v = scoped("mla_qkv", self._qkv, params, x)
+        key_mask = None if mask is None else mask[:, None, None, :].astype(bool)
+        y = dot_product_attention(q, k, v, key_mask, causal=True)
+
+        def out(p, y):
+            b, h, t, dv = y.shape
+            return y.transpose(0, 2, 1, 3).reshape(b, t, h * dv) @ p["W_o"]
+
+        return scoped("mla_out", out, params, y), state
+
+    def regularizable_params(self):
+        return ("W_q", "W_kva", "W_kvb", "W_o")
+
+
+@register_layer
+@dataclasses.dataclass
+class DecoderBlock(Layer):
+    """Pre-norm decoder block: ``h = x + mixer(norm(x))``, ``y = h +
+    mlp(norm(h))``, with RMSNorm and any two layers that keep the width:
+    ``mixer`` (``LatentAttention``, ``KimiDeltaAttention``, ...) and ``mlp``
+    (``GatedMLP``, ``MixtureOfExperts``). Each opens its own scopes, which so
+    sit directly under this block's."""
+
+    mixer: Any = None
+    mlp: Any = None
+    eps: float = 1e-5
+    remat_in_scopes = True  # mixer and MLP recompute inside their scopes (``scoped``)
+
+    def __post_init__(self):
+        for name in ("mixer", "mlp"):
+            if isinstance(getattr(self, name), dict):
+                setattr(self, name, Layer.from_dict(getattr(self, name)))
+
+    def init(self, key, input_type, g: GlobalConfig):
+        k_mixer, k_mlp = jax.random.split(key)
+        ones = jnp.ones((input_type.size,), g.dtype or jnp.float32)
+        mixer, mixer_state = _sub_init(self.mixer, k_mixer, input_type, g)
+        mlp, mlp_state = _sub_init(self.mlp, k_mlp, input_type, g)
+        state = {name: s for name, s in (("mixer", mixer_state), ("mlp", mlp_state)) if s}
+        if any("_aux_loss" in s for s in state.values()):
+            # the network adds up ``_aux_loss`` entries it finds at a layer's top level
+            state["_aux_loss"] = jnp.zeros((), jnp.float32)
+        return {"norm1": ones, "mixer": mixer, "norm2": ones, "mlp": mlp}, state
+
+    def forward(self, params, state, x, *, training=False, rng=None, mask=None):
+        new_state = dict(state)
+        for name, norm in (("mixer", "norm1"), ("mlp", "norm2")):
+            sub = getattr(self, name)
+            sub._g = self._g
+            with jax.named_scope("norm"):
+                normed = rms_norm(x, params[norm], self.eps)
+            y, s = sub.forward(params[name], state.get(name, {}), normed,
+                               training=training, rng=rng, mask=mask)
+            x = x + y
+            if name in state:
+                new_state[name] = s
+        if "_aux_loss" in state:
+            new_state["_aux_loss"] = sum(s["_aux_loss"] for s in new_state.values()
+                                         if isinstance(s, dict) and "_aux_loss" in s)
+        return x, new_state
+
+    def regularizable_params(self):
+        return tuple(set(self.mixer.regularizable_params()) | set(self.mlp.regularizable_params()))

@@ -412,3 +412,9 @@ class RnnOutputLayer(OutputLayer):
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)
+
+    def preoutput(self, params, x):
+        # the head's matmul over every position, apart from the loss it feeds
+        # (``loss/lm_head`` in the device trace)
+        with jax.named_scope("lm_head"):
+            return super().preoutput(params, x)

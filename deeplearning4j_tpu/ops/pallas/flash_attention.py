@@ -352,6 +352,14 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
 # short causal lengths, so the resident forms stay for T <= threshold).
 BWD_CHUNK_THRESHOLD = 8192
 BWD_CHUNK = 4096
+# ... and, whatever the length, where the resident forms' full-sequence
+# operands would not fit the kernels' scoped VMEM (32 MiB asked for):
+# q/k and v/dO rows in the input dtype plus the two float32 row statistics,
+# which Mosaic pads from RES_LANES to 128 lanes, all double-buffered. At
+# T=8192 a head of 64 needs 21 MB and stays resident; a q.k head of 192
+# against a v head of 128 needs 27 MB plus its blocks, and Mosaic refused
+# it by 0.8 MB (compiled for a v5e, PR 30).
+RESIDENT_BWD_VMEM = 24 * 1024 * 1024
 
 
 def _bwd_dq_kernel_chunked(*refs, scale: float, block_k: int,
@@ -599,8 +607,14 @@ def _flash_bwd_chunked(q, k, v, bias, out, lse, g, scale, causal, has_bias):
             dv.reshape(b, h, t_k, d_v))
 
 
+def _resident_bwd_bytes(t: int, d: int, d_v: int, itemsize: int) -> int:
+    return 2 * t * ((d + d_v) * itemsize + 2 * 128 * 4)
+
+
 def _flash_bwd(q, k, v, bias, out, lse, g, scale, causal, has_bias):
-    if max(q.shape[2], k.shape[2]) > BWD_CHUNK_THRESHOLD:
+    t = max(q.shape[2], k.shape[2])
+    if (t > BWD_CHUNK_THRESHOLD
+            or _resident_bwd_bytes(t, q.shape[-1], v.shape[-1], q.dtype.itemsize) > RESIDENT_BWD_VMEM):
         return _flash_bwd_chunked(q, k, v, bias, out, lse, g, scale,
                                   causal, has_bias)
     b, h, t_q, d = q.shape

@@ -61,7 +61,9 @@ class Environment:
     # Rematerialization (jax.checkpoint) of single-entry DAG segments during
     # training: trades recompute FLOPs for HBM traffic — the winning trade
     # when a model is bandwidth-bound (ResNet-50 measured 87 GB/step vs the
-    # v5e's 819 GB/s). The workspace-memory knob of this framework.
+    # v5e's 819 GB/s). The workspace-memory knob of this framework. Layers
+    # that set ``remat_in_scopes`` (the decoder block) recompute inside each
+    # of their named scopes instead of as a whole (``nn.attention_layers``).
     remat_segments: bool = False
     # Flat-buffer packing of small train-state leaves at the jitted-step
     # boundary (runtime/state_packing.py): bit-identical math, ~4x fewer

@@ -22,6 +22,7 @@ from deeplearning4j_tpu.zoo.unet import UNet
 from deeplearning4j_tpu.zoo.darknet19 import Darknet19
 from deeplearning4j_tpu.zoo.textgen_lstm import TextGenerationLSTM
 from deeplearning4j_tpu.zoo.bert import Bert
+from deeplearning4j_tpu.zoo.kimi_linear import KimiLinear
 from deeplearning4j_tpu.zoo.vgg19 import VGG19
 from deeplearning4j_tpu.zoo.squeezenet import SqueezeNet
 from deeplearning4j_tpu.zoo.xception import Xception
@@ -29,5 +30,5 @@ from deeplearning4j_tpu.zoo.inception_resnet import InceptionResNetV1
 from deeplearning4j_tpu.zoo.yolo2 import TinyYOLO, YOLO2
 
 __all__ = ["ZooModel", "LeNet", "SimpleCNN", "AlexNet", "VGG16", "VGG19",
-           "ResNet50", "UNet", "Darknet19", "TextGenerationLSTM", "Bert",
+           "ResNet50", "UNet", "Darknet19", "TextGenerationLSTM", "Bert", "KimiLinear",
            "SqueezeNet", "Xception", "InceptionResNetV1", "TinyYOLO", "YOLO2"]

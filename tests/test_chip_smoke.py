@@ -68,7 +68,8 @@ def test_checks_rehearse_at_a_tiny_preset(tmp_path, monkeypatch):
         serve_rows=(1,), max_batch_size=1,
         rnn_t=8, rnn_b=8, rnn_h=128, rnn_vocab=20, rnn_steps=3,
         attn_shape=(1, 1, 128, 64), attn_long=(1, 1, 512, 64),
-        attn_fused=(2, 128, 2, 64))
+        attn_fused=(2, 128, 2, 64),
+        kimi_seq=128)
     report = {"phases": {}}
     # a fallback an earlier test of this process left behind is not this
     # run's: the smoke reads the counter's change over its own run
@@ -79,7 +80,9 @@ def test_checks_rehearse_at_a_tiny_preset(tmp_path, monkeypatch):
         compile_cache.disable()
         env.set_compute_dtype(compute_dtype)
     assert set(report["phases"]) == {"kernels", "char_rnn", "bert_train",
-                                     "bert_serve", "four_chips"}
+                                     "bert_serve", "kimi_linear",
+                                     "four_chips"}
+    assert report["kimi_linear"]["last_loss"] < report["kimi_linear"]["first_loss"]
     assert set(report["kernels"]) == {
         "fused_lstm", "fused_lstm_graves", "fused_gru", "flash_padding_mask",
         "flash_causal", "flash_causal_chunked", "fused_attention"}
