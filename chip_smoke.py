@@ -28,8 +28,10 @@ vocab 30522; batch 64 x seq 128, bf16 compute, library defaults otherwise):
    synthetic SST-2-shaped batches with a padded tail; then *kimi_linear*:
    ``KimiLinear.tiny(...)`` at the published head sizes (KDA 128; latent
    attention 128 + 64 against 128) trained on next tokens at T=1024, where
-   ``dot_product_attention`` routes the flash kernel with two head sizes
-   and the experts their grouped matmul; no assignment beyond the buffer.
+   ``chunk_kda`` routes the delta rule's kernel pair (held against its XLA
+   form first), ``dot_product_attention`` the flash kernel with two head
+   sizes and the experts their grouped matmul; no assignment beyond the
+   buffer.
 4. *server*: ``ModelSerializer.write_model`` -> ``ModelRegistry.load`` with
    one replica per device -> ``ModelServer`` -> ``POST
    /v1/models/bert/predict`` with mixed row counts, ``/healthz``,
@@ -507,14 +509,35 @@ def _recomputing(check):
 
 @_recomputing
 def check_kimi_linear(p: Preset) -> Dict[str, Any]:
-    """A few next-token steps of a small ``KimiLinear`` through ``fit``:
-    KDA's chunked scan, latent attention through the flash kernel (a q.k
-    head of two parts against a smaller v head), experts through the
-    grouped matmul, held to: finite falling loss, nothing compiled after
-    warm-up, the kernels in the compiled step, no assignment left out."""
+    """The delta rule's kernel pair against its XLA form, then a few
+    next-token steps of a small ``KimiLinear`` through ``fit``: KDA through
+    that pair, latent attention through the flash kernel (a q.k head of two
+    parts against a smaller v head), experts through the grouped matmul,
+    held to: finite falling loss, nothing compiled after warm-up, the
+    kernels in the compiled step, no assignment left out."""
     from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.nn.linear_attention_layers import (chunk_kda,
+                                                               chunk_kda_xla)
+    from deeplearning4j_tpu.ops.pallas.chunk_kda import chunk_kda_compatible
     from deeplearning4j_tpu.train.listeners import CollectScoresListener
     from deeplearning4j_tpu.zoo import KimiLinear
+
+    # the layer's operands: unit q (scaled) and k, log-decay <= 0, beta in (0, 1)
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    d = p.kimi["kda_head_dim"]
+    shape = (p.kimi_batch, p.kimi_seq, 2, d)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    q = (unit(jax.random.normal(ks[0], shape)) * d ** -0.5).astype(jnp.bfloat16)
+    k = unit(jax.random.normal(ks[1], shape)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], shape).astype(jnp.bfloat16)
+    g = -jax.random.uniform(ks[3], shape, maxval=0.5)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))
+    assert chunk_kda_compatible(q, v), "chunk_kda: the kernel pair is ineligible"
+    tol = lambda s: 0.05 * max(s, 1.0)                          # noqa: E731
+    delta_rule = _compare(
+        "chunk_kda", chunk_kda, chunk_kda_xla, (q, k, v, g, beta),
+        tuple(x.astype(jnp.float32) for x in (q, k, v)) + (g, beta),
+        (0, 1, 2, 3, 4), p, tol, tol, calls=2)
 
     net = KimiLinear.tiny(**p.kimi).init()
     scores = CollectScoresListener()
@@ -535,14 +558,18 @@ def check_kimi_linear(p: Preset) -> Dict[str, Any]:
     compiled = step.lower(
         packer.pack_device(net.train_state), jnp.asarray(batch.features),
         jnp.asarray(batch.labels), jax.random.PRNGKey(0), None, None).compile()
-    # flash forward and its two backward passes, at the least
-    n_calls = _mosaic_calls(compiled, p, 3, "KimiLinear train step")
+    # flash forward and its two backward passes and the delta rule's pair
+    # in every KDA layer, at the least
+    n_calls = _mosaic_calls(compiled, p, 5, "KimiLinear train step")
     if p.expect_mosaic:
-        assert "flash_attention_fwd" in compiled.as_text(), \
-            "KimiLinear train step: the flash kernel was routed around"
+        text = compiled.as_text()
+        for kernel in ("flash_attention_fwd", "chunk_kda_fwd", "chunk_kda_bwd"):
+            assert kernel in text, \
+                f"KimiLinear train step: {kernel} was routed around"
     _log(f"  KimiLinear train step: mosaic_calls={n_calls}")
     return {"steps": len(losses), "first_loss": losses[0],
-            "last_loss": losses[-1], "mosaic_calls": n_calls}
+            "last_loss": losses[-1], "mosaic_calls": n_calls,
+            "chunk_kda": delta_rule}
 
 
 # ----------------------------------------------------------- phase 4: server

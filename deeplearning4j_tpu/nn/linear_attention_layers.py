@@ -7,10 +7,8 @@ Per head, with a state ``S`` of (d_k, d_v) that starts at zero::
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t = S_t^T q_t
 
-``chunk_kda`` computes it a chunk of tokens at a time in XLA ops (no Pallas
-kernel: the layer's ``kda_scan`` scope is where one would be measured
-against this). Within a chunk, with ``G`` the running sum of ``g`` from the
-chunk's start::
+``chunk_kda`` computes it a chunk of tokens at a time. Within a chunk, with
+``G`` the running sum of ``g`` from the chunk's start::
 
     A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)      (i > j)
     B_ij =        sum_c q_ic k_jc exp(G_ic - G_jc)      (i >= j)
@@ -22,6 +20,15 @@ No exponent is ever positive: ``A`` and ``B`` are built from sub-blocks of
 block's first token (a matmul), a diagonal one from the differences
 ``G_i - G_j`` themselves, so a decay of any strength neither overflows nor
 loses a small term against a large one.
+
+Two forms of the one algorithm share these equations and the constants
+``CHUNK`` and ``SUB``. ``chunk_kda_xla`` is the XLA form: the CPU path, the
+oracle of the kernel's tests and what the kernel is measured against. The
+Pallas kernel pair ``chunk_kda_fwd`` / ``chunk_kda_bwd``
+(``ops/pallas/chunk_kda.py``) carries the state in VMEM from chunk to chunk;
+``chunk_kda`` takes it by what it can see in its input (a platform with
+kernels, head sizes that are lane tiles, whole chunks, a block that fits
+VMEM): no option, no environment variable.
 """
 
 from __future__ import annotations
@@ -34,10 +41,9 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.attention_layers import rms_norm, scoped
 from deeplearning4j_tpu.nn.base import GlobalConfig, Layer, register_layer
 from deeplearning4j_tpu.ops.initializers import init_weights
+from deeplearning4j_tpu.ops.pallas.chunk_kda import CHUNK, SUB, chunk_kda_compatible, chunk_kda_pallas
 
-CHUNK = 64   # tokens a step of the carried state (the published kernel's)
-SUB = 16     # rows of a sub-block inside a chunk
-GROUP = 16   # chunks worked on together: bounds what the backward pass holds
+GROUP = 16   # chunks the XLA form works on together: bounds what its backward pass holds
 
 
 def _intra_chunk(q, k, G, beta, mm):
@@ -108,20 +114,33 @@ def chunk_kda(q, k, v, g, beta, chunk: int = CHUNK):
     ``beta`` (b, t, h) in (0, 1). Returns (b, t, h, d_v) in ``v``'s dtype.
 
     Matmul operands take ``q``'s dtype and accumulate in float32; the state,
-    the decays and the triangular solve are float32. One ``lax.scan`` over
-    groups of ``GROUP`` chunks, its body under ``jax.checkpoint``: the
-    backward pass keeps the state at every group and recomputes inside one.
-
-    Scopes: call this directly under the layer's scope. The re-layouts are
-    ``kda_scan``; the scan itself is opened under no scope of its own, so
-    that the device trace names the ops of its body ``<layer>/while/...``
-    and the ``while`` op, whose span on the device covers all of them a
-    second time, plain ``<layer>``: a table that adds device ops up by their
-    first two scopes then shows the recurrence once, under ``while``, and its
-    duplicate in the row of the bare layer (docs/observability.md)."""
+    the decays and the triangular solve are float32. The kernel pair where
+    ``chunk_kda_compatible`` takes the call, under the ``kda_scan`` scope
+    (forward and backward kernel alike), else ``chunk_kda_xla``."""
     b, t, h, _ = q.shape
     if t % chunk:
         raise ValueError(f"chunk_kda: {t} tokens are no multiple of the chunk of {chunk}")
+    if not chunk_kda_compatible(q, v, chunk):
+        return chunk_kda_xla(q, k, v, g, beta, chunk)
+    flat = lambda a: a.reshape(b, t, -1)  # the projections' own layout: no copy
+    with jax.named_scope("kda_scan"):
+        o = chunk_kda_pallas(flat(q), flat(k), flat(v), flat(g.astype(jnp.float32)), beta.astype(jnp.float32), h)
+    return o.reshape(b, t, h, -1)
+
+
+def chunk_kda_xla(q, k, v, g, beta, chunk: int = CHUNK):
+    """``chunk_kda`` in XLA ops. One ``lax.scan`` over groups of ``GROUP``
+    chunks, its body under ``jax.checkpoint``: the backward pass keeps the
+    state at every group and recomputes inside one.
+
+    Scopes: the re-layouts are ``kda_scan``; the scan itself is opened under
+    no scope of its own, so that the device trace names the ops of its body
+    ``<layer>/while/...`` and the ``while`` op, whose span on the device
+    covers all of them a second time, plain ``<layer>``: a table that adds
+    device ops up by their first two scopes then shows the recurrence once,
+    under ``while``, and its duplicate in the row of the bare layer
+    (docs/observability.md)."""
+    b, t, h, _ = q.shape
     n = t // chunk
     group = GROUP if n % GROUP == 0 else 1
 
@@ -204,7 +223,7 @@ class KimiDeltaAttention(Layer):
 
     def forward(self, params, state, x, *, training=False, rng=None, mask=None):
         q, k, v, g, beta, gate = scoped("kda_in", self._in, params, x)
-        o = chunk_kda(q, k, v, g, beta)  # opens its own scopes: ``kda_scan`` and, for the scan's body, ``while``
+        o = chunk_kda(q, k, v, g, beta)  # opens its own scope, ``kda_scan`` (the XLA form also ``while``)
         return scoped("kda_out", self._out, params, o, gate), state
 
     def regularizable_params(self):

@@ -83,6 +83,8 @@ def test_checks_rehearse_at_a_tiny_preset(tmp_path, monkeypatch):
                                      "bert_serve", "kimi_linear",
                                      "four_chips"}
     assert report["kimi_linear"]["last_loss"] < report["kimi_linear"]["first_loss"]
+    # the delta rule's kernel pair (interpreted here) against its XLA form
+    assert report["kimi_linear"]["chunk_kda"]["bwd_err"] < 0.05
     assert set(report["kernels"]) == {
         "fused_lstm", "fused_lstm_graves", "fused_gru", "flash_padding_mask",
         "flash_causal", "flash_causal_chunked", "fused_attention"}

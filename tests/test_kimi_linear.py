@@ -28,7 +28,9 @@ from benchmark import run as bench  # noqa: E402
 from deeplearning4j_tpu.nn import (DecoderBlock, GatedMLP, InputType, KimiDeltaAttention,  # noqa: E402
                                    LatentAttention, MixtureOfExperts)
 from deeplearning4j_tpu.nn.base import GlobalConfig, Layer  # noqa: E402
-from deeplearning4j_tpu.nn.linear_attention_layers import chunk_kda  # noqa: E402
+from deeplearning4j_tpu.nn import linear_attention_layers  # noqa: E402
+from deeplearning4j_tpu.nn.linear_attention_layers import chunk_kda, chunk_kda_xla  # noqa: E402
+from deeplearning4j_tpu.ops.pallas.chunk_kda import CHUNK, chunk_kda_compatible  # noqa: E402
 from deeplearning4j_tpu.runtime.environment import get_environment  # noqa: E402
 from deeplearning4j_tpu.zoo import KimiLinear  # noqa: E402
 
@@ -109,15 +111,7 @@ def test_chunked_delta_rule_matches_the_token_recurrence(tokens, decay):
     matters (0.05 a token: a state survives a chunk at e^-3) and one so
     strong (3 a token, e^-192 over a chunk) that a decay factored through
     the chunk's first token would overflow."""
-    ks = jax.random.split(jax.random.PRNGKey(tokens), 5)
-    b, h, d = 2, 3, 16
-    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(ks[0], (b, tokens, h, d))) * d ** -0.5
-    k = unit(jax.random.normal(ks[1], (b, tokens, h, d)))
-    v = jax.random.normal(ks[2], (b, tokens, h, d))
-    g = -jax.random.uniform(ks[3], (b, tokens, h, d), minval=0.0, maxval=decay)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, tokens, h)))
-    args = (q, k, v, g, beta)
+    args = delta_rule_inputs(tokens, decay)
     want = FAMILY.delta_rule(*args)
     close(chunk_kda(*args), want, 2e-5)
     if tokens > 64 and decay < 1:  # the state carried over a chunk's edge is a visible part of the output
@@ -125,6 +119,74 @@ def test_chunked_delta_rule_matches_the_token_recurrence(tokens, decay):
         assert float(jnp.max(jnp.abs(first - want[:, 128:]))) > 1e-3
     grads = lambda f: jax.grad(lambda *a: jnp.sum(jnp.sin(3 * f(*a))), argnums=(0, 1, 2, 3, 4))(*args)
     trees_close(grads(chunk_kda), grads(FAMILY.delta_rule), 1e-4)
+
+
+def delta_rule_inputs(tokens, decay, b=2, h=3, d=16, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(tokens), 5)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (b, tokens, h, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, tokens, h, d)))
+    v = jax.random.normal(ks[2], (b, tokens, h, d))
+    g = -jax.random.uniform(ks[3], (b, tokens, h, d), minval=0.0, maxval=decay)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, tokens, h)))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def kernel_calls(monkeypatch):
+    """The list that grows by one with every call ``chunk_kda`` routes to the kernel pair."""
+    calls = []
+    real = linear_attention_layers.chunk_kda_pallas
+    monkeypatch.setattr(linear_attention_layers, "chunk_kda_pallas", lambda *a: calls.append(1) or real(*a))
+    return calls
+
+
+@pytest.mark.parametrize("tokens,decay", [(192, 0.05), (512, 3.0)])
+def test_the_delta_rule_kernel_matches_the_xla_form_and_the_token_recurrence(tokens, decay, monkeypatch):
+    """The Pallas pair under the interpreter at the published head size (128)
+    against the XLA form and against the family's token recurrence, outputs
+    and all five gradients: three blocks of one chunk with a weak decay (the
+    state carried from block to block in VMEM is a visible part of the
+    output), two blocks of four chunks with a strong one (3 a token). One
+    chunk and one block: ``tests/test_pallas.py``."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    calls = kernel_calls(monkeypatch)
+    args = delta_rule_inputs(tokens, decay, b=2, h=2, d=128)
+    assert chunk_kda_compatible(args[0], args[2])
+    got, xla, want = chunk_kda(*args), chunk_kda_xla(*args), FAMILY.delta_rule(*args)
+    assert calls
+    close(got, xla, 2e-5)
+    close(got, want, 2e-5)
+    if tokens == 192:
+        later = FAMILY.delta_rule(*(a[:, 128:] for a in args))
+        assert float(jnp.max(jnp.abs(later - want[:, 128:]))) > 1e-3
+    grads = lambda f: jax.grad(lambda *a: jnp.sum(jnp.sin(3 * f(*a))), argnums=(0, 1, 2, 3, 4))(*args)
+    ours = grads(chunk_kda)
+    trees_close(ours, grads(chunk_kda_xla), 1e-4)
+    trees_close(ours, grads(FAMILY.delta_rule), 1e-4)
+
+
+@pytest.mark.parametrize("why,d,tokens,chunk,interpreter,takes", [
+    ("the cell's head size, whole chunks", 128, 128, CHUNK, True, True),
+    ("a head that is no lane tile", 16, 128, CHUNK, True, False),
+    ("tokens that are no whole chunks", 128, 96, CHUNK, True, False),
+    ("another chunk than the kernel's", 128, 128, 32, True, False),
+    ("no kernels on this platform", 128, 128, CHUNK, False, False)])
+def test_chunk_kda_takes_the_kernel_by_shape_and_platform_alone(why, d, tokens, chunk, interpreter, takes, monkeypatch):
+    if interpreter:
+        monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET", raising=False)
+    args = delta_rule_inputs(tokens, 0.05, b=1, h=1, d=d)
+    assert chunk_kda_compatible(args[0], args[2], chunk) is takes, why
+    entered = kernel_calls(monkeypatch)
+    if tokens % chunk:
+        with pytest.raises(ValueError, match="no multiple of the chunk"):
+            chunk_kda(*args, chunk=chunk)
+    else:
+        got = chunk_kda(*args, chunk=chunk)
+        assert bool(entered) is takes, why
+        if not takes:  # the XLA form itself, to the bit
+            np.testing.assert_array_equal(got, chunk_kda_xla(*args, chunk=chunk))
 
 
 def test_kda_layer_matches_the_reference():

@@ -2,6 +2,7 @@
 on TPU by the benchmarks)."""
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -856,3 +857,76 @@ def test_fused_attention_costs_no_layout_copy_in_a_bert_step(v5e_chip, monkeypat
               if re.match(r"\s+%?copy[.\d]* = bf16\[32,(512,768|768,512)\]", line)]
     beside_a_matmul = [line for line in copies if "dot_general" in line]
     assert not beside_a_matmul, beside_a_matmul[0][:400]
+
+
+@pytest.mark.parametrize("tokens,why", [(64, "one chunk, a block of one"), (256, "one block of four chunks")])
+def test_chunk_kda_kernel_pair_matches_the_xla_form(tokens, why):
+    """``chunk_kda_fwd`` / ``chunk_kda_bwd`` under the interpreter at the
+    published head size against the XLA form of the same algorithm, outputs
+    and all five gradients, two rows and two heads (the heads' states share
+    one VMEM scratch). Several blocks, a strong decay and the token
+    recurrence: ``tests/test_kimi_linear.py``."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.linear_attention_layers import chunk_kda, chunk_kda_xla
+    from deeplearning4j_tpu.ops.pallas.chunk_kda import chunk_kda_compatible
+    ks = jax.random.split(jax.random.PRNGKey(tokens), 5)
+    shape = (2, tokens, 2, 128)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    args = (unit(jax.random.normal(ks[0], shape)) * 128 ** -0.5, unit(jax.random.normal(ks[1], shape)),
+            jax.random.normal(ks[2], shape), -jax.random.uniform(ks[3], shape, maxval=0.05),
+            jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+    assert chunk_kda_compatible(args[0], args[2]), why
+
+    def both(f):
+        return jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(3 * f(*a))), argnums=(0, 1, 2, 3, 4))(*args)
+
+    for got, want in zip(jax.tree.leaves(both(chunk_kda)), jax.tree.leaves(both(chunk_kda_xla))):
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-4 * float(jnp.max(jnp.abs(want))))
+
+
+def _delta_rule_shapes(v5e_chip, tokens=8192, heads=32, d=128):
+    import jax
+    import jax.numpy as jnp
+    x = jax.ShapeDtypeStruct((1, tokens, heads, d), jnp.bfloat16, sharding=v5e_chip)
+    g = jax.ShapeDtypeStruct((1, tokens, heads, d), jnp.float32, sharding=v5e_chip)
+    beta = jax.ShapeDtypeStruct((1, tokens, heads), jnp.float32, sharding=v5e_chip)
+    return x, x, x, g, beta
+
+
+@pytest.mark.parametrize("layers,compile_it", [(1, True), (4, False)])
+def test_chunk_kda_at_the_kimi_cells_shape_for_v5e(v5e_chip, monkeypatch, layers, compile_it):
+    """The gated delta rule at 1 x 8192 x (32 x 128) bf16, forward and
+    backward. One call, through Mosaic: exactly the two kernels, no XLA loop
+    left. Four calls, as the cell's four KDA layers make them, lowered only:
+    **a guard on set-up that is a size, not a time.** A ``pallas_call`` is
+    traced and lowered to a Mosaic module in every process before the
+    compile cache's key exists, so what the step's module holds of it is
+    paid in every warm start: the lowered text of the gradient through four
+    layers holds each kernel's module once (the layers share one traced
+    function) in 52,657 bytes, where the XLA form's eight loop bodies take
+    1,086,758 (PR 32). A kernel body that unrolls its chunks, or an entry
+    that is traced again at every site, fails here and not in the driver's
+    check of ``setup_s``."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.linear_attention_layers import chunk_kda
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the route's platform probe sees the described chip
+
+    def loss(q, k, v, g, beta):
+        for _ in range(layers):
+            v = chunk_kda(q, k, v, g, beta)
+        return jnp.sum(v.astype(jnp.float32) ** 2)
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*_delta_rule_shapes(v5e_chip))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 2 and "stablehlo.while" not in text
+    assert len(text) < 120_000
+    if compile_it:
+        hlo = lowered.compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+        assert "chunk_kda_fwd" in hlo and "chunk_kda_bwd" in hlo
+        assert not re.search(r" while\(", hlo)
