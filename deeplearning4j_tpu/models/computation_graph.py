@@ -35,15 +35,14 @@ from deeplearning4j_tpu.nn.base import GlobalConfig, Layer
 from deeplearning4j_tpu.nn.core_layers import LossLayer, OutputLayer
 from deeplearning4j_tpu.nn.graph_vertices import GraphVertex
 from deeplearning4j_tpu.nn.inputs import InputType
-from deeplearning4j_tpu.models.multi_layer_network import TrainState, _mask_keys
 from deeplearning4j_tpu.nn.base import cast_floating
 from deeplearning4j_tpu.models._tbptt import (carry_dtype, is_sequence_array,
                                                seq_length, slice_time)
 from deeplearning4j_tpu.nn.recurrent_layers import BaseRecurrentLayer
 from deeplearning4j_tpu.runtime.environment import get_environment
-from deeplearning4j_tpu.runtime.rng import RngManager
-from deeplearning4j_tpu.train.listeners import TrainingListener
-from deeplearning4j_tpu.train.updaters import Sgd, Updater, gradient_normalization_transform
+from deeplearning4j_tpu.train.fit_engine import TrainEngine, TrainState
+from deeplearning4j_tpu.train.solvers import graph_solver_fit_batch
+from deeplearning4j_tpu.train.updaters import Updater
 
 
 @dataclasses.dataclass
@@ -226,44 +225,13 @@ class ComputationGraphConfiguration:
         return ComputationGraphConfiguration.from_dict(json.loads(s))
 
 
-def _cg_group_compatible(a, b) -> bool:
-    """Whether two buffered (inputs, labels, rng, masks) tuples may share
-    one unrolled dispatch: same input/label shapes and mask presence."""
-    ia, la, _, ma = a
-    ib, lb, _, mb = b
-    if set(ia) != set(ib) or len(la) != len(lb):
-        return False
-    if any(ia[n].shape != ib[n].shape for n in ia):
-        return False
-    if any(x.shape != y.shape for x, y in zip(la, lb)):
-        return False
-    if (ma is None) != (mb is None):
-        return False
-    if ma is not None:
-        if set(ma) != set(mb):
-            return False
-        for n in ma:
-            if (ma[n] is None) != (mb[n] is None):
-                return False
-            if ma[n] is not None and ma[n].shape != mb[n].shape:
-                return False
-    return True
-
-
-class ComputationGraph:
+class ComputationGraph(TrainEngine):
     def __init__(self, conf: ComputationGraphConfiguration):
+        super().__init__(conf.global_conf.seed)
         self.conf = conf
         for n in conf.nodes:
             if n.kind == "layer":
                 n.obj._g = conf.global_conf
-        self.rng = RngManager(conf.global_conf.seed)
-        self.train_state: Optional[TrainState] = None
-        self._listeners: List[TrainingListener] = []
-        self._iteration = 0
-        self._epoch = 0
-        self._score = float("nan")
-        self._tx: Optional[optax.GradientTransformation] = None
-        self._jit_cache: Dict[str, Any] = {}
         self._remat_segs: Optional[List[List[str]]] = None
 
     @property
@@ -306,34 +274,8 @@ class ComputationGraph:
         self._rnn_carries = None  # stale hidden state must not cross inits
         return self
 
-    def _build_tx(self, params) -> optax.GradientTransformation:
-        g = self.conf.global_conf
-        default_updater: Updater = g.updater if g.updater is not None else Sgd(0.1)
-        transforms, labels = {}, {}
-        for n in self.conf.nodes:
-            if n.kind != "layer" or n.name not in params:
-                continue
-            layer = n.obj
-            if layer.frozen:
-                tx = optax.set_to_zero()
-            else:
-                upd = layer.updater if layer.updater is not None else default_updater
-                chain = []
-                gn = gradient_normalization_transform(
-                    g.gradient_normalization, g.gradient_normalization_threshold)
-                if gn is not None:
-                    chain.append(gn)
-                chain.append(upd.make())
-                wd = layer.weight_decay if layer.weight_decay is not None else g.weight_decay
-                if wd:
-                    from deeplearning4j_tpu.train.updaters import decoupled_weight_decay
-                    reg = set(layer.regularizable_params())
-                    chain.append(decoupled_weight_decay(
-                        wd, upd._lr(), mask=lambda p, rk=reg: _mask_keys(p, rk)))
-                tx = optax.chain(*chain) if len(chain) > 1 else chain[0]
-            transforms[n.name] = tx
-            labels[n.name] = jax.tree.map(lambda _: n.name, params[n.name])
-        return optax.multi_transform(transforms, labels)
+    def _named_layers(self):
+        return [(n.name, n.obj) for n in self.conf.nodes if n.kind == "layer"]
 
     # --------------------------------------------------------------- forward
     def _exec_node(self, i: int, name: str, acts, last_inputs, new_state,
@@ -554,123 +496,7 @@ class ComputationGraph:
                     total = total + s2["_aux_loss"]
         return total, (new_state, new_carries)
 
-    def _reg_score(self, params):
-        g = self.conf.global_conf
-        total = jnp.zeros((), jnp.float32)
-        for n in self.conf.nodes:
-            if n.kind != "layer" or n.name not in params:
-                continue
-            layer = n.obj
-            l1 = layer.l1 if layer.l1 is not None else g.l1
-            l2 = layer.l2 if layer.l2 is not None else g.l2
-            if not l1 and not l2:
-                continue
-            reg_keys = set(layer.regularizable_params())
-            for path, w in jax.tree_util.tree_flatten_with_path(params[n.name])[0]:
-                if any(getattr(p, "key", None) in reg_keys for p in path):
-                    if l1:
-                        total = total + l1 * jnp.sum(jnp.abs(w))
-                    if l2:
-                        total = total + 0.5 * l2 * jnp.sum(w * w)
-        return total
-
     # ------------------------------------------------------------ train/fit
-    def _apply_constraints(self, params):
-        """Post-update projections (reference applyConstraints)."""
-        from deeplearning4j_tpu.nn.constraints import apply_layer_constraints
-        layer_nodes = [n for n in self.conf.topo_order
-                       if self.conf.node(n).kind == "layer"]
-        if not any(getattr(self.conf.node(n).obj, "constraints", None)
-                   or getattr(self.conf.node(n).obj, "bias_constraints", None)
-                   for n in layer_nodes):
-            return params
-        out = dict(params)
-        for n in layer_nodes:
-            if n in out:
-                out[n] = apply_layer_constraints(self.conf.node(n).obj, out[n])
-        return out
-
-    def _train_step_fn(self):
-        # named anew with the scopes: see MultiLayerNetwork._train_step_fn
-        def graph_train_step(ts: TrainState, inputs, labels, rng, masks):
-            (loss, (new_state, _)), grads = jax.value_and_grad(
-                self._loss, has_aux=True)(
-                ts.params, ts.model_state, inputs, labels, rng, masks)
-            with jax.named_scope("updater"):
-                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-                new_params = self._apply_constraints(
-                    optax.apply_updates(ts.params, updates))
-            return TrainState(params=new_params, model_state=new_state,
-                              opt_state=new_opt, step=ts.step + 1), loss
-
-        return graph_train_step
-
-    def _make_train_step(self):
-        return jax.jit(self._train_step_fn(), donate_argnums=(0,))
-
-    def _make_packed_train_step(self):
-        """Train step with flat-packed small leaves at the jit boundary
-        (see :mod:`deeplearning4j_tpu.runtime.state_packing`): same math,
-        bit-identical results, ~4x fewer buffer handles per dispatch."""
-        from deeplearning4j_tpu.runtime.state_packing import LeafPacker
-        packer = LeafPacker(self.train_state)
-        raw = self._train_step_fn()
-
-        def packed_graph_train_step(pts, inputs, labels, rng, masks):
-            new_ts, loss = raw(packer.unpack(pts), inputs, labels, rng, masks)
-            return packer.pack(new_ts), loss
-
-        return jax.jit(packed_graph_train_step, donate_argnums=(0,)), packer
-
-    def _make_tbptt_step(self):
-        """Train step carrying recurrent state across truncated chunks
-        (reference: tBPTT on ComputationGraph)."""
-        def tbptt_graph_train_step(ts: TrainState, carries, inputs, labels, rng, masks):
-            (loss, (new_state, new_carries)), grads = jax.value_and_grad(
-                self._loss, has_aux=True)(
-                ts.params, ts.model_state, inputs, labels, rng, masks,
-                True, carries)
-            with jax.named_scope("updater"):
-                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-                new_params = optax.apply_updates(ts.params, updates)
-            new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
-            return (TrainState(params=new_params, model_state=new_state,
-                               opt_state=new_opt, step=ts.step + 1),
-                    new_carries, loss)
-
-        return jax.jit(tbptt_graph_train_step, donate_argnums=(0, 1))
-
-    def _jitted(self, name, factory):
-        # remat is read at TRACE time, so flipping env.set_remat() must
-        # invalidate previously jitted steps — key the cache on the flag.
-        key = f"{name}@remat={get_environment().remat_segments}"
-        if key not in self._jit_cache:
-            self._jit_cache[key] = factory()
-        return self._jit_cache[key]
-
-    def _packed_cache_key(self) -> str:
-        return f"packed_train_step@remat={get_environment().remat_segments}"
-
-    def _jitted_packed(self):
-        # keyed directly by _packed_cache_key so the invalidation path in
-        # PackedStepLoop.step pops the SAME key this populates
-        key = self._packed_cache_key()
-        if key not in self._jit_cache:
-            self._jit_cache[key] = self._make_packed_train_step()
-        return self._jit_cache[key]
-
-    def _jitted_packed_unrolled(self, k: int):
-        """K same-shape batches per device dispatch (env.dispatch_unroll);
-        shares the single-step packer (see MultiLayerNetwork)."""
-        key = f"{self._packed_cache_key()}@unroll={k}"
-        if key not in self._jit_cache:
-            from deeplearning4j_tpu.runtime.state_packing import (
-                make_unrolled_packed_step)
-            _, packer = self._jitted_packed()
-            self._jit_cache[key] = make_unrolled_packed_step(
-                self._train_step_fn(), packer, k)
-        return self._jit_cache[key]
-
     def _coerce_batch(self, batch) -> Tuple[Dict[str, Any], List[Any], Optional[Dict]]:
         from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
         if isinstance(batch, MultiDataSet):
@@ -689,147 +515,40 @@ class ComputationGraph:
             masks = {self.conf.outputs[0]: jnp.asarray(ds.labels_mask)}
         return inputs, labels, masks
 
+    def _prepare_batch(self, batch):
+        args = self._coerce_batch(batch)
+        return args, next(iter(args[0].values())).shape[0]
+
     def fit(self, data, labels=None, epochs: int = 1,
             prefetch_buffer: int = 0, profiler=None) -> "ComputationGraph":
         """``prefetch_buffer > 0`` stages coerced batches on-device ahead of
         the step (``train.prefetch.DevicePrefetcher``; trajectory
         bit-identical to the synchronous loop); ``profiler`` takes a
         :class:`~deeplearning4j_tpu.train.profiler.TrainingProfiler`."""
-        if self.train_state is None:
-            self.init()
-        if labels is not None:
-            from deeplearning4j_tpu.data.dataset import DataSet
-            from deeplearning4j_tpu.data.iterators import ListDataSetIterator
-            iterator = ListDataSetIterator(
-                [DataSet(np.asarray(data), np.asarray(labels))], batch_size=len(data))
-        else:
-            iterator = data
-        from deeplearning4j_tpu.runtime.state_packing import (GroupedDispatch,
-                                                               PackedStepLoop)
-        from deeplearning4j_tpu.train.prefetch import (AsyncLossDelivery,
-                                                       stateless_listeners)
-        from deeplearning4j_tpu.train.profiler import sync_timed
-        ploop = PackedStepLoop.for_network(self)
-        if profiler is not None:
-            profiler.start()
+        return self._fit(data, labels, epochs, prefetch_buffer, profiler)
 
-        def deliver(_n, loss):
-            self._score = loss
-            self._iteration += 1
-            for lst in self._listeners:
-                lst.iteration_done(self, self._iteration, self._epoch, loss)
+    _solver_fit_batch = graph_solver_fit_batch  # (net, inputs, labels, masks) -> loss
 
-        # async loss readback (see MultiLayerNetwork._fit_epochs): listener
-        # delivery moves to a completion thread when every listener is
-        # stateless — same callbacks, same order, no dispatch stall; no
-        # listeners and no profiler = deliver inline, no thread
-        adel = (AsyncLossDelivery(deliver, profiler=profiler)
-                if (self._listeners or profiler is not None)
-                and stateless_listeners(self) else None)
-        # nothing but the loss crosses into the delivery queue — queued step
-        # args would pin full device batches for up to max_pending steps
-        sink = adel.submit if adel is not None else deliver
-        gd = GroupedDispatch(
-            # with a state-reading listener, packing is off and batches must
-            # dispatch one at a time so iteration_done sees fresh state
-            unroll=(get_environment().dispatch_unroll if ploop.enabled else 1),
-            compatible=_cg_group_compatible,
-            run_single=lambda a: ploop.step(*a)[0],
-            run_group=ploop.step_group,
-            deliver=lambda args, loss: sink(None, loss))
-        try:
-            try:
-                self._fit_epochs(
-                    iterator, int(epochs), ploop, gd,
-                    drain=(adel.flush if adel is not None else (lambda: None)),
-                    prefetch_buffer=int(prefetch_buffer), profiler=profiler)
-            finally:
-                gd.drain_on_error()
-                if adel is not None:
-                    adel.shutdown()  # never raises; original errors win
-        finally:
-            # any exit path (incl. KeyboardInterrupt / iterator errors) must
-            # leave train_state reflecting every completed step
-            sync_timed(ploop, profiler)
-            if profiler is not None:
-                profiler.stop()
-        if adel is not None:
-            adel.raise_pending()
-        return self
-
-    def _fit_epochs(self, iterator, epochs: int, ploop, gd,
-                    drain=lambda: None, prefetch_buffer: int = 0,
-                    profiler=None) -> None:
-        from deeplearning4j_tpu.train.prefetch import batch_source
-        from deeplearning4j_tpu.train.profiler import (drain_timed,
-                                                        submit_timed)
-        for _ in range(epochs):
-            for lst in self._listeners:
-                lst.on_epoch_start(self, self._epoch)
-            src = batch_source(iterator, self._coerce_batch,
-                               prefetch_buffer, profiler)
-            try:
-                for inputs, labels_, masks in src:
-                    algo = self.conf.global_conf.optimization_algo
-                    if self.conf.tbptt_fwd_length and any(
-                            is_sequence_array(v) for v in inputs.values()):
-                        if algo != "STOCHASTIC_GRADIENT_DESCENT":
-                            raise NotImplementedError(
-                                "tBPTT training with optimization_algo="
-                                f"{algo!r} is not supported; use SGD or full-"
-                                "sequence BPTT")
-                        gd.flush()
-                        drain()  # tBPTT notifies listeners inline (ordered)
-                        ploop.sync(release=True)  # tBPTT mutates train_state
-                        self._fit_tbptt(inputs, labels_, masks)
-                        continue
-                    if algo != "STOCHASTIC_GRADIENT_DESCENT":
-                        from deeplearning4j_tpu.train.solvers import (
-                            graph_solver_fit_batch)
-                        gd.flush()
-                        ploop.sync(release=True)  # solver mutates train_state
-                        loss = graph_solver_fit_batch(self, inputs, labels_, masks)
-                        gd._deliver((inputs, labels_, None, masks), loss)
-                        continue
-                    submit_timed(
-                        gd, self.rng,
-                        lambda key: (inputs, labels_, key, masks), profiler)
-            finally:
-                src.close()
-            drain_timed(gd, drain, profiler)
-            # no epoch-end sync: packing only runs when every listener is
-            # stateless, so nothing reads train_state until fit() returns
-            for lst in self._listeners:
-                lst.on_epoch_end(self, self._epoch)
-            self._epoch += 1
-
-    def _fit_tbptt(self, inputs, labels_, masks):
-        """Chunk the time axis into tbptt-length windows, carrying hidden
-        state between them (reference: tBPTT on ComputationGraph)."""
+    def _tbptt_plan(self, inputs, labels_, masks):
+        """Zero carries, and the batch cut along its time axis into
+        tbptt-length chunks."""
         L = int(self.conf.tbptt_fwd_length)
         T = max(seq_length(v) for v in inputs.values() if is_sequence_array(v))
+
+        def chunks():
+            for t0 in range(0, T, L):
+                yield ({k: slice_time(v, t0, L) for k, v in inputs.items()},
+                       [y[:, t0:t0 + L] if hasattr(y, "ndim") and y.ndim == 3
+                        else y for y in labels_],
+                       None if masks is None else {
+                           k: (m[:, t0:t0 + L] if hasattr(m, "ndim")
+                               and m.ndim >= 2 and m.shape[1] == T else m)
+                           for k, m in masks.items()})
+
         first = next(iter(inputs.values()))
-        dt = carry_dtype(first, get_environment().compute_dtype)
-        carries = {
-            n.name: n.obj.init_carry(first.shape[0], dt)
-            for n in self.conf.nodes
-            if n.kind == "layer" and isinstance(n.obj, BaseRecurrentLayer)}
-        step_fn = self._jitted("tbptt_step", self._make_tbptt_step)
-        for t0 in range(0, T, L):
-            ci = {k: slice_time(v, t0, L) for k, v in inputs.items()}
-            cl = [y[:, t0:t0 + L] if hasattr(y, "ndim") and y.ndim == 3 else y
-                  for y in labels_]
-            cm = None if masks is None else {
-                k: (m[:, t0:t0 + L] if hasattr(m, "ndim") and m.ndim >= 2
-                    and m.shape[1] == T else m)
-                for k, m in masks.items()}
-            rng = self.rng.next_key()
-            self.train_state, carries, loss = step_fn(
-                self.train_state, carries, ci, cl, rng, cm)
-            self._score = loss
-            self._iteration += 1
-            for lst in self._listeners:
-                lst.iteration_done(self, self._iteration, self._epoch, loss)
+        return self._rnn_zero_carries(
+            first.shape[0],
+            carry_dtype(first, get_environment().compute_dtype)), chunks()
 
     # ------------------------------------------------------------- inference
     def output(self, *xs, training: bool = False):
@@ -1031,15 +750,6 @@ class ComputationGraph:
         return ev
 
     # -------------------------------------------------------------- plumbing
-    def set_listeners(self, *listeners: TrainingListener) -> None:
-        self._listeners = list(listeners)
-
-    def get_listeners(self):
-        return list(self._listeners)
-
-    def add_listeners(self, *listeners: TrainingListener) -> None:
-        self._listeners.extend(listeners)
-
     def clone(self) -> "ComputationGraph":
         net = ComputationGraph(
             ComputationGraphConfiguration.from_dict(self.conf.to_dict()))
@@ -1050,20 +760,6 @@ class ComputationGraph:
                 net.train_state,
                 model_state=jax.tree.map(jnp.copy, self.train_state.model_state))
         return net
-
-    def params(self):
-        return self.train_state.params if self.train_state else None
-
-    def set_params(self, params) -> None:
-        if self.train_state is None:
-            self.init(params=params)
-        else:
-            self.train_state = dataclasses.replace(self.train_state, params=params)
-
-    def num_params(self) -> int:
-        if self.train_state is None:
-            return 0
-        return int(sum(np.prod(p.shape) for p in jax.tree.leaves(self.train_state.params)))
 
     def save(self, path: str, save_updater: bool = True) -> None:
         from deeplearning4j_tpu.models.serializer import ModelSerializer

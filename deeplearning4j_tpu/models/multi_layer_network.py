@@ -20,7 +20,7 @@ TPU-first re-architecture (NOT a port — SURVEY.md §7.1):
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,53 +30,27 @@ import optax
 from deeplearning4j_tpu.nn.base import GlobalConfig, Layer
 from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.core_layers import LossLayer, OutputLayer
-from deeplearning4j_tpu.models._tbptt import (carry_dtype, is_sequence_array,
-                                               slice_time)
+from deeplearning4j_tpu.models._tbptt import carry_dtype, slice_time
 from deeplearning4j_tpu.nn.recurrent_layers import BaseRecurrentLayer
 from deeplearning4j_tpu.runtime.environment import get_environment
-from deeplearning4j_tpu.runtime.rng import RngManager
-from deeplearning4j_tpu.train.listeners import PerformanceListener, TrainingListener
-from deeplearning4j_tpu.train.updaters import Sgd, Updater, gradient_normalization_transform
-
-
-@jax.tree_util.register_dataclass
-@dataclasses.dataclass
-class TrainState:
-    """Donated training state: one pytree through the jitted step."""
-
-    params: Dict[str, Dict[str, jax.Array]]
-    model_state: Dict[str, Dict[str, jax.Array]]
-    opt_state: Any
-    step: jax.Array  # scalar int32
+from deeplearning4j_tpu.train.fit_engine import TrainEngine, TrainState
+from deeplearning4j_tpu.train.prefetch import coerce_training_batch
+from deeplearning4j_tpu.train.solvers import solver_fit_batch
+from deeplearning4j_tpu.train.updaters import Sgd, Updater
 
 
 def _layer_key(i: int, layer: Layer) -> str:
     return layer.name or f"layer_{i}"
 
 
-def _group_compatible(a, b) -> bool:
-    """Whether two buffered (x, y, rng, fm, lm) step tuples may share one
-    unrolled dispatch: same input/label shapes and mask presence."""
-    return (a[0].shape == b[0].shape and a[1].shape == b[1].shape
-            and (a[3] is None) == (b[3] is None)
-            and (a[4] is None) == (b[4] is None))
-
-
-class MultiLayerNetwork:
+class MultiLayerNetwork(TrainEngine):
     def __init__(self, conf: MultiLayerConfiguration):
+        super().__init__(conf.global_conf.seed)
         self.conf = conf
         self.layers: List[Layer] = conf.layers
         for l in self.layers:
             l._g = conf.global_conf
-        self.rng = RngManager(conf.global_conf.seed)
-        self.train_state: Optional[TrainState] = None
-        self._listeners: List[TrainingListener] = []
-        self._iteration = 0
-        self._epoch = 0
-        self._score = float("nan")
         self._rnn_carries: Optional[Dict[str, Any]] = None
-        self._tx: Optional[optax.GradientTransformation] = None
-        self._jit_cache: Dict[str, Any] = {}
 
     # ------------------------------------------------------------------ init
     def init(self, params: Optional[Dict] = None) -> "MultiLayerNetwork":
@@ -115,42 +89,9 @@ class MultiLayerNetwork:
         # Frozen layers keep params but receive zero updates (handled by labels)
         return params
 
-    def _layer_transform(self, layer) -> optax.GradientTransformation:
-        """The optax transform one layer's params train under — shared by
-        the standard per-layer-key multi_transform and the pipe executor's
-        stage-stacked trunk (``parallel/plan_exec.py``), so packed and
-        unpacked updates are the same math."""
-        g = self.conf.global_conf
-        default_updater: Updater = g.updater if g.updater is not None else Sgd(0.1)
-        if layer.frozen:
-            return optax.set_to_zero()
-        upd = layer.updater if layer.updater is not None else default_updater
-        chain = []
-        gn = gradient_normalization_transform(
-            g.gradient_normalization, g.gradient_normalization_threshold)
-        if gn is not None:
-            chain.append(gn)
-        chain.append(upd.make())
-        wd = layer.weight_decay if layer.weight_decay is not None else g.weight_decay
-        if wd:
-            # Decoupled decay AFTER the updater, scaled by the LR (the
-            # reference's WeightDecay with applyLR=true; AdamW-style).
-            from deeplearning4j_tpu.train.updaters import decoupled_weight_decay
-            reg_keys = set(layer.regularizable_params())
-            chain.append(decoupled_weight_decay(
-                wd, upd._lr(), mask=lambda p, rk=reg_keys: _mask_keys(p, rk)))
-        return optax.chain(*chain) if len(chain) > 1 else chain[0]
-
-    def _build_tx(self, params) -> optax.GradientTransformation:
-        transforms: Dict[str, optax.GradientTransformation] = {}
-        labels = {}
-        for i, layer in enumerate(self.layers):
-            k = _layer_key(i, layer)
-            if k not in params:
-                continue
-            transforms[k] = self._layer_transform(layer)
-            labels[k] = jax.tree.map(lambda _: k, params[k])
-        return optax.multi_transform(transforms, labels)
+    def _named_layers(self):
+        return [(_layer_key(i, layer), layer)
+                for i, layer in enumerate(self.layers)]
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, model_state, x, *, training: bool, rng,
@@ -256,128 +197,6 @@ class MultiLayerNetwork:
                 model_state.get(k, {}), jax.lax.stop_gradient(last_in), y)
         return loss, (new_state, new_carries)
 
-    def _reg_score(self, params):
-        """l1/l2 penalty (reference: score includes regularization terms).
-        Walks nested param trees (e.g. Bidirectional {'fwd': .., 'bwd': ..})
-        by path, matching the weight-decay mask semantics."""
-        g = self.conf.global_conf
-        total = jnp.zeros((), jnp.float32)
-        for i, layer in enumerate(self.layers):
-            k = _layer_key(i, layer)
-            if k not in params:
-                continue
-            l1 = layer.l1 if layer.l1 is not None else g.l1
-            l2 = layer.l2 if layer.l2 is not None else g.l2
-            if not l1 and not l2:
-                continue
-            reg_keys = set(layer.regularizable_params())
-            leaves = jax.tree_util.tree_flatten_with_path(params[k])[0]
-            for path, w in leaves:
-                if any(getattr(p, "key", None) in reg_keys for p in path):
-                    if l1:
-                        total = total + l1 * jnp.sum(jnp.abs(w))
-                    if l2:
-                        total = total + 0.5 * l2 * jnp.sum(w * w)
-        return total
-
-    # ------------------------------------------------------------ train step
-    def _apply_constraints(self, params):
-        """Post-update projections (reference applyConstraints) — pure ops
-        inside the same compiled step."""
-        from deeplearning4j_tpu.nn.constraints import apply_layer_constraints
-        if not any(getattr(l, "constraints", None)
-                   or getattr(l, "bias_constraints", None)
-                   for l in self.layers):
-            return params
-        out = dict(params)
-        for i, layer in enumerate(self.layers):
-            k = _layer_key(i, layer)
-            if k in out:
-                out[k] = apply_layer_constraints(layer, out[k])
-        return out
-
-    def _train_step_fn(self):
-        # Renamed with the scopes (ISSUE 26): the persistent cache key holds
-        # the module's name but not its metadata, so the old name would be
-        # served a scope-less executable from an older cache. A renamed
-        # scope needs a cleared cache (docs/observability.md, "Training").
-        def mln_train_step(ts: TrainState, x, y, rng, fmask, lmask):
-            (loss, (new_state, _)), grads = jax.value_and_grad(self._loss, has_aux=True)(
-                ts.params, ts.model_state, x, y, rng, fmask, lmask)
-            with jax.named_scope("updater"):
-                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-                new_params = self._apply_constraints(
-                    optax.apply_updates(ts.params, updates))
-            return TrainState(params=new_params, model_state=new_state,
-                              opt_state=new_opt, step=ts.step + 1), loss
-
-        return mln_train_step
-
-    def _make_train_step(self):
-        return jax.jit(self._train_step_fn(), donate_argnums=(0,))
-
-    def _make_packed_train_step(self):
-        """Train step whose boundary carries flat-packed small leaves
-        (see :mod:`deeplearning4j_tpu.runtime.state_packing`): same math,
-        bit-identical results, ~4x fewer buffer handles per dispatch."""
-        from deeplearning4j_tpu.runtime.state_packing import LeafPacker
-        packer = LeafPacker(self.train_state)
-        raw = self._train_step_fn()
-
-        def packed_train_step(pts, x, y, rng, fmask, lmask):
-            new_ts, loss = raw(packer.unpack(pts), x, y, rng, fmask, lmask)
-            return packer.pack(new_ts), loss
-
-        return jax.jit(packed_train_step, donate_argnums=(0,)), packer
-
-    def _make_tbptt_step(self):
-        """Train step with explicit recurrent carries (truncated BPTT)."""
-        def tbptt_train_step(ts: TrainState, carries, x, y, rng, fmask, lmask):
-            (loss, (new_state, new_carries)), grads = jax.value_and_grad(
-                self._loss, has_aux=True)(ts.params, ts.model_state, x, y, rng,
-                                          fmask, lmask, carries)
-            with jax.named_scope("updater"):
-                updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-                new_params = optax.apply_updates(ts.params, updates)
-            new_carries = jax.tree.map(jax.lax.stop_gradient, new_carries)
-            return (TrainState(params=new_params, model_state=new_state,
-                               opt_state=new_opt, step=ts.step + 1), new_carries, loss)
-
-        return jax.jit(tbptt_train_step, donate_argnums=(0, 1))
-
-    def _jitted(self, name: str, factory):
-        # remat is read at TRACE time, so flipping env.set_remat() must
-        # produce a different cache entry (same rule as ComputationGraph)
-        name = f"{name}@remat={get_environment().remat_segments}"
-        if name not in self._jit_cache:
-            self._jit_cache[name] = factory()
-        return self._jit_cache[name]
-
-    def _packed_cache_key(self) -> str:
-        return f"packed_train_step@remat={get_environment().remat_segments}"
-
-    def _jitted_packed_unrolled(self, k: int):
-        """K same-shape batches per device dispatch (env.dispatch_unroll).
-        Shares the single-step packer, so packed state flows between
-        grouped and single dispatches. (Mask presence needs no key
-        component: jit retraces on the None-vs-array pytree structure.)"""
-        key = f"{self._packed_cache_key()}@unroll={k}"
-        if key not in self._jit_cache:
-            from deeplearning4j_tpu.runtime.state_packing import (
-                make_unrolled_packed_step)
-            _, packer = self._jitted_packed()
-            self._jit_cache[key] = make_unrolled_packed_step(
-                self._train_step_fn(), packer, k)
-        return self._jit_cache[key]
-
-    def _jitted_packed(self):
-        # keyed directly by _packed_cache_key so the invalidation path in
-        # PackedStepLoop.step pops the SAME key this populates
-        key = self._packed_cache_key()
-        if key not in self._jit_cache:
-            self._jit_cache[key] = self._make_packed_train_step()
-        return self._jit_cache[key]
-
     # ------------------------------------------------------------------- fit
     def fit(self, data, labels=None, epochs: int = 1, mask=None,
             labels_mask=None, prefetch_buffer: int = 0,
@@ -394,147 +213,35 @@ class MultiLayerNetwork:
         (trajectory bit-identical to the synchronous loop); ``profiler``
         takes a :class:`~deeplearning4j_tpu.train.profiler.TrainingProfiler`
         that splits each iteration into data-wait/dispatch/step time."""
-        if self.train_state is None:
-            self.init()
-        if labels is not None:
-            from deeplearning4j_tpu.data.dataset import DataSet
-            from deeplearning4j_tpu.data.iterators import ListDataSetIterator
-            ds = DataSet(np.asarray(data), np.asarray(labels), features_mask=mask,
-                         labels_mask=labels_mask)
-            iterator = ListDataSetIterator([ds], batch_size=len(ds))
-        else:
-            iterator = data
-        from deeplearning4j_tpu.runtime.state_packing import PackedStepLoop
-        from deeplearning4j_tpu.train.profiler import sync_timed
-        ploop = PackedStepLoop.for_network(self)
-        if profiler is not None:
-            profiler.start()
-        try:
-            self._fit_epochs(iterator, int(epochs), ploop,
-                             prefetch_buffer=int(prefetch_buffer),
-                             profiler=profiler)
-        finally:
-            # any exit path (incl. KeyboardInterrupt / iterator errors) must
-            # leave train_state reflecting every completed step
-            sync_timed(ploop, profiler)
-            if profiler is not None:
-                profiler.stop()
-        return self
+        return self._fit(data, labels, epochs, prefetch_buffer, profiler,
+                         mask, labels_mask)
 
-    def _fit_epochs(self, iterator, epochs: int, ploop,
-                    prefetch_buffer: int = 0, profiler=None) -> None:
-        from deeplearning4j_tpu.runtime.state_packing import GroupedDispatch
-        from deeplearning4j_tpu.train.prefetch import (AsyncLossDelivery,
-                                                       stateless_listeners)
+    def _prepare_batch(self, batch):
+        args = coerce_training_batch(self, batch)
+        return args, args[0].shape[0]
 
-        def deliver(n, loss):
-            self._score = loss
-            self._iteration += 1
-            for lst in self._listeners:
-                if isinstance(lst, PerformanceListener):
-                    lst.record_batch(n)
-                lst.iteration_done(self, self._iteration, self._epoch, loss)
+    def _divert(self, args):
+        # zero-copy ref for listeners that sample activations
+        # (StatsListener histograms)
+        self._last_batch_features = args[0]
+        return super()._divert(args)
 
-        # async loss readback: with only stateless listeners, delivery moves
-        # to a completion thread (same callbacks, same order) so a listener
-        # reading float(loss) no longer blocks dispatch of the next step; a
-        # state-reading listener forces the synchronous path (it must see
-        # ITS iteration's post-step train_state). No listeners and no
-        # profiler = nothing worth a thread: deliver inline.
-        adel = (AsyncLossDelivery(deliver, profiler=profiler)
-                if (self._listeners or profiler is not None)
-                and stateless_listeners(self) else None)
-        # only the batch SIZE crosses into the delivery queue — queued step
-        # args would pin full device batches for up to max_pending steps
-        sink = adel.submit if adel is not None else deliver
-        gd = GroupedDispatch(
-            # with a state-reading listener, packing is off and batches must
-            # dispatch one at a time so iteration_done sees fresh state
-            unroll=(get_environment().dispatch_unroll if ploop.enabled else 1),
-            compatible=_group_compatible,
-            run_single=lambda a: ploop.step(*a)[0],
-            run_group=ploop.step_group,
-            deliver=lambda args, loss: sink(args[0].shape[0], loss))
-        try:
-            self._run_epochs(
-                iterator, epochs, ploop, gd,
-                drain=(adel.flush if adel is not None else (lambda: None)),
-                prefetch_buffer=prefetch_buffer, profiler=profiler)
-        finally:
-            gd.drain_on_error()
-            if adel is not None:
-                adel.shutdown()  # never raises; original errors win
-        if adel is not None:
-            adel.raise_pending()
+    _solver_fit_batch = solver_fit_batch  # (net, x, y, fmask, lmask) -> loss
 
-    def _run_epochs(self, iterator, epochs, ploop, gd, drain=lambda: None,
-                    prefetch_buffer=0, profiler=None) -> None:
-        from deeplearning4j_tpu.train.prefetch import (batch_source,
-                                                       coerce_training_batch)
-        from deeplearning4j_tpu.train.profiler import (drain_timed,
-                                                        submit_timed)
-        for _ in range(epochs):
-            for lst in self._listeners:
-                lst.on_epoch_start(self, self._epoch)
-            src = batch_source(iterator,
-                               lambda ds: coerce_training_batch(self, ds),
-                               prefetch_buffer, profiler)
-            try:
-                for x, y, fm, lm in src:
-                    # zero-copy ref for listeners that sample activations
-                    # (StatsListener histograms)
-                    self._last_batch_features = x
-                    if self.conf.tbptt_fwd_length and is_sequence_array(x):
-                        if self.conf.global_conf.optimization_algo != \
-                                "STOCHASTIC_GRADIENT_DESCENT":
-                            raise NotImplementedError(
-                                "truncated BPTT is only supported with "
-                                "STOCHASTIC_GRADIENT_DESCENT (matching "
-                                "ComputationGraph)")
-                        gd.flush()
-                        drain()  # tBPTT notifies listeners inline (ordered)
-                        ploop.sync(release=True)  # tBPTT mutates train_state
-                        self._fit_tbptt(x, y, fm, lm)
-                        continue
-                    if self.conf.global_conf.optimization_algo != \
-                            "STOCHASTIC_GRADIENT_DESCENT":
-                        from deeplearning4j_tpu.train.solvers import solver_fit_batch
-                        gd.flush()
-                        ploop.sync(release=True)  # solver mutates train_state
-                        loss = solver_fit_batch(self, x, y, fm, lm)
-                        gd._deliver((x, y, None, fm, lm), loss)  # same bookkeeping
-                        continue
-                    submit_timed(gd, self.rng,
-                                 lambda key: (x, y, key, fm, lm), profiler)
-            finally:
-                src.close()
-            drain_timed(gd, drain, profiler)
-            # no epoch-end sync: packing only runs when every listener is
-            # stateless, so nothing reads train_state until fit() returns
-            for lst in self._listeners:
-                lst.on_epoch_end(self, self._epoch)
-            self._epoch += 1
-
-    def _fit_tbptt(self, x, y, fmask, lmask):
-        """Split the time axis into tbptt-length chunks, carrying hidden state
-        (reference: truncated BPTT in ``MultiLayerNetwork.fitHelper``)."""
-        T = x.shape[1]
+    def _tbptt_plan(self, x, y, fmask, lmask):
+        """Zero carries, and the batch cut along its time axis into
+        tbptt-length chunks."""
         L = int(self.conf.tbptt_fwd_length)
-        carries = self._zero_carries(
-            x.shape[0], carry_dtype(x, get_environment().compute_dtype))
-        step_fn = self._jitted("tbptt_step", self._make_tbptt_step)
-        for t0 in range(0, T, L):
-            xs = slice_time(x, t0, L)
-            ys = y[:, t0:t0 + L] if y.ndim >= 3 else y
-            fms = fmask[:, t0:t0 + L] if fmask is not None else None
-            lms = lmask[:, t0:t0 + L] if lmask is not None else None
-            rng = self.rng.next_key()
-            self.train_state, carries, loss = step_fn(
-                self.train_state, carries, xs, ys, rng, fms, lms)
-            self._score = loss
-            self._iteration += 1
-            for lst in self._listeners:
-                lst.iteration_done(self, self._iteration, self._epoch, loss)
+
+        def chunks():
+            for t0 in range(0, x.shape[1], L):
+                yield (slice_time(x, t0, L),
+                       y[:, t0:t0 + L] if y.ndim >= 3 else y,
+                       fmask[:, t0:t0 + L] if fmask is not None else None,
+                       lmask[:, t0:t0 + L] if lmask is not None else None)
+
+        return self._zero_carries(
+            x.shape[0], carry_dtype(x, get_environment().compute_dtype)), chunks()
 
     # -------------------------------------------------------------- pretrain
     def pretrain(self, iterator, epochs: int = 1) -> "MultiLayerNetwork":
@@ -868,29 +575,6 @@ class MultiLayerNetwork:
         return out, new_state
 
     # -------------------------------------------------------------- plumbing
-    def set_listeners(self, *listeners: TrainingListener) -> None:
-        self._listeners = list(listeners)
-
-    def add_listeners(self, *listeners: TrainingListener) -> None:
-        self._listeners.extend(listeners)
-
-    def get_listeners(self) -> Sequence[TrainingListener]:
-        return list(self._listeners)
-
-    def params(self):
-        return self.train_state.params if self.train_state else None
-
-    def set_params(self, params) -> None:
-        if self.train_state is None:
-            self.init(params=params)
-        else:
-            self.train_state = dataclasses.replace(self.train_state, params=params)
-
-    def num_params(self) -> int:
-        if self.train_state is None:
-            return 0
-        return int(sum(np.prod(p.shape) for p in jax.tree.leaves(self.train_state.params)))
-
     def get_layer(self, key) -> Layer:
         """Layer by index or name (reference ``getLayer``)."""
         if isinstance(key, int):
@@ -928,14 +612,6 @@ class MultiLayerNetwork:
         lines.append(f"Total parameters: {total:,}")
         return "\n".join(lines)
 
-    @property
-    def iteration(self) -> int:
-        return self._iteration
-
-    @property
-    def epoch(self) -> int:
-        return self._epoch
-
     # serialization (reference ModelSerializer.writeModel / save+load methods)
     def save(self, path: str, save_updater: bool = True) -> None:
         from deeplearning4j_tpu.models.serializer import ModelSerializer
@@ -953,10 +629,3 @@ class MultiLayerNetwork:
             net.train_state = dataclasses.replace(
                 net.train_state, model_state=jax.tree.map(jnp.copy, self.train_state.model_state))
         return net
-
-
-def _mask_keys(params, keys):
-    """Boolean mask pytree: True where the leaf's dict key is a regularizable
-    param name (weight-decay applies to weights, not biases/norm scales)."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: any(getattr(p, "key", None) in keys for p in path), params)

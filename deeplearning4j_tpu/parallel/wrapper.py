@@ -33,12 +33,101 @@ from typing import Optional
 
 import jax
 
-from deeplearning4j_tpu.parallel.sharding import (ShardingStrategy, shard_batch,
+from deeplearning4j_tpu.parallel.sharding import (ShardingStrategy,
                                                   shard_batch_tree,
                                                   shard_train_state)
-from deeplearning4j_tpu.runtime.environment import get_environment
 from deeplearning4j_tpu.runtime.mesh import DATA_AXIS, MODEL_AXIS, create_mesh
-from deeplearning4j_tpu.train.listeners import PerformanceListener
+from deeplearning4j_tpu.runtime.state_packing import step_args_signature
+from deeplearning4j_tpu.train.fit_engine import run_fit
+from deeplearning4j_tpu.train.prefetch import stateless_listeners
+
+
+class _ShardedSteps:
+    """``run_fit``'s dispatcher over a mesh: the network's plain jitted
+    step on sharded per-leaf state (sharded state cannot pack, see
+    ``runtime/state_packing.py``), batches sharded ahead of the step."""
+
+    def __init__(self, net, strategy: ShardingStrategy):
+        self._net = net
+        self._strategy = strategy
+        # a state-reading listener forces one-at-a-time, inline delivery
+        self.grouped = stateless_listeners(net)
+        self._step_fn = net._jitted("train_step", net._make_train_step)
+        # Lowering captures the committed NamedShardings, so a (graph,
+        # shape, mesh) signature maps to exactly one executable. The plan
+        # signature joins every key: plan drift (axis added or resized,
+        # schedule knob changed) misses the cache and recompiles — never a
+        # stale executable for the wrong mesh.
+        self._aot = net._aot_cache("__aot_pw__", "pw-step")
+        self._plan = strategy.signature()
+
+    def context(self):
+        return self._strategy.mesh
+
+    def prepare(self, batch):
+        """Host→device for one batch: the network's coercion, the tBPTT
+        guard, then the sharded ``jax.device_put`` with the strategy's
+        ``NamedSharding``s. Pure with respect to model state, so the
+        prefetch worker runs it ahead of the current step."""
+        args, n = self._net._prepare_batch(batch)
+        if self._net._tbptt_applies(args):
+            raise NotImplementedError(
+                "tBPTT training under ParallelWrapper is not supported — "
+                "the wrapper would run full-sequence BPTT instead of the "
+                "model's tBPTT chunking; use the model's own fit(), or "
+                "full-sequence BPTT (unset tbptt_fwd_length) to train "
+                "sharded")
+        return shard_batch_tree(self._strategy, args), n
+
+    def step(self, args):
+        net = self._net
+        net.train_state, loss = self._aot.call(
+            ("pw", self._plan, step_args_signature(args)),
+            self._step_fn, net.train_state, *args)
+        return loss
+
+    def step_group(self, group):
+        """K compatible buffered steps as ONE device dispatch
+        (``env.dispatch_unroll``)."""
+        net = self._net
+        k = len(group)
+        net.train_state, losses = self._aot.call(
+            ("pw-group", self._plan, k, step_args_signature(group[0])),
+            net._jitted_unrolled(k), net.train_state, group)
+        return [losses[i] for i in range(k)]
+
+    def sync(self, release: bool = False) -> None:
+        pass  # every step leaves its state on the network
+
+
+class _PipeSteps(_ShardedSteps):
+    """Pipe-axis dispatcher: the model's uniform trunk is stage-stacked and
+    streamed through the GPipe shift register (``plan_exec``); each pipe
+    device holds 1/S of the trunk, the ``data`` axis (if present) shards
+    the batch. The stacked state lives here until :meth:`sync` writes the
+    trained params back to ``model.train_state``."""
+
+    def __init__(self, net, strategy, executor):
+        super().__init__(net, strategy)
+        self.grouped = False  # one step at a time, delivered inline
+        self._executor = executor
+        self._ts, tx = executor.packed_state()
+        self._step_fn = jax.jit(executor.make_train_step(tx),
+                                donate_argnums=(0,))
+
+    def step(self, args):
+        if args[3] is not None:
+            raise NotImplementedError(
+                "feature masks are not supported under pipe-axis plans")
+        self._ts, loss = self._aot.call(
+            ("pw-pipe", self._plan, step_args_signature(args)),
+            self._step_fn, self._ts, *args)
+        return loss
+
+    def sync(self, release: bool = False) -> None:
+        # a donated step that raised left no state to write back
+        if not any(a.is_deleted() for a in jax.tree.leaves(self._ts)):
+            self._executor.sync_back(self._ts)
 
 
 class ParallelWrapper:
@@ -120,160 +209,24 @@ class ParallelWrapper:
         solvers) would silently train with different gradients here — so
         refuse loudly instead. tBPTT is checked per-batch (the models'
         own fit engages it only for sequence batches)."""
-        conf = getattr(self.model, "conf", None)
-        gc = getattr(conf, "global_conf", None)
-        algo = getattr(gc, "optimization_algo",
-                       "STOCHASTIC_GRADIENT_DESCENT") or \
-            "STOCHASTIC_GRADIENT_DESCENT"
+        algo = self.model.conf.global_conf.optimization_algo
         if algo != "STOCHASTIC_GRADIENT_DESCENT":
             raise NotImplementedError(
                 f"ParallelWrapper supports optimization_algo=SGD only "
                 f"(got {algo!r}); legacy solvers run single-context via "
                 "the model's own fit()")
 
-    def _check_not_tbptt(self, x):
-        from deeplearning4j_tpu.models._tbptt import is_sequence_array
-        if getattr(getattr(self.model, "conf", None),
-                   "tbptt_fwd_length", None) and is_sequence_array(x):
-            raise NotImplementedError(
-                "tBPTT training under ParallelWrapper is not supported — "
-                "the wrapper would run full-sequence BPTT instead of the "
-                "model's tBPTT chunking; use the model's own fit(), or "
-                "full-sequence BPTT (unset tbptt_fwd_length) to train "
-                "sharded")
-
-    def _ensure_sharded(self):
-        self._check_supported()
-        if self.model.train_state is None:
-            self.model.init()
-        if not self._sharded:
-            self.model.train_state = shard_train_state(self.model.train_state, self.strategy)
-            self._sharded = True
-
-    def _prepare_batch(self, batch):
-        """Host→device for one batch: coercion (shared helper), tBPTT
-        guard, then the sharded ``jax.device_put`` with the strategy's
-        ``NamedSharding``s. Pure with respect to model state, so the
-        prefetch worker runs it ahead of the current step. Returns
-        ``(step_args_without_rng, n_examples)`` — MultiLayerNetwork steps
-        take (ts, x, y, rng, fmask, lmask); ComputationGraph takes
-        (ts, inputs_dict, labels_list, rng, masks)."""
-        from deeplearning4j_tpu.train.prefetch import coerce_training_batch
-        model = self.model
-        if hasattr(model, "_coerce_batch"):  # ComputationGraph
-            inputs, labels_, masks = model._coerce_batch(batch)
-            for v in inputs.values():
-                self._check_not_tbptt(v)
-            inputs = shard_batch_tree(self.strategy, inputs)
-            labels_ = shard_batch_tree(self.strategy, labels_)
-            masks = None if masks is None else shard_batch_tree(
-                self.strategy, masks)
-            n = next(iter(inputs.values())).shape[0]
-            return (inputs, labels_, masks), n
-        x, y, fm, lm = coerce_training_batch(model, batch)
-        self._check_not_tbptt(x)
-        x, y, fm, lm = shard_batch(self.strategy, x, y, fm, lm)
-        return (x, y, fm, lm), x.shape[0]
-
-    def _insert_rng(self, args, rng):
-        """Step args with the NEXT rng key (drawn at dispatch time) spliced
-        in — key order (and so the trajectory) follows submission order,
-        never prefetch completion order."""
-        if hasattr(self.model, "_coerce_batch"):  # (inputs, labels, rng, masks)
-            return (args[0], args[1], rng, args[2])
-        return (args[0], args[1], rng, args[2], args[3])
-
-    def _run_group(self, step_fn_unused, group):
-        """K compatible buffered steps as ONE device dispatch
-        (``env.dispatch_unroll``) — the sharded counterpart of the fit
-        loops' packed grouped dispatch (sharded state cannot pack, see
-        ``runtime/state_packing.py``)."""
-        from deeplearning4j_tpu.runtime.state_packing import (
-            make_unrolled_step, step_args_signature)
-        model = self.model
-        k = len(group)
-        fn = model._jitted(
-            f"pw_unrolled@k={k}",
-            lambda: make_unrolled_step(model._train_step_fn(), k))
-        model.train_state, losses = self._aot().call(
-            ("pw-group", self.strategy.signature(), k,
-             step_args_signature(group[0][0])),
-            fn, model.train_state, [args for args, _n in group])
-        return [losses[i] for i in range(k)]
-
-    def _aot(self):
-        """The sharded-dispatch AOT executable cache, stored in the model's
-        jit cache so ``init()`` invalidation covers it. Lowering captures
-        the committed NamedShardings, so a (graph, shape, mesh) signature
-        maps to exactly one executable."""
-        from deeplearning4j_tpu.runtime.compile_cache import AotCache
-        return self.model._jit_cache.setdefault(
-            "__aot_pw__", AotCache("pw-step"))
-
-    def _fit_pipe(self, iterator, epochs: int, profiler=None):
-        """Pipe-axis fit: the model's uniform trunk is stage-stacked and
-        streamed through the GPipe shift register (``plan_exec``); each pipe
-        device holds 1/S of the trunk, the ``data`` axis (if present) shards
-        the batch. Same listener/epoch semantics as the SPMD path; the
-        trained params are written back to ``model.train_state``."""
+    def _pipe_dispatcher(self):
+        from deeplearning4j_tpu.models.multi_layer_network import (
+            MultiLayerNetwork)
         from deeplearning4j_tpu.parallel.plan_exec import PipePlanExecutor
-        from deeplearning4j_tpu.runtime.state_packing import (
-            step_args_signature)
-        from deeplearning4j_tpu.train.prefetch import batch_source
-        self._check_supported()
-        model = self.model
-        if hasattr(model, "_coerce_batch"):
+        if not isinstance(self.model, MultiLayerNetwork):
             raise NotImplementedError(
                 "pipe-axis plans drive MultiLayerNetwork layer stacks; "
                 "ComputationGraph topologies have no linear trunk to stage")
-        if model.train_state is None:
-            model.init()
         if getattr(self, "_pipe_exec", None) is None:
-            self._pipe_exec = PipePlanExecutor(model, self.strategy)
-        ex = self._pipe_exec
-        packed_ts, tx = ex.packed_state()
-        step_fn = jax.jit(ex.make_train_step(tx), donate_argnums=(0,))
-        aot = self._aot()
-        plan_sig = self.strategy.signature()
-        if profiler is not None:
-            profiler.start()
-
-        try:
-            with self.strategy.mesh:
-                for _ in range(int(epochs)):
-                    for lst in model._listeners:
-                        lst.on_epoch_start(model, model._epoch)
-                    src = batch_source(iterator, self._prepare_batch,
-                                       self.prefetch_buffer, profiler)
-                    try:
-                        for args, n in src:
-                            args = self._insert_rng(args,
-                                                    model.rng.next_key())
-                            if args[3] is not None:
-                                raise NotImplementedError(
-                                    "feature masks are not supported under "
-                                    "pipe-axis plans")
-                            packed_ts, loss = aot.call(
-                                ("pw-pipe", plan_sig,
-                                 step_args_signature(args)),
-                                step_fn, packed_ts, *args)
-                            model._score = loss
-                            model._iteration += 1
-                            for lst in model._listeners:
-                                if isinstance(lst, PerformanceListener):
-                                    lst.record_batch(n)
-                                lst.iteration_done(model, model._iteration,
-                                                   model._epoch, loss)
-                    finally:
-                        src.close()
-                    for lst in model._listeners:
-                        lst.on_epoch_end(model, model._epoch)
-                    model._epoch += 1
-        finally:
-            if profiler is not None:
-                profiler.stop()
-        ex.sync_back(packed_ts)
-        return model
+            self._pipe_exec = PipePlanExecutor(self.model, self.strategy)
+        return _PipeSteps(self.model, self.strategy, self._pipe_exec)
 
     def fit(self, iterator, epochs: int = 1, profiler=None):
         """Distributed fit: same listener/epoch semantics (and bit-identical
@@ -283,92 +236,18 @@ class ParallelWrapper:
         :class:`~deeplearning4j_tpu.train.profiler.TrainingProfiler`.
 
         Plans with a ``pipe`` axis route through the GPipe executor
-        (:meth:`_fit_pipe`) — same call, pipelined execution."""
+        (:class:`_PipeSteps`) — same call, pipelined execution."""
+        self._check_supported()
+        if self.model.train_state is None:
+            self.model.init()
         if self.strategy.pipe_size > 1:
-            return self._fit_pipe(iterator, epochs, profiler)
-        from deeplearning4j_tpu.runtime.state_packing import GroupedDispatch
-        from deeplearning4j_tpu.train.prefetch import (AsyncLossDelivery,
-                                                       batch_source,
-                                                       stateless_listeners)
-        from deeplearning4j_tpu.train.profiler import (drain_timed,
-                                                        submit_timed)
-        self._ensure_sharded()
-        model = self.model
-        step_fn = model._jitted("train_step", model._make_train_step)
-        if hasattr(model, "_coerce_batch"):
-            from deeplearning4j_tpu.models.computation_graph import (
-                _cg_group_compatible as base_compat)
+            dispatcher = self._pipe_dispatcher()
         else:
-            from deeplearning4j_tpu.models.multi_layer_network import (
-                _group_compatible as base_compat)
-        stateless = stateless_listeners(model)
-        if profiler is not None:
-            profiler.start()
-
-        from deeplearning4j_tpu.runtime.state_packing import (
-            step_args_signature)
-        aot = self._aot()
-
-        def run_single(item):
-            args, _n = item
-            # the plan signature joins the key: plan drift (axis added or
-            # resized, schedule knob changed) misses the cache and
-            # recompiles — never a stale executable for the wrong mesh
-            out = aot.call(("pw", self.strategy.signature(),
-                            step_args_signature(args)),
-                           step_fn, model.train_state, *args)
-            model.train_state, loss = out
-            return loss
-
-        def deliver(n, loss):
-            model._score = loss
-            model._iteration += 1
-            for lst in model._listeners:
-                if isinstance(lst, PerformanceListener):
-                    lst.record_batch(n)
-                lst.iteration_done(model, model._iteration, model._epoch, loss)
-
-        # async loss readback (see MultiLayerNetwork._fit_epochs): a
-        # state-reading listener forces synchronous one-at-a-time delivery;
-        # no listeners and no profiler = deliver inline, no thread
-        adel = (AsyncLossDelivery(deliver, profiler=profiler)
-                if (model._listeners or profiler is not None)
-                and stateless else None)
-        # only the batch SIZE crosses into the delivery queue — queued step
-        # args would pin full sharded batches for up to max_pending steps
-        sink = adel.submit if adel is not None else deliver
-        gd = GroupedDispatch(
-            unroll=(get_environment().dispatch_unroll if stateless else 1),
-            compatible=lambda a, b: base_compat(a[0], b[0]),
-            run_single=run_single,
-            run_group=lambda group: self._run_group(step_fn, group),
-            deliver=lambda item, loss: sink(item[1], loss))
-        drain = adel.flush if adel is not None else (lambda: None)
-        try:
-            with self.strategy.mesh:
-                for _ in range(int(epochs)):
-                    for lst in model._listeners:
-                        lst.on_epoch_start(model, model._epoch)
-                    src = batch_source(iterator, self._prepare_batch,
-                                       self.prefetch_buffer, profiler)
-                    try:
-                        for args, n in src:
-                            submit_timed(
-                                gd, model.rng,
-                                lambda key: (self._insert_rng(args, key), n),
-                                profiler)
-                    finally:
-                        src.close()
-                    drain_timed(gd, drain, profiler)
-                    for lst in model._listeners:
-                        lst.on_epoch_end(model, model._epoch)
-                    model._epoch += 1
-        finally:
-            gd.drain_on_error()
-            if adel is not None:
-                adel.shutdown()  # never raises; original errors win
-            if profiler is not None:
-                profiler.stop()
-        if adel is not None:
-            adel.raise_pending()
-        return model
+            if not self._sharded:
+                self.model.train_state = shard_train_state(
+                    self.model.train_state, self.strategy)
+                self._sharded = True
+            dispatcher = _ShardedSteps(self.model, self.strategy)
+        run_fit(self.model, iterator, int(epochs), dispatcher,
+                self.prefetch_buffer, profiler)
+        return self.model

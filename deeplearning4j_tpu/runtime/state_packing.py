@@ -194,8 +194,8 @@ def make_unrolled_packed_step(raw_step, packer, k: int):
     (env.dispatch_unroll). The per-step argument tuples arrive as a LIST
     pytree — never pre-stacked on device, which would cost one tiny
     dispatch per array per group (the very overhead grouping removes).
-    Shared by MultiLayerNetwork and ComputationGraph (both raw steps take
-    ``(train_state, *step_args)`` and return ``(new_state, loss)``)."""
+    ``raw_step`` takes ``(train_state, *step_args)`` and returns
+    ``(new_state, loss)``."""
     def unrolled_packed_train_steps(pts, args_list):
         ts = packer.unpack(pts)
         losses = []
@@ -212,8 +212,8 @@ def make_unrolled_step(raw_step, k: int):
     PER-LEAF state — the sharded-training counterpart of
     :func:`make_unrolled_packed_step` (sharded training cannot pack: one
     flat buffer would force a common sharding across leaves, see module
-    docstring). Used by ``ParallelWrapper`` to honor
-    ``env.dispatch_unroll`` on a mesh; state donated, losses stacked."""
+    docstring): how ``env.dispatch_unroll`` is honored on a mesh; state
+    donated, losses stacked."""
     def unrolled_train_steps(ts, args_list):
         losses = []
         for i in range(k):
@@ -306,129 +306,3 @@ def step_args_signature(args) -> tuple:
         return tuple(shape), dt
 
     return tuple(leaf(a) for a in args)
-
-
-class PackedStepLoop:
-    """Drives a network's jitted train step with packed state inside ``fit``.
-
-    Lazily packs ``net.train_state`` on the first :meth:`step`; callers must
-    :meth:`sync` before anything else reads or writes ``net.train_state``
-    (listeners that need model state, solver/tBPTT branches, epoch ends).
-    ``sync(release=True)`` additionally drops the packed copy so a
-    subsequent step re-packs from the (possibly externally modified) state.
-
-    Dispatch rides the AOT fast path (``env.aot_dispatch``): per step-args
-    signature, the loop calls a cached ``lower().compile()`` executable
-    with the donated packed buffers instead of re-entering jit dispatch —
-    bit-identical trajectories (same trace → same executable). The
-    :class:`~deeplearning4j_tpu.runtime.compile_cache.AotCache` lives in
-    the NETWORK's jit cache, so repeated ``fit`` calls reuse executables
-    and ``init()``/graph edits (which clear that cache) invalidate them.
-    """
-
-    def __init__(self, net, enabled: bool):
-        self._net = net
-        self._enabled = enabled
-        self._packed = None
-        self._step_fn = None
-        self._packer = None
-        from deeplearning4j_tpu.runtime.compile_cache import AotCache
-        self._aot = net._jit_cache.setdefault("__aot__", AotCache("fit-step"))
-
-    @classmethod
-    def for_network(cls, net) -> "PackedStepLoop":
-        from deeplearning4j_tpu.runtime.environment import get_environment
-        from deeplearning4j_tpu.train.prefetch import stateless_listeners
-        # same listener gate as async loss delivery — the two must never
-        # desynchronize (a state-reading listener disables BOTH)
-        enabled = (get_environment().packed_state
-                   and stateless_listeners(net))
-        return cls(net, enabled)
-
-    @property
-    def active(self) -> bool:
-        return self._packed is not None
-
-    @property
-    def enabled(self) -> bool:
-        """Whether packed stepping is in effect (env flag + listener gate).
-        Grouped dispatch must also gate on this: with a state-reading
-        listener attached, batches must dispatch (and notify) one at a
-        time so the listener observes per-iteration state."""
-        return self._enabled
-
-    def step(self, *rest_args):
-        """One train step (packed when enabled, plain otherwise). Returns the
-        ``(loss, aux...)`` tail of the step (everything after the state)."""
-        if not self._enabled:
-            if self._step_fn is None:
-                self._step_fn = self._net._jitted(
-                    "train_step", self._net._make_train_step)
-            out = self._aot.call(
-                ("plain", step_args_signature(rest_args)),
-                self._step_fn, self._net.train_state, *rest_args)
-            self._net.train_state = out[0]
-            return out[1:]
-        if self._packed is None:
-            self._step_fn, self._packer = self._net._jitted_packed()
-            try:
-                self._packed = self._packer.pack_device(self._net.train_state)
-            # Structure changed since the packer was built. A changed
-            # treedef/dtype raises ValueError; a changed leaf SHAPE with the
-            # same treedef surfaces as TypeError from the reshape inside
-            # pack — both mean "rebuild the packer".
-            except (ValueError, TypeError):
-                prefix = self._net._packed_cache_key()
-                for k in [k for k in self._net._jit_cache
-                          if isinstance(k, str) and k.startswith(prefix)]:
-                    self._net._jit_cache.pop(k, None)  # incl. @unroll variants
-                # AOT executables were lowered from the stale packed step
-                self._aot.clear()
-                self._step_fn, self._packer = self._net._jitted_packed()
-                self._packed = self._packer.pack_device(self._net.train_state)
-        out = self._aot.call(
-            ("packed", self._net._packed_cache_key(),
-             step_args_signature(rest_args)),
-            self._step_fn, self._packed, *rest_args)
-        self._packed = out[0]
-        return out[1:]
-
-    def step_group(self, group):
-        """Run a list of per-step argument tuples as ONE unrolled device
-        dispatch (env.dispatch_unroll). All tuples in the group must share
-        shapes and mask-presence (the fit loop guarantees it). Returns the
-        per-step losses (device scalars, lazy)."""
-        if not self._enabled or len(group) == 1:
-            return [self.step(*args)[0] for args in group]
-        if self._packed is None:
-            # first call packs lazily: run the first batch single-step,
-            # then the rest as a (possibly shorter) group
-            first_loss, = self.step(*group[0])
-            rest = self.step_group(group[1:]) if len(group) > 1 else []
-            return [first_loss] + rest
-        fn = self._net._jitted_packed_unrolled(len(group))
-        self._packed, losses = self._aot.call(
-            ("packed-group", self._net._packed_cache_key(), len(group),
-             step_args_signature(group[0])),
-            fn, self._packed, [tuple(args) for args in group])
-        return [losses[i] for i in range(len(group))]
-
-    def sync(self, release: bool = False) -> None:
-        """Refresh ``net.train_state`` from the packed buffers.
-
-        If a donated step consumed the packed buffers and then raised (NaN
-        panic, device error), no post-step state exists anywhere — sync
-        drops the dead packed copy WITHOUT raising, so the original
-        exception propagates; ``net.train_state`` is then whatever was last
-        synced, and recovery is checkpoint restore (reference semantics for
-        a crashed fit are the same).
-        """
-        if self._packed is None:
-            return
-        if LeafPacker.is_dead(self._packed):
-            self._packed = None
-            return
-        self._net.train_state = self._packer.unpack_device(
-            self._packed, donate=release)
-        if release:
-            self._packed = None

@@ -1,7 +1,6 @@
 """Overlapped training feed path: device prefetch + async loss readback.
 
-The synchronous fit loops (``MultiLayerNetwork._run_epochs``,
-``ParallelWrapper.fit``) leave the device idle on every batch: host-side
+A synchronous fit loop leaves the device idle on every batch: host-side
 ETL + ``jnp.asarray`` (and, sharded, the blocking ``shard_batch`` transfer)
 run *between* steps, and listener delivery — which may read ``float(loss)``
 and therefore sync on the device — runs *before* the next batch is even
@@ -27,14 +26,14 @@ synchronous loop's, so results are bit-identical.
   arguments but no longer blocks dispatch when a listener reads the score.
   Mirrors ``GroupedDispatch``'s snapshot-before-deliver discipline: items
   are snapshotted at submit, delivered FIFO, drained on every exit path.
-- :func:`coerce_training_batch` — the one shared batch-coercion /
-  mask-defaulting helper (previously duplicated between
-  ``MultiLayerNetwork._run_epochs`` and ``ParallelWrapper._run_step``).
+- :func:`coerce_training_batch` — ``MultiLayerNetwork``'s batch coercion
+  and mask defaulting.
 
 Only listeners that declare ``needs_model_state = False`` may be delivered
 asynchronously: a state-reading listener must observe the post-step
 ``train_state`` of *its* iteration, which forces one-at-a-time dispatch
-(the same gate ``PackedStepLoop.for_network`` applies to state packing).
+(the same gate ``train.fit_engine.PackedStepLoop`` applies to state
+packing and grouping).
 """
 
 from __future__ import annotations
@@ -72,9 +71,9 @@ def coerce_training_batch(model, batch):
 
     The labels mask defaults to the features mask propagated through any
     time-axis-changing layers (``model._output_time_mask``) for
-    per-timestep labels — the reference's tBPTT/masking semantics. Shared
-    by ``MultiLayerNetwork._run_epochs``, ``ParallelWrapper`` and
-    :class:`DevicePrefetcher`; pure host→device work, safe off-thread.
+    per-timestep labels — the reference's tBPTT/masking semantics.
+    ``MultiLayerNetwork._prepare_batch``; pure host→device work, safe
+    off-thread.
     """
     x = jnp.asarray(batch.features)
     y = jnp.asarray(batch.labels)

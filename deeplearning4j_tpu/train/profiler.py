@@ -17,7 +17,8 @@ device trace's clock. The stages that tile the fit thread:
 - **dispatch** - ``gd.submit``: issuing the jitted step, compiles included
   (a ``StepTraceAnnotation``, so device ops group by step),
 - **drain** - ``gd.flush`` and the delivery flush at an epoch's end,
-- **sync** - ``PackedStepLoop.sync``: the packed state unpacked.
+- **sync** - the dispatcher's ``sync`` (``PackedStepLoop``: the packed
+  state unpacked).
 
 **data_wait** is what the fit thread waited for its next batch: next_batch +
 h2d when synchronous, the queue wait alone when prefetched. **step** is
@@ -235,9 +236,8 @@ class TrainingProfiler:
 
 
 def submit_timed(gd, rng, build, profiler: Optional[TrainingProfiler] = None) -> None:
-    """``gd.submit(build(rng.next_key()))`` - the one submit wrapper shared
-    by the three fit loops (MultiLayerNetwork, ComputationGraph,
-    ParallelWrapper). ``build`` splices the step's key into its argument
+    """``gd.submit(build(rng.next_key()))`` - ``run_fit``'s submit.
+    ``build`` splices the step's key into its argument
     tuple; with a profiler the key draw is the ``rng`` stage and the submit
     the ``dispatch`` stage."""
     if profiler is None:
@@ -250,7 +250,7 @@ def submit_timed(gd, rng, build, profiler: Optional[TrainingProfiler] = None) ->
 
 
 def drain_timed(gd, drain, profiler: Optional[TrainingProfiler] = None) -> None:
-    """An epoch's end in the three fit loops: flush the buffered group,
+    """An epoch's end in ``run_fit``: flush the buffered group,
     then the delivery queue (``on_epoch_end`` must observe every
     ``iteration_done``) - the ``drain`` stage."""
     if profiler is None:
@@ -263,8 +263,8 @@ def drain_timed(gd, drain, profiler: Optional[TrainingProfiler] = None) -> None:
 
 
 def sync_timed(ploop, profiler: Optional[TrainingProfiler] = None) -> None:
-    """``ploop.sync(release=True)`` when ``fit`` returns - the ``sync``
-    stage (the packed state's final unpack)."""
+    """The dispatcher's ``sync(release=True)`` when ``fit`` returns - the
+    ``sync`` stage (the packed state's final unpack)."""
     if profiler is None:
         ploop.sync(release=True)
         return

@@ -17,22 +17,19 @@ from deeplearning4j_tpu.nn import (ConvolutionLayer, DenseLayer, GravesLSTM,
                                    SubsamplingLayer)
 from deeplearning4j_tpu.train import Adam, CollectScoresListener, Sgd
 
-# re-recorded 2026-08-03 on jax 0.4.37 (this repo's pinned toolchain), CPU
-# backend, verified bit-identical across two fresh processes. The previous
-# values (recorded on jax 0.9.0) were unreachable here: initialization /
-# dropout draws differ across jax versions, so every curve diverged from
-# step 1 and the goldens never provided regression signal on this
-# toolchain. Goldens are environment-pinned fixtures — re-record (twice,
-# diffing for determinism) whenever the jax pin moves.
-LENET_GOLDEN = [2.309887, 2.272974, 2.253786, 2.242065, 2.193092,
-                2.156597, 2.138206, 2.118122, 2.115263, 2.068008]
-# (round 2: LSTM cell activation fixed to the reference's tanh default —
-# was inheriting global identity)
-LSTM_GOLDEN = [2.471995, 2.455743, 2.443324, 2.432385, 2.422121,
-               2.412248, 2.402635, 2.393207]
-# (round 3: dropout masks moved from threefry to the rbg generator —
-# intentional perf change, BASELINE.md)
-BERT_GOLDEN = [0.533299, 0.650245, 0.674123, 0.651878, 0.568803, 0.644421]
+# Recorded at commit 2305a6a (the parent of PR 33) on jax / jaxlib 0.9.0,
+# CPU backend, under the suite's own conftest (8 virtual devices, x64 off);
+# two fresh processes gave the same digits. The values before these were
+# from another jax and initialiser stream and missed from step 1, so the
+# curves guarded nothing; these hold `fit` to the trajectory it had before
+# the train step and the loop moved into `train/fit_engine.py`. Goldens are
+# fixtures of one toolchain: record them again (twice, and compare) when
+# the jax pin moves, never to make a change of the engine pass.
+LENET_GOLDEN = [2.247756, 2.208591, 2.171265, 2.144371, 2.125517,
+                2.076218, 2.015083, 1.953701, 1.946526, 1.947022]
+LSTM_GOLDEN = [2.502773, 2.483892, 2.466112, 2.449220, 2.433141,
+               2.417893, 2.403498, 2.389911]
+BERT_GOLDEN = [1.090776, 1.286131, 1.276235, 0.919525, 1.136208, 1.115440]
 
 _TOL = dict(rtol=2e-3, atol=2e-3)
 

@@ -13,7 +13,8 @@ import pytest
 from deeplearning4j_tpu.nn import DenseLayer, InputType, OutputLayer
 from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
 from deeplearning4j_tpu.runtime.environment import get_environment
-from deeplearning4j_tpu.runtime.state_packing import LeafPacker, PackedStepLoop
+from deeplearning4j_tpu.runtime.state_packing import LeafPacker
+from deeplearning4j_tpu.train.fit_engine import PackedStepLoop
 from deeplearning4j_tpu.train.updaters import Adam
 
 

@@ -19,7 +19,8 @@ from deeplearning4j_tpu.nn import (DenseLayer, InputType,
 from deeplearning4j_tpu.parallel import ParallelWrapper
 from deeplearning4j_tpu.runtime.chaos import ChaosController, ChaosError, FailNth
 from deeplearning4j_tpu.runtime.environment import get_environment
-from deeplearning4j_tpu.train import (Adam, CollectScoresListener, Sgd,
+from deeplearning4j_tpu.train import (Adam, CollectScoresListener,
+                                      PerformanceListener, Sgd,
                                       TrainingListener, TrainingProfiler)
 
 
@@ -177,6 +178,99 @@ def test_computation_graph_prefetched_fit_bit_identical():
     assert cs.scores == cp.scores
     assert (np.asarray(g1.params()["h"]["W"])
             == np.asarray(g2.params()["h"]["W"])).all()
+
+
+# ---------------------------------------------------------- the one loop
+class _RecordingListener(PerformanceListener):
+    """A PerformanceListener that writes down what the loop tells it."""
+
+    def __init__(self):
+        super().__init__()
+        self.events = []
+
+    def record_batch(self, n_examples):
+        self.events.append(("batch", n_examples))
+
+    def iteration_done(self, model, iteration, epoch, score):
+        self.events.append(("iter", iteration, epoch, float(score)))
+
+    def on_epoch_start(self, model, epoch):
+        self.events.append(("start", epoch))
+
+    def on_epoch_end(self, model, epoch):
+        self.events.append(("end", epoch))
+
+
+def _graph_conf(seed=7):
+    """_conf() as a linear ComputationGraph: same node keys, same draws."""
+    return (NeuralNetConfiguration.builder().seed(seed).updater(Sgd(0.1))
+            .graph_builder().add_inputs("in")
+            .add_layer("layer_0", DenseLayer(n_out=16, activation="tanh"), "in")
+            .add_layer("layer_1", OutputLayer(n_out=4, activation="softmax"),
+                       "layer_0")
+            .set_outputs("layer_1")
+            .set_input_types(InputType.feed_forward(8)).build())
+
+
+def _one_loop_fit(path, prefetch=2):
+    """(network, fit) for one of the three ways into run_fit. The wrapper
+    prefetches by default, and a fault then surfaces ahead of the batches
+    staged behind it: ``prefetch=0`` makes where it stops exact."""
+    from deeplearning4j_tpu.models.computation_graph import ComputationGraph
+    if path == "graph":
+        net = ComputationGraph(_graph_conf()).init()
+        return net, net.fit
+    net = MultiLayerNetwork(_conf()).init()
+    if path == "wrapper":
+        return net, (ParallelWrapper.builder(net).workers(1)
+                     .prefetch_buffer(prefetch).build().fit)
+    return net, net.fit
+
+
+class _BreaksAfter(ListDataSetIterator):
+    """Yields ``good`` batches, then raises."""
+
+    def __init__(self, datasets, good):
+        super().__init__(datasets)
+        self._good = good
+
+    def next(self):
+        if self._pos == self._good:
+            raise RuntimeError("iterator boom")
+        return super().next()
+
+
+@pytest.mark.parametrize("path", ["mln", "graph", "wrapper"])
+def test_one_loop_serves_both_engines_and_the_wrapper(path):
+    """The same tiny model and batches through MultiLayerNetwork.fit, the
+    equivalent linear ComputationGraph.fit and ParallelWrapper.fit on a
+    one-device mesh: the same losses, the same listener calls in the same
+    order (``record_batch(n)`` before each ``iteration_done``), and a
+    ``train_state`` that holds every completed step after the iterator
+    raises mid-epoch."""
+    x, y = _data(n=56)  # 3 batches of 16 and one of 8
+    want_net = MultiLayerNetwork(_conf()).init()
+    want = _RecordingListener()
+    want_net.set_listeners(want)
+    want_net.fit(NumpyDataSetIterator(x, y, batch_size=16), epochs=2)
+    assert [e[1] for e in want.events if e[0] == "batch"] == [16, 16, 16, 8] * 2
+
+    net, fit = _one_loop_fit(path)
+    got = _RecordingListener()
+    net.set_listeners(got)
+    fit(NumpyDataSetIterator(x, y, batch_size=16), epochs=2)
+    assert got.events == want.events
+    assert (_params(net) == _params(want_net)).all()
+
+    net, fit = _one_loop_fit(path, prefetch=0)
+    net.set_listeners(_RecordingListener())
+    batches = [DataSet(x[i:i + 16], y[i:i + 16]) for i in (0, 16, 32)]
+    with pytest.raises(RuntimeError, match="iterator boom"):
+        fit(_BreaksAfter(batches, good=2), epochs=1)
+    assert int(net.train_state.step) == 2 and net.iteration == 2
+    ref = MultiLayerNetwork(_conf()).init()
+    ref.fit(ListDataSetIterator(batches[:2]), epochs=1)
+    assert (_params(net) == _params(ref)).all()
 
 
 # ------------------------------------------------- async listener delivery
