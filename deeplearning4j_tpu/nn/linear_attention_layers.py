@@ -27,8 +27,8 @@ oracle of the kernel's tests and what the kernel is measured against. The
 Pallas kernel pair ``chunk_kda_fwd`` / ``chunk_kda_bwd``
 (``ops/pallas/chunk_kda.py``) carries the state in VMEM from chunk to chunk;
 ``chunk_kda`` takes it by what it can see in its input (a platform with
-kernels, head sizes that are lane tiles, whole chunks, a block that fits
-VMEM): no option, no environment variable.
+kernels, head sizes that are lane tiles, whole chunks in blocks of whole
+lane tiles, a block that fits VMEM): no option, no environment variable.
 """
 
 from __future__ import annotations
@@ -122,10 +122,12 @@ def chunk_kda(q, k, v, g, beta, chunk: int = CHUNK):
         raise ValueError(f"chunk_kda: {t} tokens are no multiple of the chunk of {chunk}")
     if not chunk_kda_compatible(q, v, chunk):
         return chunk_kda_xla(q, k, v, g, beta, chunk)
-    flat = lambda a: a.reshape(b, t, -1)  # the projections' own layout: no copy
+    # (b, h * d, t): XLA keeps these activations time-minor, so the transposes are layout requests, not copies
+    time_minor = lambda a: a.reshape(b, t, -1).transpose(0, 2, 1)
     with jax.named_scope("kda_scan"):
-        o = chunk_kda_pallas(flat(q), flat(k), flat(v), flat(g.astype(jnp.float32)), beta.astype(jnp.float32), h)
-    return o.reshape(b, t, h, -1)
+        o = chunk_kda_pallas(time_minor(q), time_minor(k), time_minor(v), time_minor(g.astype(jnp.float32)),
+                             beta.astype(jnp.float32), h)
+        return o.transpose(0, 2, 1).reshape(b, t, h, -1)
 
 
 def chunk_kda_xla(q, k, v, g, beta, chunk: int = CHUNK):

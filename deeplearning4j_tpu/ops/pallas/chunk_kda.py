@@ -22,10 +22,24 @@ in one VMEM scratch across the grid's block axis (zeroed at the first
 block), so blocks and heads are sequential axes and heads the inner one:
 ``beta`` (b, t, h) then comes in, and its gradient goes out, as one
 (rows, h) block a block of chunks, each head taking and writing its own
-lane. q, k, v, o and their gradients are blocks (1, rows, 128) of the
-(b, t, h * d) arrays the projections write, column block = head: no
-transpose on either side (such blocks stream at 500 GB/s, my chip run, PR
-32); g stays float32 in the same layout.
+lane.
+
+**Operand layout.** q, k, v, g, o and their gradients live in HBM as
+(b, h * d, t): features on sublanes, time on lanes, a head an aligned slice
+of 128 sublanes, a block of chunks (1, 128, rows) with 256 tokens on two
+lane tiles. A ``pallas_call`` pins row-major operands, and XLA on the TPU
+keeps this model's (1, t, h * d) activations head by head and, beside its
+matmuls (the weight gradients contract over t), time-minor: a pair on
+(b, t, h * d) blocks was paid for in 36 re-layout copies of 67 and 134 MB a
+step at 8192 x 32 x 128 and more around them, 5.2 GB moved again (PERF.md
+section 6, PR 34; PR 27 found the same around ``fused_attention``).
+``chunk_kda`` asks for the transposes, and the step compiled for a v5e
+holds no copy for them: the fusions on either side write and read this
+layout themselves. Inside, a block is widened to float32 and transposed in
+VMEM as it is loaded, and a result transposed back before it is rounded and
+stored (0.4 + 0.6 ms a layer, forward + backward, of 7.4 + 11.6; 16-bit
+transposes return 0.14 of it: not kept): the body works on (rows, 128) as
+it always did; g stays float32.
 
 **The solve** ``(I + A) [W | U0] = beta [k exp(G) | v]`` is float32 on the
 MXU (``Precision.HIGHEST``): the inverse of the 16-row diagonal blocks as
@@ -110,21 +124,19 @@ def _sub(a):
     return a.reshape(-1, SUB, a.shape[-1])
 
 
-def _local(q, k, v, g, beta, T=None):
+def _local(qf, kf, vf, g, beta, mm, T=None):
     """What chunks compute without the state, for the ``nb`` chunks of a
     grid step at once (the batch is what lets the compiler overlap the
-    chain of float32 matmuls). ``q``, ``k``: (nb, C, K) and ``v``:
-    (nb, C, V) in the matmuls' dtype, ``g``: (nb, C, K) float32, ``beta``:
-    (nb, C, 1) float32. Returns a dict: ``W`` ``qd`` ``kout`` ``B`` (matmul
-    dtype), ``U0`` (float32), ``eGend`` (nb, 1, K), ``T`` = (I + A)^-1
-    (nb, C, C) float32; given ``T`` (the backward pass: the forward saved
-    it) the solve is not repeated and what the gradients re-use is returned
-    too."""
-    mm = q.dtype
-    nb, c, kdim = q.shape
+    chain of float32 matmuls). ``qf``, ``kf``, ``g``: (nb, C, K) and ``vf``:
+    (nb, C, V), ``beta``: (nb, C, 1), all float32 (q, k, v widened from
+    ``mm``, the matmuls' dtype, without loss). Returns a dict: ``W`` ``qd``
+    ``kout`` ``B`` (matmul dtype), ``U0`` (float32), ``eGend`` (nb, 1, K),
+    ``T`` = (I + A)^-1 (nb, C, C) float32; given ``T`` (the backward pass:
+    the forward saved it) the solve is not repeated and what the gradients
+    re-use is returned too."""
+    nb, c, kdim = qf.shape
     n_sub = c // SUB
     m = nb * n_sub
-    qf, kf, vf = q.astype(_F32), k.astype(_F32), v.astype(_F32)
     G = _running_sum(g.reshape(nb * c, kdim)).reshape(nb, c, kdim)       # from each chunk's start
     G4, q4, k4 = _sub(G), _sub(qf), _sub(kf)
     # G just before each sub-block's first row (0 for a chunk's first)
@@ -177,8 +189,16 @@ def _local(q, k, v, g, beta, T=None):
 
 
 def _chunks(ref, nb):
-    """A (1, nb * C, x) block as (nb, C, x)."""
-    return ref[0].reshape(nb, CHUNK, ref.shape[2])
+    """A (1, x, nb * C) block, features on sublanes and time on lanes as HBM
+    holds it, as float32 (nb, C, x): widened first, so that the transpose in
+    VMEM is a 32-bit one whatever the block's dtype."""
+    return ref[0].astype(_F32).T.reshape(nb, CHUNK, ref.shape[1])
+
+
+def _store(ref, a):
+    """Float32 (..., x) rows into a (1, x, rows) block: transposed back in
+    VMEM, then rounded to the block's dtype."""
+    ref[0] = a.reshape(-1, a.shape[-1]).T.astype(ref.dtype)
 
 
 def _head_lane(ref, nb, head):
@@ -214,28 +234,27 @@ def _through_chunk(L, j, St):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, s_ref, t_ref, st_ref):
     block, head = pl.program_id(1), pl.program_id(2)
-    nb, c = q_ref.shape[1] // CHUNK, CHUNK
+    nb = q_ref.shape[2] // CHUNK
 
     @pl.when(block == 0)
     def _():
         st_ref[head] = jnp.zeros(st_ref.shape[1:], _F32)
 
     L = _local(_chunks(q_ref, nb), _chunks(k_ref, nb), _chunks(v_ref, nb), _chunks(g_ref, nb),
-               _head_lane(beta_ref, nb, head))
+               _head_lane(beta_ref, nb, head), q_ref.dtype)
     t_ref[0, 0] = _pack(L["T"])
     St, U, qS = st_ref[head], [None] * nb, [None] * nb
     s_ref[0, 0, 0] = St
     for j in range(nb):  # the state through the chunks in turn
         U[j], qS[j], St = _through_chunk(L, j, St)
     st_ref[head] = St
-    o = jnp.stack(qS) + _mm(L["B"], jnp.stack(U), _B_NN)
-    o_ref[0] = o.reshape(nb * c, o.shape[2]).astype(o_ref.dtype)
+    _store(o_ref, jnp.stack(qS) + _mm(L["B"], jnp.stack(U), _B_NN))
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, t_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dst_ref):
     block, head = pl.program_id(1), pl.program_id(2)
-    nb, c, kdim = q_ref.shape[1] // CHUNK, CHUNK, q_ref.shape[2]
+    nb, c, kdim, mm = q_ref.shape[2] // CHUNK, CHUNK, q_ref.shape[1], q_ref.dtype
     n_sub = c // SUB
 
     @pl.when(block == 0)  # the sequence's last block: nothing follows it
@@ -243,12 +262,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, t_ref, do_ref,
         dst_ref[head] = jnp.zeros(dst_ref.shape[1:], _F32)
 
     beta = _head_lane(beta_ref, nb, head)
-    L = _local(_chunks(q_ref, nb), _chunks(k_ref, nb), _chunks(v_ref, nb), _chunks(g_ref, nb), beta, _unpack(t_ref[0, 0], nb))
-    mm = L["W"].dtype
+    L = _local(_chunks(q_ref, nb), _chunks(k_ref, nb), _chunks(v_ref, nb), _chunks(g_ref, nb), beta, mm,
+               _unpack(t_ref[0, 0], nb))
     St, U = [s_ref[0, 0, 0]] * (nb + 1), [None] * nb  # the block's first state was saved; the others follow
     for j in range(nb):
         U[j], _, St[j + 1] = _through_chunk(L, j, St[j])
-    St, U, dO = jnp.stack(St[:nb]), jnp.stack(U), _chunks(do_ref, nb)
+    St, U, dO = jnp.stack(St[:nb]), jnp.stack(U), _chunks(do_ref, nb).astype(mm)
     Stb = St.astype(mm)
     # through the state: U = U0 - W S, O = qd S + B U, S' = eGend S + kout^T U; dS from the last chunk back
     dU_of_O = _mm(L["B"], dO, _B_TN)
@@ -291,13 +310,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s_ref, t_ref, do_ref,
     leaving = dkout * kf * L["eOut"]                                     # d(G_end - G) of kout
     dG = dqd * qf * eG + dkd * L["kd"] - leaving + kf * (rk - ck) + qf * rq
     dG = dG + jnp.where(_iota(dG.shape, 1) == c - 1, jnp.sum(leaving, 1, keepdims=True) + dGend, 0.0)
-    rows = lambda a: a.reshape(nb * c, a.shape[2])
-    dq_ref[0] = rows(dqd * eG + rq).astype(dq_ref.dtype)
-    dk_ref[0] = rows(dkout * L["eOut"] + dkd * eG + rk + ck).astype(dk_ref.dtype)
-    dv_ref[0] = rows(dRU * beta).astype(dv_ref.dtype)
-    dg_ref[0] = _running_sum(rows(dG), reverse=True)
+    _store(dq_ref, dqd * eG + rq)
+    _store(dk_ref, dkout * L["eOut"] + dkd * eG + rk + ck)
+    _store(dv_ref, dRU * beta)
+    _store(dg_ref, _running_sum(dG.reshape(nb * c, kdim), reverse=True))
     lanes = dbeta_ref[0]
-    dbeta_ref[0] = jnp.where(_iota(lanes.shape, 1) == head, rows(dbeta), lanes)
+    dbeta_ref[0] = jnp.where(_iota(lanes.shape, 1) == head, dbeta.reshape(nb * c, 1), lanes)
 
 
 def _block_chunks(n: int) -> int:
@@ -306,12 +324,14 @@ def _block_chunks(n: int) -> int:
 
 def _vmem_bytes(rows: int, d_k: int, d_v: int, heads: int, itemsize: int) -> int:
     """The backward kernel's blocks counted once, as ``VMEM_BUDGET`` wants
-    them (q, k, dq, dk and v, dO, dv in the matmuls' dtype, g and dg, beta
-    and dbeta padded to a lane tile, the block's first state and its
+    them (q, k, dq, dk and v, dO, dv in the matmuls' dtype, g and dg, each
+    (d, rows) with the rows on whole lane tiles, so no byte of padding;
+    beta and dbeta padded to a lane tile, the block's first state and its
     chunks' ``T``), the carried dS of every head, and the float32
     temporaries of the chunks taken together that are live at once (four
-    (SUB, SUB, d_k) arrays a sub-block, three (CHUNK, d_k) a sub-block;
-    Mosaic compiles twice the block under ``VMEM_LIMIT_BYTES``)."""
+    (SUB, SUB, d_k) arrays a sub-block, three (CHUNK, d_k) a sub-block, the
+    transposed blocks among them; Mosaic compiles twice the block under
+    ``VMEM_LIMIT_BYTES``)."""
     chunks = rows // CHUNK
     blocks = (4 * d_k + 3 * d_v) * rows * itemsize + 2 * rows * d_k * 4 + 2 * rows * max(heads, 128) * 4
     saved = d_k * d_v * 4 + (chunks + 1) // 2 * CHUNK * 2 * CHUNK * 4
@@ -323,7 +343,9 @@ def _vmem_bytes(rows: int, d_k: int, d_v: int, heads: int, itemsize: int) -> int
 def chunk_kda_compatible(q, v, chunk: int = CHUNK) -> bool:
     """Whether the kernel pair takes this call: q (and k) of (b, t, h, d_k)
     and v of (b, t, h, d_v) with both head sizes lane tiles, t whole chunks
-    of the kernel's size, a platform with kernels, and a block that fits."""
+    of the kernel's size that make up blocks of whole lane tiles (time is on
+    lanes: an even number of chunks, or one), a platform with kernels, and a
+    block that fits."""
     if chunk != CHUNK or q.ndim != 4 or q.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     _, t, h, d_k = q.shape
@@ -331,6 +353,8 @@ def chunk_kda_compatible(q, v, chunk: int = CHUNK) -> bool:
     if d_k % 128 or d_v % 128 or t % CHUNK or not kernels_available():
         return False
     rows = _block_chunks(t // CHUNK) * CHUNK
+    if rows % 128 and rows != t:
+        return False
     return _vmem_bytes(rows, d_k, d_v, h, q.dtype.itemsize) <= VMEM_BUDGET
 
 
@@ -340,7 +364,7 @@ def _specs(b, t, heads, d_k, d_v, reverse):
     n = _block_chunks(t // CHUNK)
     n_blocks = t // (n * CHUNK)
     at = (lambda j: n_blocks - 1 - j) if reverse else (lambda j: j)
-    of_head = lambda d: pl.BlockSpec((1, n * CHUNK, d), lambda i, j, hd: (i, at(j), hd))
+    of_head = lambda d: pl.BlockSpec((1, d, n * CHUNK), lambda i, j, hd: (i, hd, at(j)))
     per_block = pl.BlockSpec((1, n * CHUNK, heads), lambda i, j, hd: (i, at(j), 0))
     state = pl.BlockSpec((1, 1, 1, d_v, d_k), lambda i, j, hd: (i, hd, at(j), 0, 0))
     solved = pl.BlockSpec((1, 1, (n + 1) // 2, CHUNK, 2 * CHUNK), lambda i, j, hd: (i, hd, at(j), 0, 0))
@@ -353,8 +377,8 @@ _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "ar
 
 @functools.partial(jax.jit, static_argnames=("heads", "interpret"))
 def _forward(q, k, v, g, beta, *, heads, interpret):
-    b, t, hk = q.shape
-    d_k, d_v = hk // heads, v.shape[2] // heads
+    b, hk, t = q.shape
+    d_k, d_v = hk // heads, v.shape[1] // heads
     grid, of_head, per_block, state, solved = _specs(b, t, heads, d_k, d_v, False)
     return pl.pallas_call(
         _fwd_kernel, name="chunk_kda_fwd", grid=grid,
@@ -369,8 +393,8 @@ def _forward(q, k, v, g, beta, *, heads, interpret):
 
 @functools.partial(jax.jit, static_argnames=("heads", "interpret"))
 def _backward(q, k, v, g, beta, states, solved, do, *, heads, interpret):
-    b, t, hk = q.shape
-    d_k, d_v = hk // heads, v.shape[2] // heads
+    b, hk, t = q.shape
+    d_k, d_v = hk // heads, v.shape[1] // heads
     grid, of_head, per_block, state, solved_spec = _specs(b, t, heads, d_k, d_v, True)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
     return pl.pallas_call(
@@ -385,9 +409,10 @@ def _backward(q, k, v, g, beta, states, solved, do, *, heads, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def chunk_kda_pallas(q, k, v, g, beta, heads: int):
-    """The gated delta rule on (b, t, heads * d) ``q``, ``k``, ``v``, float32
-    ``g`` in the same layout and float32 ``beta`` (b, t, heads), for calls
-    that :func:`chunk_kda_compatible` accepts. Returns (b, t, heads * d_v)."""
+    """The gated delta rule on time-minor (b, heads * d, t) ``q``, ``k``,
+    ``v``, float32 ``g`` in the same layout and float32 ``beta``
+    (b, t, heads), for calls that :func:`chunk_kda_compatible` accepts.
+    Returns (b, heads * d_v, t); the gradients come in and go out likewise."""
     return _forward(q, k, v, g, beta, heads=heads, interpret=_interpret())[0]
 
 

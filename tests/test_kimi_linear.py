@@ -140,14 +140,15 @@ def kernel_calls(monkeypatch):
     return calls
 
 
-@pytest.mark.parametrize("tokens,decay", [(192, 0.05), (512, 3.0)])
+@pytest.mark.parametrize("tokens,decay", [(384, 0.05), (512, 3.0)])
 def test_the_delta_rule_kernel_matches_the_xla_form_and_the_token_recurrence(tokens, decay, monkeypatch):
     """The Pallas pair under the interpreter at the published head size (128)
     against the XLA form and against the family's token recurrence, outputs
-    and all five gradients: three blocks of one chunk with a weak decay (the
+    and all five gradients: three blocks of two chunks with a weak decay (the
     state carried from block to block in VMEM is a visible part of the
-    output), two blocks of four chunks with a strong one (3 a token). One
-    chunk and one block: ``tests/test_pallas.py``."""
+    output; time is on lanes since PR 34, so a block is whole lane tiles of
+    128 tokens), two blocks of four chunks with a strong one (3 a token).
+    One chunk and one block: ``tests/test_pallas.py``."""
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     calls = kernel_calls(monkeypatch)
     args = delta_rule_inputs(tokens, decay, b=2, h=2, d=128)
@@ -156,7 +157,7 @@ def test_the_delta_rule_kernel_matches_the_xla_form_and_the_token_recurrence(tok
     assert calls
     close(got, xla, 2e-5)
     close(got, want, 2e-5)
-    if tokens == 192:
+    if tokens == 384:
         later = FAMILY.delta_rule(*(a[:, 128:] for a in args))
         assert float(jnp.max(jnp.abs(later - want[:, 128:]))) > 1e-3
     grads = lambda f: jax.grad(lambda *a: jnp.sum(jnp.sin(3 * f(*a))), argnums=(0, 1, 2, 3, 4))(*args)
@@ -165,10 +166,40 @@ def test_the_delta_rule_kernel_matches_the_xla_form_and_the_token_recurrence(tok
     trees_close(ours, grads(FAMILY.delta_rule), 1e-4)
 
 
+def test_the_delta_rule_kernels_take_and_give_time_minor_arrays(monkeypatch):
+    """The layout the pair is handed in HBM is the layer's to choose, and it
+    chooses what XLA holds on the chip: the two ``pallas_call``s in the
+    gradient through ``chunk_kda`` take q, k, v, g, dO and give o, dq, dk,
+    dv, dg as (b, h * d, t), features before time. Row-major (b, t, h * d)
+    operands cost 36 re-layout copies a step in the Kimi Linear cell
+    (``tests/test_pallas.py`` holds the compiled step to none)."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
+    b, t, h, d = 2, 128, 2, 128
+    args = delta_rule_inputs(t, 0.05, b=b, h=h, d=d)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(chunk_kda(*a)), argnums=(0, 1, 2, 3, 4)))(*args)
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    calls = {eqn.params["name"]: eqn for eqn in eqns(jaxpr.jaxpr) if eqn.primitive.name == "pallas_call"}
+    assert sorted(calls) == ["chunk_kda_bwd", "chunk_kda_fwd"]
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]
+    per_token, per_head = (b, h * d, t), (b, t, h)
+    fwd, bwd = calls["chunk_kda_fwd"], calls["chunk_kda_bwd"]
+    assert shapes(fwd.invars) == [per_token] * 4 + [per_head]
+    assert shapes(fwd.outvars)[0] == per_token
+    assert shapes(bwd.invars)[:5] == [per_token] * 4 + [per_head] and shapes(bwd.invars)[-1] == per_token
+    assert shapes(bwd.outvars) == [per_token] * 4 + [per_head]
+
+
 @pytest.mark.parametrize("why,d,tokens,chunk,interpreter,takes", [
     ("the cell's head size, whole chunks", 128, 128, CHUNK, True, True),
     ("a head that is no lane tile", 16, 128, CHUNK, True, False),
     ("tokens that are no whole chunks", 128, 96, CHUNK, True, False),
+    ("three chunks: a block of one would be half a lane tile of the sequence", 128, 192, CHUNK, True, False),
     ("another chunk than the kernel's", 128, 128, 32, True, False),
     ("no kernels on this platform", 128, 128, CHUNK, False, False)])
 def test_chunk_kda_takes_the_kernel_by_shape_and_platform_alone(why, d, tokens, chunk, interpreter, takes, monkeypatch):

@@ -930,3 +930,74 @@ def test_chunk_kda_at_the_kimi_cells_shape_for_v5e(v5e_chip, monkeypatch, layers
         assert hlo.count('custom_call_target="tpu_custom_call"') == 2
         assert "chunk_kda_fwd" in hlo and "chunk_kda_bwd" in hlo
         assert not re.search(r" while\(", hlo)
+
+
+def _entry_instructions(hlo):
+    """The ENTRY computation of a compiled module's text as ``name ->
+    (type, op, operands that ENTRY defines)``; a Mosaic kernel's op reads
+    ``tpu_custom_call``."""
+    lines = hlo[hlo.index("\nENTRY "):].splitlines()
+    parsed = [re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (.+?) ([a-z][a-z\-]*)\((.*)$", line) for line in lines]
+    found = {m.group(1): m.groups()[1:] for m in parsed if m}
+    mosaic = 'custom_call_target="tpu_custom_call"'
+    return {name: (kind, "tpu_custom_call" if mosaic in rest else op,
+                   [o for o in re.findall(r"%([\w.\-]+)", rest) if o in found])
+            for name, (kind, op, rest) in found.items()}
+
+
+def test_chunk_kda_costs_no_layout_copy_in_a_kimi_linear_step(v5e_chip, monkeypatch):
+    """PR 27's finding a second time (PR 34): XLA lays this model's
+    (1, 8192, 32 x 128) activations out for its own fusions (head by head,
+    time-minor beside the matmuls), a ``pallas_call`` pins row-major
+    operands, and a pair on (b, t, h * d) blocks had every operand and
+    result re-laid in HBM, 36 copies of 67 and 134 MB a step in the cell's
+    four layers; on (b, h * d, t) the fusions write and read the kernels'
+    layout themselves. The step of a one-KDA-layer decoder at the cell's
+    widths (1 x 8192, hidden 2304, 32 heads x 128, bf16 compute, every scope
+    recomputed), compiled for the described chip, holds the two kernels and
+    no copy of an (8192, 4096)-sized array that feeds, or is fed by, either."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.runtime.environment import get_environment
+    from deeplearning4j_tpu.zoo import KimiLinear
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET")
+    env = get_environment()
+    dtype, remat = env.compute_dtype, env.remat_segments
+    try:
+        env.set_compute_dtype("bfloat16")
+        env.set_remat(True)
+        net = KimiLinear(vocab_size=1024, d_model=2304, n_layers=1, kda_layers=[1], full_attn_layers=[],
+                         n_heads=32, kda_head_dim=128, dense_size=1024).init()
+        step, packer = net._jitted_packed()
+        spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip)  # noqa: E731
+        ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=v5e_chip)
+        args = (jax.tree.map(spec, packer.pack_device(net.train_state)), ids, ids, spec(jax.random.PRNGKey(0)),
+                None, None)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the route's platform probe sees the described chip
+        entry = _entry_instructions(step.lower(*args).compile().as_text())
+    finally:
+        env.set_compute_dtype(dtype)
+        env.set_remat(remat)
+    kernels = [name for name, (_, op, _) in entry.items() if op == "tpu_custom_call"]
+    assert sorted(name.split(".")[0] for name in kernels) == ["chunk_kda_bwd", "chunk_kda_fwd"]
+    users = {name: [] for name in entry}
+    for name, (_, _, operands) in entry.items():
+        for operand in operands:
+            users[operand].append(name)
+
+    def beyond_views(names, onward):
+        """What ``names`` reach through bitcasts and tuple elements."""
+        for name in names:
+            if entry[name][1] in ("bitcast", "get-tuple-element"):
+                yield from beyond_views(onward(name), onward)
+            else:
+                yield name
+
+    def elements(kind):
+        return int(np.prod([int(d) for d in re.match(r"\w+\[([\d,]*)\]", kind).group(1).split(",") if d] or [0]))
+
+    beside = [n for k in kernels for n in (*beyond_views(entry[k][2], lambda n: entry[n][2]),
+                                           *beyond_views(users[k], lambda n: users[n]))]
+    copies = [f"{n} = {entry[n][0]}" for n in beside if entry[n][1] == "copy" and elements(entry[n][0]) >= 8192 * 4096]
+    assert len(beside) >= 18 and not copies, copies
