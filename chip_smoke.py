@@ -31,7 +31,10 @@ vocab 30522; batch 64 x seq 128, bf16 compute, library defaults otherwise):
    ``chunk_kda`` routes the delta rule's kernel pair (held against its XLA
    form first), ``dot_product_attention`` the flash kernel with two head
    sizes and the experts their grouped matmul; no assignment beyond the
-   buffer.
+   buffer; then *glm_moe_lite*: ``GlmMoeLite.tiny(...)`` at the published
+   head sizes (rotary latent attention 192 + 64 against 256, a low-rank
+   query) with its prediction layer on the tied table and head, where the
+   flash kernel routes at 256 / 256 in every block; both loss terms finite.
 4. *server*: ``ModelSerializer.write_model`` -> ``ModelRegistry.load`` with
    one replica per device -> ``ModelServer`` -> ``POST
    /v1/models/bert/predict`` with mixed row counts, ``/healthz``,
@@ -113,6 +116,10 @@ class Preset:
         v_dim=128, kv_rank=128, vocab_size=512))
     kimi_batch: int = 2
     kimi_seq: int = 1024
+    # GlmMoeLite.tiny at the published head sizes (q.k 192 + 64, v 256)
+    glm: Dict[str, Any] = dataclasses.field(default_factory=lambda: dict(
+        d_model=256, q_rank=96, kv_rank=128, qk_nope_dim=192,
+        qk_shared_dim=64, v_dim=256, vocab_size=512))
 
 
 # ------------------------------------------------------------------ helpers
@@ -507,6 +514,30 @@ def _recomputing(check):
     return run
 
 
+def _next_token_fit(net, p: Preset, what: str, at_least: int):
+    """Six counted next-token steps of a decoder through ``fit`` at the
+    preset's batch and length, then its compiled step: (losses, the
+    compiled step's text, its Mosaic calls, ``at_least`` expected)."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.train.listeners import CollectScoresListener
+    scores = CollectScoresListener()
+    net.set_listeners(scores)
+    ids = np.random.default_rng(0).integers(
+        0, net.layers[0].n_in, (p.kimi_batch, p.kimi_seq + 1), dtype=np.int32)
+    batch = DataSet(np.ascontiguousarray(ids[:, :-1]),
+                    np.ascontiguousarray(ids[:, 1:]))
+    losses = _fit_counted(net.fit, [batch], [batch], p.kimi_batch, 6, scores,
+                          f"{what} fit")
+    assert _on_platform(net.train_state, p.platform), \
+        f"{what} train state is not on the device"
+    step, packer = net._jitted_packed()
+    compiled = step.lower(
+        packer.pack_device(net.train_state), jnp.asarray(batch.features),
+        jnp.asarray(batch.labels), jax.random.PRNGKey(0), None, None).compile()
+    return losses, compiled.as_text(), _mosaic_calls(
+        compiled, p, at_least, f"{what} train step")
+
+
 @_recomputing
 def check_kimi_linear(p: Preset) -> Dict[str, Any]:
     """The delta rule's kernel pair against its XLA form, then a few
@@ -515,11 +546,9 @@ def check_kimi_linear(p: Preset) -> Dict[str, Any]:
     parts against a smaller v head), experts through the grouped matmul,
     held to: finite falling loss, nothing compiled after warm-up, the
     kernels in the compiled step, no assignment left out."""
-    from deeplearning4j_tpu.data.dataset import DataSet
     from deeplearning4j_tpu.nn.linear_attention_layers import (chunk_kda,
                                                                chunk_kda_xla)
     from deeplearning4j_tpu.ops.pallas.chunk_kda import chunk_kda_compatible
-    from deeplearning4j_tpu.train.listeners import CollectScoresListener
     from deeplearning4j_tpu.zoo import KimiLinear
 
     # the layer's operands: unit q (scaled) and k, log-decay <= 0, beta in (0, 1)
@@ -540,29 +569,14 @@ def check_kimi_linear(p: Preset) -> Dict[str, Any]:
         (0, 1, 2, 3, 4), p, tol, tol, calls=2)
 
     net = KimiLinear.tiny(**p.kimi).init()
-    scores = CollectScoresListener()
-    net.set_listeners(scores)
-    ids = np.random.default_rng(0).integers(
-        0, net.layers[0].n_in, (p.kimi_batch, p.kimi_seq + 1), dtype=np.int32)
-    batch = DataSet(np.ascontiguousarray(ids[:, :-1]),
-                    np.ascontiguousarray(ids[:, 1:]))
-    losses = _fit_counted(net.fit, [batch], [batch], p.kimi_batch, 6, scores,
-                          "KimiLinear fit")
-    assert _on_platform(net.train_state, p.platform), \
-        "KimiLinear train state is not on the device"
+    # flash forward and its two backward passes and the delta rule's pair
+    # in every KDA layer, at the least
+    losses, text, n_calls = _next_token_fit(net, p, "KimiLinear", 5)
     overflow = {k: float(s["mlp"]["overflow"])
                 for k, s in net.train_state.model_state.items()}
     assert overflow and not any(overflow.values()), \
         f"KimiLinear: assignments beyond the experts' buffer {overflow}"
-    step, packer = net._jitted_packed()
-    compiled = step.lower(
-        packer.pack_device(net.train_state), jnp.asarray(batch.features),
-        jnp.asarray(batch.labels), jax.random.PRNGKey(0), None, None).compile()
-    # flash forward and its two backward passes and the delta rule's pair
-    # in every KDA layer, at the least
-    n_calls = _mosaic_calls(compiled, p, 5, "KimiLinear train step")
     if p.expect_mosaic:
-        text = compiled.as_text()
         for kernel in ("flash_attention_fwd", "chunk_kda_fwd", "chunk_kda_bwd"):
             assert kernel in text, \
                 f"KimiLinear train step: {kernel} was routed around"
@@ -570,6 +584,43 @@ def check_kimi_linear(p: Preset) -> Dict[str, Any]:
     return {"steps": len(losses), "first_loss": losses[0],
             "last_loss": losses[-1], "mosaic_calls": n_calls,
             "chunk_kda": delta_rule}
+
+
+@_recomputing
+def check_glm_moe_lite(p: Preset) -> Dict[str, Any]:
+    """A few steps of a small ``GlmMoeLite`` through ``fit`` with its
+    multi-token-prediction loss: rotary latent attention through the flash
+    kernel at a q.k head of 192 + 64 against a v head of 256 in all four
+    blocks (the prediction layer's among them), held to: finite falling
+    loss, both terms finite and summing to it, nothing compiled after
+    warm-up, the kernel in the compiled step, no assignment left out."""
+    from deeplearning4j_tpu.zoo import GlmMoeLite
+
+    zoo = GlmMoeLite.tiny(**p.glm)
+    net = zoo.init()
+    # flash forward and its two backward passes in each of the four blocks
+    losses, text, n_calls = _next_token_fit(net, p, "GlmMoeLite", 12)
+    state = net.train_state.model_state
+    counters = [s["mlp"] for s in state.values() if "mlp" in s] + \
+               [s["block"]["mlp"] for s in state.values() if "block" in s]
+    overflow = [float(c["overflow"]) for c in counters]
+    assert len(overflow) == 3 and not any(overflow), \
+        f"GlmMoeLite: assignments beyond the experts' buffer {overflow}"
+    main, = (float(s["main_loss"]) for s in state.values() if "main_loss" in s)
+    mtp, = (float(s["mtp_loss"]) for s in state.values() if "mtp_loss" in s)
+    assert np.isfinite(main) and np.isfinite(mtp) and main > 0 and mtp > 0, \
+        f"GlmMoeLite: loss terms {main}, {mtp}"
+    total = main + zoo.mtp_weight * mtp
+    assert abs(total - losses[-1]) <= 0.02 * losses[-1], \
+        f"GlmMoeLite: {main} + {zoo.mtp_weight} x {mtp} is not the last loss {losses[-1]}"
+    if p.expect_mosaic:
+        assert "flash_attention_fwd" in text, \
+            "GlmMoeLite train step: flash_attention_fwd was routed around"
+    _log(f"  GlmMoeLite train step: mosaic_calls={n_calls} "
+         f"main_loss={main:.4f} mtp_loss={mtp:.4f}")
+    return {"steps": len(losses), "first_loss": losses[0],
+            "last_loss": losses[-1], "main_loss": main, "mtp_loss": mtp,
+            "mosaic_calls": n_calls}
 
 
 # ----------------------------------------------------------- phase 4: server
@@ -721,6 +772,9 @@ def run(p: Preset, report: Dict[str, Any], workdir: str) -> None:
     gc.collect()
     with _phase(report, "kimi_linear"):
         report["kimi_linear"] = check_kimi_linear(p)
+    gc.collect()
+    with _phase(report, "glm_moe_lite"):
+        report["glm_moe_lite"] = check_glm_moe_lite(p)
     gc.collect()
     if len(jax.devices()) >= 4:
         with _phase(report, "four_chips"):

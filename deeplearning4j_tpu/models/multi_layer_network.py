@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from deeplearning4j_tpu.nn.base import GlobalConfig, Layer
+from deeplearning4j_tpu.nn.base import GlobalConfig, Layer, cast_floating, with_tied
 from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.core_layers import LossLayer, OutputLayer
 from deeplearning4j_tpu.models._tbptt import carry_dtype, slice_time
@@ -76,6 +76,8 @@ class MultiLayerNetwork(TrainEngine):
         new_params, model_state = jax.jit(init_all)(jax.random.PRNGKey(g.seed))
         if params is not None:
             new_params = params
+        for layer in self.layers:
+            with_tied(layer, {}, new_params)  # a tie that names nothing fails here, not in the first step
         self._tx = self._build_tx(new_params)
         trainable = self._trainable(new_params)
         opt_state = self._tx.init(trainable)
@@ -95,17 +97,18 @@ class MultiLayerNetwork(TrainEngine):
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, model_state, x, *, training: bool, rng,
-                 fmask=None, carries: Optional[Dict] = None):
+                 fmask=None, carries: Optional[Dict] = None, labels=None):
         """Compose all layers; returns (final_out, pre_output_input, new_state,
         new_carries). ``pre_output_input`` is the input fed to the final
         (output) layer — AFTER that layer's input dropout, so the fused loss
         path and the forward output see the same dropped activations.
-        ``fmask``: (batch, time) features mask threaded to sequence layers."""
+        ``fmask``: (batch, time) features mask threaded to sequence layers.
+        ``labels`` go to the layers that set ``takes_labels``; a layer's
+        ``tied`` parameters are read from their owners (``nn.base.with_tied``)."""
         env = get_environment()
         cdt = env.compute_dtype
         if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != cdt:
             x = x.astype(cdt)
-        from deeplearning4j_tpu.nn.base import cast_floating
         params = cast_floating(params, cdt)
         new_state = dict(model_state)
         new_carries = {} if carries is not None else None
@@ -126,9 +129,10 @@ class MultiLayerNetwork(TrainEngine):
             with jax.named_scope(f"{k}.{type(layer).__name__}"):
                 if i in self.conf.preprocessors:
                     x = self.conf.preprocessors[i].pre_process(x, fmask)
-                p = params.get(k, {})
+                p = with_tied(layer, params.get(k, {}), params)
                 s = model_state.get(k, {})
                 lrng = jax.random.fold_in(rng, i) if rng is not None else None
+                extra = {"labels": labels} if layer.takes_labels else {}
                 if training and getattr(layer, "weight_noise", None) is not None:
                     from deeplearning4j_tpu.nn.constraints import apply_weight_noise
                     p = apply_weight_noise(
@@ -152,7 +156,7 @@ class MultiLayerNetwork(TrainEngine):
                         x, s_new = jax.checkpoint(_fwd)(p, s, x, lrng, fmask)
                     else:
                         x, s_new = layer.forward(p, s, x, training=training,
-                                                 rng=lrng, mask=fmask)
+                                                 rng=lrng, mask=fmask, **extra)
                     if s:
                         new_state[k] = s_new
                 if fmask is not None and hasattr(layer, "transform_mask"):
@@ -164,13 +168,12 @@ class MultiLayerNetwork(TrainEngine):
               carries=None, training: bool = True):
         out, last_in, new_state, new_carries = self._forward(
             params, model_state, x, training=training, rng=rng, fmask=fmask,
-            carries=carries)
+            carries=carries, labels=y)
         final = self.layers[-1]
         if not hasattr(final, "compute_loss"):
             raise ValueError("Last layer must be an output/loss layer to compute loss")
         k = _layer_key(len(self.layers) - 1, final)
-        from deeplearning4j_tpu.nn.base import cast_floating
-        final_p = cast_floating(params.get(k, {}), get_environment().compute_dtype)
+        final_p = cast_floating(with_tied(final, params.get(k, {}), params), get_environment().compute_dtype)
         if training and getattr(final, "weight_noise", None) is not None \
                 and rng is not None:
             # SAME noise keys as _forward's output-layer branch, so the loss
@@ -182,6 +185,8 @@ class MultiLayerNetwork(TrainEngine):
         with jax.named_scope("loss"):
             loss = final.compute_loss(final_p, last_in, y, mask=lmask,
                                       state=model_state.get(k, {}))
+            if "main_loss" in model_state.get(k, {}):  # an output layer with ``record_loss``
+                new_state = {**new_state, k: {**model_state[k], "main_loss": loss.astype(jnp.float32)}}
             loss = loss + self._reg_score(params)
         # differentiable auxiliary losses surfaced by layers through the
         # state channel (e.g. MoE load balancing) — same trace, so grads

@@ -64,6 +64,7 @@ from deeplearning4j_tpu.nn.attention_layers import (
     DecoderBlock,
     GatedMLP,
     LatentAttention,
+    MultiTokenPrediction,
     RMSNormLayer,
 )
 from deeplearning4j_tpu.nn.linear_attention_layers import KimiDeltaAttention
@@ -138,6 +139,7 @@ __all__ = [
     "DecoderBlock",
     "GatedMLP",
     "LatentAttention",
+    "MultiTokenPrediction",
     "RMSNormLayer",
     "KimiDeltaAttention",
     "LearnedPositionalEmbeddingLayer",

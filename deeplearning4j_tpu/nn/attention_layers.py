@@ -449,17 +449,38 @@ class GatedMLP(Layer):
         return ("W_g", "W_u", "W_d")
 
 
+def rotary(x, positions, theta: float):
+    """Rotary position embedding over the whole last axis of ``x`` (..., t,
+    d) or (b, t, h, d) with time on axis 1, in the half-split pairing:
+    channel ``i`` turns with channel ``i + d/2`` by the angle ``positions *
+    theta^(-2i/d)``. Angles and rotation in float32; the result in ``x``'s
+    dtype."""
+    half = x.shape[-1] // 2
+    freqs = jnp.exp(-jnp.log(jnp.float32(theta)) * (jnp.arange(half, dtype=jnp.float32) / half))
+    angles = positions.astype(jnp.float32)[:, None] * freqs  # (t, d/2)
+    shape = (1, angles.shape[0]) + (1,) * (x.ndim - 3) + (half,)
+    cos, sin = jnp.cos(angles).reshape(shape), jnp.sin(angles).reshape(shape)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
+
+
 @register_layer
 @dataclasses.dataclass
 class LatentAttention(Layer):
-    """Multi-head latent attention without rotary (MLA, NoPE), causal.
+    """Multi-head latent attention (MLA), causal.
 
     Keys and values come up from one ``kv_rank``-wide normed latent per
     token; each head's key is its own ``qk_nope_dim`` part beside one
-    ``qk_shared_dim`` part that all heads share. The T x T part goes through
-    ``dot_product_attention`` and the routing it owns (the flash kernel takes
-    the q.k head of ``qk_nope_dim + qk_shared_dim`` against a v head of
-    ``v_dim``: two sizes, nothing padded)."""
+    ``qk_shared_dim`` part that all heads share. With ``q_rank`` the queries
+    come up from a normed latent of that width too (``W_qa``, ``q_norm``,
+    ``W_qb`` in place of ``W_q``). With ``rope_theta`` the ``qk_shared_dim``
+    parts of queries and keys are turned by their position (``rotary``,
+    under a scope ``rope``); without it the layer has no notion of position
+    (NoPE). The T x T part goes through ``dot_product_attention`` and the
+    routing it owns (the flash kernel takes the q.k head of ``qk_nope_dim +
+    qk_shared_dim`` against a v head of ``v_dim``: two sizes, nothing
+    padded)."""
 
     n_heads: int = 32
     kv_rank: int = 512
@@ -467,30 +488,57 @@ class LatentAttention(Layer):
     qk_shared_dim: int = 64
     v_dim: int = 128
     eps: float = 1e-5
+    q_rank: Optional[int] = None
+    rope_theta: Optional[float] = None
 
     def init(self, key, input_type, g: GlobalConfig):
         d, h = input_type.size, self.n_heads
         qk = self.qk_nope_dim + self.qk_shared_dim
-        shapes = {"W_q": (d, h * qk), "W_kva": (d, self.kv_rank + self.qk_shared_dim),
+        queries = ({"W_q": (d, h * qk)} if self.q_rank is None
+                   else {"W_qa": (d, self.q_rank), "W_qb": (self.q_rank, h * qk)})
+        shapes = {**queries, "W_kva": (d, self.kv_rank + self.qk_shared_dim),
                   "W_kvb": (self.kv_rank, h * (self.qk_nope_dim + self.v_dim)),
                   "W_o": (h * self.v_dim, d)}
         params = {name: init_weights(k, shape, self._winit(g), fan=shape, dtype=g.dtype)
                   for (name, shape), k in zip(shapes.items(), jax.random.split(key, len(shapes)))}
         params["kv_norm"] = jnp.ones((self.kv_rank,), g.dtype or jnp.float32)
+        if self.q_rank is not None:
+            params["q_norm"] = jnp.ones((self.q_rank,), g.dtype or jnp.float32)
         return params, {}
 
-    def _qkv(self, p, x):
+    def _project(self, p, x):
+        """(q (b, t, h, dn + dr), [k_n | v] (b, t, h, dn + dv), the keys' shared part (b, t, dr))."""
         b, t, _ = x.shape
         h, dn, dr = self.n_heads, self.qk_nope_dim, self.qk_shared_dim
-        q = (x @ p["W_q"]).reshape(b, t, h, dn + dr)
+        if self.q_rank is None:
+            q = (x @ p["W_q"]).reshape(b, t, h, dn + dr)
+        else:
+            q = (rms_norm(x @ p["W_qa"], p["q_norm"], self.eps) @ p["W_qb"]).reshape(b, t, h, dn + dr)
         latent = x @ p["W_kva"]
         c, k_shared = latent[..., :self.kv_rank], latent[..., self.kv_rank:]
         kv = (rms_norm(c, p["kv_norm"], self.eps) @ p["W_kvb"]).reshape(b, t, h, dn + self.v_dim)
+        return q, kv, k_shared
+
+    def _join(self, q, kv, k_shared):
+        """q, k, v as (b, h, t, d): every head's key gets the shared part."""
+        b, t, h, _ = q.shape
+        dn, dr = self.qk_nope_dim, self.qk_shared_dim
         k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared[:, :, None, :], (b, t, h, dr))], -1)
         return tuple(a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., dn:]))
 
+    def _qkv(self, p, x):
+        return self._join(*self._project(p, x))
+
+    def _rotate_and_join(self, q, kv, k_shared):
+        dn, positions = self.qk_nope_dim, jnp.arange(q.shape[1])
+        q = jnp.concatenate([q[..., :dn], rotary(q[..., dn:], positions, self.rope_theta)], -1)
+        return self._join(q, kv, rotary(k_shared, positions, self.rope_theta))
+
     def forward(self, params, state, x, *, training=False, rng=None, mask=None):
-        q, k, v = scoped("mla_qkv", self._qkv, params, x)
+        if self.rope_theta is None:
+            q, k, v = scoped("mla_qkv", self._qkv, params, x)
+        else:
+            q, k, v = scoped("rope", self._rotate_and_join, *scoped("mla_qkv", self._project, params, x))
         key_mask = None if mask is None else mask[:, None, None, :].astype(bool)
         y = dot_product_attention(q, k, v, key_mask, causal=True)
 
@@ -501,7 +549,7 @@ class LatentAttention(Layer):
         return scoped("mla_out", out, params, y), state
 
     def regularizable_params(self):
-        return ("W_q", "W_kva", "W_kvb", "W_o")
+        return ("W_q", "W_qa", "W_qb", "W_kva", "W_kvb", "W_o")
 
 
 @register_layer
@@ -553,3 +601,86 @@ class DecoderBlock(Layer):
 
     def regularizable_params(self):
         return tuple(set(self.mixer.regularizable_params()) | set(self.mlp.regularizable_params()))
+
+
+@register_layer
+@dataclasses.dataclass
+class MultiTokenPrediction(Layer):
+    """A second prediction per position (DeepSeek-V3, arXiv:2412.19437 §2.2):
+    from the trunk's last hidden state at ``i`` and the embedding of the
+    token at ``i + 1`` (the batch's label at ``i``), one more decoder block
+    predicts the token at ``i + 2`` (the label at ``i + 1``)::
+
+        u_i = W_eh [RMSNorm_e(Emb(label_i)) ; RMSNorm_h(x_i)]
+        z = block(u);  logits'_i = head(RMSNorm_s(z_i));  L_mtp = CE(logits'_i, label_(i+1))
+
+    over the positions that have a label after theirs (all but the last of
+    a row; with a mask, those whose own and whose next position are valid).
+
+    The layer sits between the last block and the final norm and hands its
+    input on unchanged. It owns ``enorm``, ``hnorm``, ``W_eh``, the block
+    and ``norm``; the embedding table and the head are the trunk's, named in
+    ``tied`` as ``{"embed": "<embedding layer's key>", "head": "<output
+    layer's key>"}``: one leaf each in the network's parameters, which the
+    trunk and this layer both read, so each gets the sum of its two
+    gradients and one update. ``head`` is the output layer's configuration
+    (it scores both predictions). ``weight * L_mtp`` joins the training loss
+    through the state channel (``_aux_loss``); ``mtp_loss`` keeps the last
+    step's ``L_mtp``. Without labels (inference) the layer does nothing."""
+
+    block: Any = None  # a DecoderBlock
+    head: Any = None   # the network's output layer, as configured there
+    weight: float = 0.3
+    eps: float = 1e-5
+    remat_in_scopes = True
+    takes_labels = True
+
+    def __post_init__(self):
+        for name in ("block", "head"):
+            if isinstance(getattr(self, name), dict):
+                setattr(self, name, Layer.from_dict(getattr(self, name)))
+
+    def init(self, key, input_type, g: GlobalConfig):
+        d = input_type.size
+        k_eh, k_block = jax.random.split(key)
+        ones = jnp.ones((d,), g.dtype or jnp.float32)
+        block, block_state = _sub_init(self.block, k_block, input_type, g)
+        params = {"enorm": ones, "hnorm": ones, "norm": ones, "block": block,
+                  "W_eh": init_weights(k_eh, (2 * d, d), self._winit(g), fan=(2 * d, d), dtype=g.dtype)}
+        state = {"_aux_loss": jnp.zeros((), jnp.float32), "mtp_loss": jnp.zeros((), jnp.float32)}
+        if block_state:
+            state["block"] = block_state
+        return params, state
+
+    def forward(self, params, state, x, *, training=False, rng=None, mask=None, labels=None):
+        if labels is None:
+            return x, state
+        self.block._g = self.head._g = self._g
+
+        def mtp_in(p, table, x_, labels_):
+            e = jnp.take(table, labels_.astype(jnp.int32), axis=0)
+            both = jnp.concatenate([rms_norm(e, p["enorm"], self.eps), rms_norm(x_, p["hnorm"], self.eps)], -1)
+            return both @ p["W_eh"]
+
+        own = {name: params[name] for name in ("enorm", "hnorm", "W_eh")}
+        u = scoped("mtp_in", mtp_in, own, params["embed"]["W"], x, labels)
+        z, block_state = self.block.forward(params["block"], state.get("block", {}), u,
+                                            training=training, rng=rng, mask=mask)
+        valid = jnp.ones(labels.shape, jnp.float32) if mask is None else mask.astype(jnp.float32)
+        # position i is scored against the label at i + 1: the last has none
+        valid = (valid * jnp.roll(valid, -1, axis=1)).at[:, -1].set(0.0)
+
+        def score(head_p, w, z_, labels_, valid_):
+            return self.head.compute_loss(head_p, rms_norm(z_, w, self.eps), jnp.roll(labels_, -1, axis=1),
+                                          mask=valid_)
+
+        loss = scoped("lm_head", score, params["head"], params["norm"], z, labels, valid).astype(jnp.float32)
+        new_state = dict(state, mtp_loss=loss, _aux_loss=self.weight * loss)
+        if "block" in state:
+            new_state["block"] = block_state
+            if "_aux_loss" in block_state:  # the block's own (an expert layer's balancing term)
+                new_state["_aux_loss"] = new_state["_aux_loss"] + block_state["_aux_loss"]
+        return x, new_state
+
+    def regularizable_params(self):
+        return ("W_eh",) + tuple(self.block.regularizable_params())

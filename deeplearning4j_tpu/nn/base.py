@@ -156,9 +156,17 @@ class Layer:
     constraints: Any = None
     bias_constraints: Any = None
     weight_noise: Any = None
+    # Parameters this layer reads but another layer owns: ``{name here:
+    # "<layer key>" or "<layer key>/<param>"}`` (see ``with_tied``)
+    tied: Optional[Dict[str, str]] = None
     # GlobalConfig attached by the network at build time (not serialized) so
     # forward() needs no extra argument.
     _g: Any = dataclasses.field(default=None, repr=False, compare=False)
+
+    # A layer that sets this gets the batch's labels as ``forward(...,
+    # labels=)`` wherever the network has them (its training loss; ``None``
+    # at inference), e.g. to train on a second prediction per position.
+    takes_labels = False
 
     # ---- shape inference ----
     def output_type(self, input_type: InputType) -> InputType:
@@ -252,6 +260,29 @@ class Layer:
                                           if a != "type"})
             kwargs[k] = v
         return target(**kwargs)
+
+
+def with_tied(layer: Layer, own: Dict, all_params: Dict) -> Dict:
+    """``own`` (the layer's parameters) plus what ``layer.tied`` names of
+    other layers' parameters, each under its name here.
+
+    A tied parameter is ONE leaf of the network's parameter tree, held by
+    the layer that owns it; every layer that names it reads that leaf
+    inside the same traced step, so its gradient is the sum over its uses
+    and the updater moves it once (weight tying: an output head on the
+    embedding's table, a second prediction layer on the trunk's head)."""
+    if not layer.tied:
+        return own
+    out = dict(own)
+    for here, path in layer.tied.items():
+        node = all_params
+        for part in path.split("/"):
+            if not isinstance(node, dict) or part not in node:
+                raise KeyError(f"{type(layer).__name__}: tied parameter {here!r} names {path!r}, "
+                               f"which is not in the network's parameters ({sorted(all_params)})")
+            node = node[part]
+        out[here] = node
+    return out
 
 
 def spectral_key(key: jax.Array, i: int) -> jax.Array:

@@ -67,6 +67,16 @@ class OutputLayer(DenseLayer):
     loss function fused with the activation for numerical stability."""
 
     loss: Any = LossFunction.MCXENT
+    # keep the last step's data loss of this head in the model state, as the
+    # float32 leaf ``main_loss`` (``MultiLayerNetwork._loss`` writes it): for
+    # a network whose training loss has further terms (``_aux_loss``)
+    record_loss: bool = False
+
+    def init(self, key, input_type, g: GlobalConfig):
+        params, state = super().init(key, input_type, g)
+        if self.record_loss:
+            state = {**state, "main_loss": jnp.zeros((), jnp.float32)}
+        return params, state
 
     def forward(self, params, state, x, *, training=False, rng=None, mask=None):
         x = self._apply_input_dropout(x, self._g, training, rng)

@@ -81,8 +81,11 @@ def test_checks_rehearse_at_a_tiny_preset(tmp_path, monkeypatch):
         env.set_compute_dtype(compute_dtype)
     assert set(report["phases"]) == {"kernels", "char_rnn", "bert_train",
                                      "bert_serve", "kimi_linear",
+                                     "glm_moe_lite",
                                      "four_chips"}
     assert report["kimi_linear"]["last_loss"] < report["kimi_linear"]["first_loss"]
+    glm = report["glm_moe_lite"]
+    assert glm["last_loss"] < glm["first_loss"] and glm["main_loss"] > 0 and glm["mtp_loss"] > 0
     # the delta rule's kernel pair (interpreted here) against its XLA form
     assert report["kimi_linear"]["chunk_kda"]["bwd_err"] < 0.05
     assert set(report["kernels"]) == {

@@ -796,20 +796,23 @@ def test_fused_attention_compiles_for_v5e_at_the_cells_shape(v5e_chip, monkeypat
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
-def test_flash_attention_compiles_for_v5e_with_two_head_sizes_at_t8192(v5e_chip, monkeypatch):
-    """Latent attention's call at the Kimi Linear cell's shape: 32 heads x
-    8192 tokens, a q.k head of 192 against a v head of 128, causal, bf16.
-    The resident backward kernels want 32.8 MB of scoped VMEM there (Mosaic
-    refuses above 32): the route takes the chunked pair, and all three
-    kernels compile."""
+@pytest.mark.parametrize("heads,qk_dim,v_dim", [(32, 192, 128), (20, 256, 256)])
+def test_flash_attention_compiles_for_v5e_with_two_head_sizes_at_t8192(v5e_chip, monkeypatch, heads, qk_dim, v_dim):
+    """Latent attention's call at the Kimi Linear cell's shape (32 heads x
+    8192 tokens, a q.k head of 192 against a v head of 128) and at the
+    GLM-4.7-Flash cell's (20 heads, 192 + 64 against 256: the largest head
+    the route admits), causal, bf16. The resident backward kernels want
+    32.8 MB of scoped VMEM at the first (Mosaic refuses above 32) and more
+    at the second: the route takes the chunked pair, and all three kernels
+    compile."""
     import jax
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.ops.pallas import flash_attention as fa
     monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET")
-    qk = jax.ShapeDtypeStruct((1, 32, 8192, 192), jnp.bfloat16, sharding=v5e_chip)
-    v = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=v5e_chip)
-    assert fa._resident_bwd_bytes(8192, 192, 128, 2) > fa.RESIDENT_BWD_VMEM >= fa._resident_bwd_bytes(8192, 64, 64, 2)
+    qk = jax.ShapeDtypeStruct((1, heads, 8192, qk_dim), jnp.bfloat16, sharding=v5e_chip)
+    v = jax.ShapeDtypeStruct((1, heads, 8192, v_dim), jnp.bfloat16, sharding=v5e_chip)
+    assert fa._resident_bwd_bytes(8192, qk_dim, v_dim, 2) > fa.RESIDENT_BWD_VMEM >= fa._resident_bwd_bytes(8192, 64, 64, 2)
 
     def loss(q, k, v):
         return jnp.sum(fa.flash_attention(q, k, v, causal=True).astype(jnp.float32) ** 2)
