@@ -18,9 +18,9 @@ the backward uses to recompute P = exp(S - L) blockwise (never storing the
     D   = rowsum(dO * O)                  (precomputed, fused by XLA)
     dV += P^T @ dO
     dP  = dO @ V^T
-    dS  = P * (dP - D) * scale
-    dQ += dS @ K        (dq kernel: grid over query blocks)
-    dK += dS^T @ Q      (dkv kernel: grid over key blocks)
+    dS  = P * (dP - D)
+    dQ  = scale * sum dS @ K      (dq kernel: grid over query blocks)
+    dK  = scale * sum dS^T @ Q    (dkv kernel: grid over key blocks)
 
 Masking: a key-padding mask becomes an additive bias (0 / -1e30) of shape
 (batch, T_k, 1) streamed per batch row (the grid runs over batch*heads; the
@@ -36,7 +36,39 @@ D=64 on v5e. Longer contexts still shard across chips via ring attention
 
 ``causal=True`` masks the upper triangle AND skips fully-masked key blocks:
 the forward/dq loops stop at the diagonal, the dk/dv loop starts there —
-roughly halving the FLOPs, which XLA's dense softmax cannot do.
+roughly halving the FLOPs, which XLA's dense softmax cannot do. Of the
+blocks that are left only those the diagonal crosses are masked (one a grid
+step where ``block_q == block_k``): each kernel's loop is split in two, and
+the blocks that lie wholly under the diagonal run a body with no iota, no
+compare and no select (0.04 us a tile in the forward, nothing in the
+backward passes, whose mask hides behind their matmuls).
+
+What a kernel does between a tile's matmuls decides how long the MXU waits
+(PR 36, measured on v5e at T=8192; us a (512, 512) tile at heads of 256:
+forward 1.96 -> 1.63, dq pass 2.60 -> 2.43, dk/dv pass 3.51 -> 3.04, against
+1.36 / 2.04 / 2.73 for the matmuls alone at the MXU's peak):
+
+- The accumulators live in VMEM scratch and are updated in place: the output
+  with its running max and denominator in the forward (the two statistics
+  lane-replicated, (block_q, 128)), dq, and dk with dv. A loop carries
+  nothing: what a ``fori_loop`` carries is copied once an iteration (most
+  of what PR 36 won), and two tiles share an iteration (``_loop``).
+- ``scale`` is not multiplied into the tile. Where it is a power of two (a
+  head of 64 or 256) the (block, d) operand a grid step holds (q; k in the
+  dk/dv pass) is scaled once, which is exact; otherwise the scores keep
+  their float32 multiply. dS is accumulated unscaled and the float32 dq / dk
+  accumulator is multiplied once where it is written out.
+- The dk/dv pass computes the tile transposed, ``S^T = K @ Q_blk^T`` and
+  ``dP^T = V @ dO_blk^T``, so that ``dV += P^T @ dO_blk`` and
+  ``dK += dS^T @ Q_blk`` are plain matmuls and no (block_q, block_k) tile goes
+  through a transpose (0.07 us a tile at heads of 256, 0.37 at 192 / 128). It
+  takes the row statistics along lanes, (b*h, 1, T): the forward stores the
+  logsumexp that way too, D is made by XLA in either shape.
+- Carrying the next tile's scores through the loop, so as to start them
+  early, costs more than it hides (the copy again: +0.3 to +0.9 us a tile).
+
+All three kernels are entered through one ``jax.jit`` each, so that the
+call sites of a model, which call at one shape, share one traced kernel.
 
 Used automatically by ``nn.attention_layers.dot_product_attention`` when
 :func:`flash_attention_compatible` says the shapes and the platform allow;
@@ -47,6 +79,7 @@ the kernels in interpreter mode on the CPU backend (test path only).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -123,16 +156,92 @@ def flash_attention_compatible(q, k, v, mask=None, causal: bool = False) -> bool
     return t_k >= MIN_SEQ_FOR_KERNEL and kernels_available()
 
 
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T: both operands contracted over their last axis
+
+
 def _causal_hi(qi, block_q: int, block_k: int):
     """Number of key blocks needed for query block qi under causal masking."""
-    return (qi * block_q + block_q + block_k - 1) // block_k
+    return pl.cdiv((qi + 1) * block_q, block_k)
 
 
-def _diag_mask(s, qi, i, block_q: int, block_k: int):
-    """Apply the causal triangle inside a (block_q, block_k) score tile."""
-    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = i * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(cols <= rows, s, MASK_VALUE)
+def _diag_mask(s, q0, k0, q_axis: int = 0):
+    """Apply the causal triangle inside a score tile whose first query is
+    ``q0`` and whose first key is ``k0``; queries run along ``q_axis``."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(k_pos <= q_pos, s, MASK_VALUE)
+
+
+def _folds(scale: float) -> bool:
+    """Whether ``scale`` is a power of two: an operand times it is exact in
+    every float type, so the (block, d) operand takes it once a grid step
+    in place of every score tile."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _pre_scaled(x, scale: float):
+    return (x.astype(jnp.float32) * scale).astype(x.dtype) if _folds(scale) else x
+
+
+def _scores(a, b, scale: float):
+    """``scale * a @ b^T`` in float32, for ``a`` through :func:`_pre_scaled`."""
+    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    return s if _folds(scale) else s * scale
+
+
+# Tiles a loop iteration: a loop's back edge is a wall to Mosaic's scheduler,
+# so an iteration of one tile starts its first matmul only when the last
+# accumulate of the tile before is through; with two in a body the second's
+# scores run on the MXU while the VPU is on the first's softmax (measured on
+# v5e at T=8192, heads of 256, us a (512, 512) tile: forward 1.74 -> 1.63,
+# the backward passes 0.03 each; four copies of the tile in the traced body).
+TILES_PER_ITERATION = 2
+
+
+def _loop(tile, lo, hi, masked: bool):
+    """``tile(i, masked)`` for ``lo <= i < hi``. The tiles accumulate into
+    VMEM scratch in place and the loop carries nothing: what a ``fori_loop``
+    carries is copied once an iteration, ~2.7 cycles a vreg (measured on
+    v5e, PR 36: a carried (512, 256) float32 accumulator with its (512,) row
+    statistics cost 0.22 us a tile, a carried (512, 512) score tile 0.3).
+    The masked blocks are the few the diagonal crosses: one an iteration."""
+    def one(i, _):
+        tile(i, masked)
+
+    if not masked:
+        def several(j, _):
+            for u in range(TILES_PER_ITERATION):
+                tile(lo + TILES_PER_ITERATION * j + u, masked)
+
+        whole = (hi - lo) // TILES_PER_ITERATION
+        jax.lax.fori_loop(0, whole, several, None)
+        lo = lo + TILES_PER_ITERATION * whole
+    jax.lax.fori_loop(lo, hi, one, None)
+
+
+def _heads_flat(x):
+    """(b, h, t, d) as the kernels' (b * h, t, d)."""
+    return x.reshape(-1, *x.shape[2:])
+
+
+def _lanes(x, n: int):
+    """A lane-replicated (rows, 128) row statistic as (rows, n)."""
+    return jnp.tile(x, (1, n // 128)) if n % 128 == 0 else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _stepwise(n_chunks, init, work, flush):
+    """A grid step of a kernel that accumulates in scratch: resident
+    (``n_chunks`` None) it does all three, chunked the first chunk's step
+    initialises and the last one's flushes."""
+    if n_chunks is None:
+        init()
+        work(0)
+        flush()
+        return
+    ci = pl.program_id(2)
+    pl.when(ci == 0)(init)
+    work(ci)
+    pl.when(ci == n_chunks - 1)(flush)
 
 
 # ---------------------------------------------------------------- forward
@@ -140,74 +249,73 @@ def _diag_mask(s, qi, i, block_q: int, block_k: int):
 
 def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
                 causal: bool, save_residuals: bool):
-    if has_bias:
-        q_ref, k_ref, v_ref, bias_ref = refs[:4]
-        rest = refs[4:]
-    else:
-        q_ref, k_ref, v_ref = refs[:3]
-        bias_ref = None
-        rest = refs[3:]
-    o_ref = rest[0]
-    lse_ref = rest[1] if save_residuals else None
+    q_ref, k_ref, v_ref = refs[:3]
+    bias_ref = refs[3] if has_bias else None
+    o_ref = refs[3 + has_bias]
+    acc_ref, m_ref, l_ref = refs[-3:]  # (BQ, Dv), and lane-replicated (BQ, 128) row statistics
 
     # Matmul operands stay in the input dtype (bf16 on the fast path) so the
     # MXU runs at full rate; accumulation and softmax stats are f32.
-    q = q_ref[0]  # (BLOCK_Q, D)
+    q = _pre_scaled(q_ref[0], scale)  # (BLOCK_Q, D)
     in_dtype = q.dtype
     qi = pl.program_id(1)
-    t_k = k_ref.shape[1]
-    n_blocks = t_k // block_k
     block_q = q.shape[0]
+    d_v = v_ref.shape[2]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    def body(i, carry):
-        acc, m, l = carry
+    def tile(i, masked):
         k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
         v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        s = _scores(q, k_blk, scale)
         if bias_ref is not None:
             s = s + bias_ref[0, pl.ds(i * block_k, block_k), 0][None, :]
-        if causal:
-            s = _diag_mask(s, qi, i, block_q, block_k)
-        m_blk = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m, m_blk)
-        p = jnp.exp(s - m_new[:, None])
+        if masked:
+            s = _diag_mask(s, qi * block_q, i * block_k)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, block_k))
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=1)
-        acc_new = acc * corr[:, None] + jax.lax.dot(
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _lanes(corr, d_v) + jax.lax.dot(
             p.astype(in_dtype), v_blk, preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
 
-    bq, d_v = q.shape[0], v_ref.shape[2]
-    acc = jnp.zeros((bq, d_v), jnp.float32)
-    m = jnp.full((bq,), -jnp.inf, jnp.float32)
-    l = jnp.zeros((bq,), jnp.float32)
-    hi = _causal_hi(qi, block_q, block_k) if causal else n_blocks
-    acc, m, l = jax.lax.fori_loop(0, hi, body, (acc, m, l))
-    l_safe = jnp.maximum(l, 1e-20)
-    o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    if lse_ref is not None:  # residuals only requested under differentiation
-        lse = m + jnp.log(l_safe)
-        lse_ref[0] = jax.lax.broadcast_in_dim(lse, (bq, RES_LANES), (0,))
+    if causal:
+        # the key blocks wholly under the diagonal, then those it crosses
+        below = (qi * block_q) // block_k
+        _loop(tile, 0, below, False)
+        _loop(tile, below, _causal_hi(qi, block_q, block_k), True)
+    else:
+        _loop(tile, 0, k_ref.shape[1] // block_k, False)
+    l_safe = jnp.maximum(l_ref[...], 1e-20)
+    o_ref[0] = (acc_ref[...] / _lanes(l_safe, d_v)).astype(o_ref.dtype)
+    if save_residuals:  # only requested under differentiation
+        # logsumexp twice: a row a sublane for the dq pass, along lanes for
+        # the dk/dv pass (a re-layout in XLA would read the padded array)
+        lse_ref, lse_lanes_ref = refs[4 + has_bias:6 + has_bias]
+        lse = m_ref[...] + jnp.log(l_safe)
+        lse_ref[0] = lse[:, :RES_LANES]
+        lse_lanes_ref[0] = lse[:, 0][None, :]
 
 
-def _flash_fwd(q, k, v, bias, scale, causal, has_bias, save_residuals=True):
+_STATIC = ("scale", "causal", "has_bias", "block_q", "block_k", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("save_residuals",))
+def _flash_fwd(q, k, v, bias, *, scale, causal, has_bias, block_q, block_k,
+               interpret, save_residuals):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
     d_v = v.shape[-1]
-    qf = q.reshape(b * h, t_q, d)
-    kf = k.reshape(b * h, t_k, d)
-    vf = v.reshape(b * h, t_k, d_v)
-    block_q = _pick_block(t_q, BLOCK_Q)
-    block_k = _pick_block(t_k, BLOCK_K)
     grid = (b * h, t_q // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         pl.BlockSpec((1, t_k, d), lambda bh, qi: (bh, 0, 0)),
         pl.BlockSpec((1, t_k, d_v), lambda bh, qi: (bh, 0, 0)),
     ]
-    args = [qf, kf, vf]
+    args = [_heads_flat(q), _heads_flat(k), _heads_flat(v)]
     if has_bias:
         # bias is (b, t_k, 1); the index map divides the grid's batch*heads
         # row by heads, so all heads of one batch share the same block.
@@ -221,6 +329,8 @@ def _flash_fwd(q, k, v, bias, scale, causal, has_bias, save_residuals=True):
             jax.ShapeDtypeStruct((b * h, t_q, RES_LANES), jnp.float32))
         out_specs.append(
             pl.BlockSpec((1, block_q, RES_LANES), lambda bh, qi: (bh, qi, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b * h, 1, t_q), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)))
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                           has_bias=has_bias, causal=causal,
@@ -230,468 +340,318 @@ def _flash_fwd(q, k, v, bias, scale, causal, has_bias, save_residuals=True):
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((block_q, d_v), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32)],
         compiler_params=COMPILER_PARAMS,
-        interpret=_interpret(),
+        interpret=interpret,
     )(*args)
-    out = res[0].reshape(b, h, t_q, d_v)
-    return (out, res[1]) if save_residuals else (out, None)
+    return (res[0].reshape(b, h, t_q, d_v), *res[1:])
 
 
 # ---------------------------------------------------------------- backward
+#
+# Each pass has a RESIDENT form (grid (bh, block): the full K/V, or Q/dO,
+# of a head is one VMEM block a grid step) and a CHUNKED form (grid
+# (bh, block, chunk): those operands stream through VMEM a chunk a step and
+# the float32 scratch accumulators persist across the sequential minor grid
+# steps, flushed at the last).
 
 
 def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
-                   causal: bool):
-    if has_bias:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref = refs[:7]
-        dq_ref = refs[7]
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-        bias_ref = None
-        dq_ref = refs[6]
-    q = q_ref[0]                              # (BQ, D)
+                   causal: bool, n_chunks):
+    """dq pass. Resident (``n_chunks`` None): grid (bh, qi). Chunked: grid
+    (bh, qi, ci), K/V blocks are the ci-th chunk. dq accumulates in scratch,
+    unscaled, and takes ``scale`` where it is written out."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    bias_ref = refs[6] if has_bias else None
+    dq_ref, acc_ref = refs[-2:]
+    q = _pre_scaled(q_ref[0], scale)          # (BQ, D)
     do = do_ref[0]                            # (BQ, Dv)
     in_dtype = q.dtype
     lse = lse_ref[0][:, 0]                    # (BQ,)
     delta = delta_ref[0][:, 0]                # (BQ,)
     qi = pl.program_id(1)
-    t_k = k_ref.shape[1]
-    n_blocks = t_k // block_k
     block_q = q.shape[0]
+    nb = k_ref.shape[1] // block_k
 
-    def body(i, dq_acc):
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, pl.ds(i * block_k, block_k), 0][None, :]
-        if causal:
-            s = _diag_mask(s, qi, i, block_q, block_k)
-        p = jnp.exp(s - lse[:, None])                       # (BQ, BK)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(in_dtype)
-        return dq_acc + jax.lax.dot(ds, k_blk,
-                                    preferred_element_type=jnp.float32)
+    def init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    hi = _causal_hi(qi, block_q, block_k) if causal else n_blocks
-    dq = jax.lax.fori_loop(0, hi,
-                           body, jnp.zeros(q.shape, jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    def work(ci):
+        first = ci * nb  # global index of this chunk's first key block
+
+        def tile(i, masked):
+            k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
+            v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
+            s = _scores(q, k_blk, scale)
+            if bias_ref is not None:
+                s = s + bias_ref[0, pl.ds(i * block_k, block_k), 0][None, :]
+            if masked:
+                s = _diag_mask(s, qi * block_q, (first + i) * block_k)
+            p = jnp.exp(s - lse[:, None])                       # (BQ, BK)
+            dp = jax.lax.dot_general(do, v_blk, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta[:, None])).astype(in_dtype)
+            acc_ref[...] += jax.lax.dot(ds, k_blk,
+                                        preferred_element_type=jnp.float32)
+
+        if not causal:
+            _loop(tile, 0, nb, False)
+            return
+        # of this chunk: the key blocks wholly under the diagonal, then
+        # those it crosses; the blocks above it are skipped
+        below = jnp.clip((qi * block_q) // block_k - first, 0, nb)
+        hi = jnp.clip(_causal_hi(qi, block_q, block_k) - first, 0, nb)
+        _loop(tile, 0, below, False)
+        _loop(tile, below, hi, True)
+
+    def flush():
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+    _stepwise(n_chunks, init, work, flush)
 
 
 def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
-                    causal: bool):
-    if has_bias:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref = refs[:7]
-        dk_ref, dv_ref = refs[7:9]
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-        bias_ref = None
-        dk_ref, dv_ref = refs[6:8]
-    k = k_ref[0]                              # (BK, D)
+                    causal: bool, n_chunks):
+    """dk/dv pass. Resident (``n_chunks`` None): grid (bh, ki). Chunked:
+    grid (bh, ki, ci), Q/dO/lse/delta blocks are the ci-th chunk. The score
+    tile is computed transposed, (BK, BQ): keys on sublanes, queries on
+    lanes, so that both results are plain matmuls of it; ``lse`` and
+    ``delta`` come along lanes. dk accumulates unscaled."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    # this key block's bias (shared across q blocks), one a row of the tile
+    bias = refs[6][0] if has_bias else None   # (BK, 1)
+    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = refs[-4:]
+    k = _pre_scaled(k_ref[0], scale)          # (BK, D): for the scores alone
     v = v_ref[0]                              # (BK, Dv)
     in_dtype = k.dtype
     ki = pl.program_id(1)
-    t_q = q_ref.shape[1]
-    n_blocks = t_q // block_q
     block_k = k.shape[0]
-    # this key block's bias column (shared across q blocks)
-    bias_col = (bias_ref[0, pl.ds(ki * block_k, block_k), 0]
-                if bias_ref is not None else None)
+    nb = q_ref.shape[1] // block_q
 
-    def body(i, carry):
-        dk_acc, dv_acc = carry
-        q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse_blk = lse_ref[0, pl.ds(i * block_q, block_q), :][:, 0]
-        delta_blk = delta_ref[0, pl.ds(i * block_q, block_q), :][:, 0]
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if bias_col is not None:
-            s = s + bias_col[None, :]
-        if causal:
-            s = _diag_mask(s, i, ki, block_q, block_k)
-        p = jnp.exp(s - lse_blk[:, None])                   # (BQ, BK)
-        p_cast = p.astype(in_dtype)
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p_cast, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (BK, Dv)
-        dp = jax.lax.dot_general(
-            do_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_blk[:, None]) * scale).astype(in_dtype)
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (BK, D)
-        return dk_acc, dv_acc
-
-    # under causal masking, query blocks strictly above the diagonal
-    # contribute nothing to this key block
-    lo = (ki * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(
-        lo, n_blocks, body,
-        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-# Above this sequence length the backward switches to the CHUNKED kernels:
-# the single-chunk forms keep full K/V (dq pass) and full Q/dO (dkv pass)
-# VMEM-resident per grid step, which blows the ~16 MB VMEM budget past
-# T=8192; the chunked forms stream those operands through VMEM in
-# BWD_CHUNK-row chunks via a third grid dimension, accumulating in f32
-# scratch that persists across the (sequential) minor grid steps. The two
-# kernel families are NOT unified into always-chunked (measured on v5e:
-# chunked == resident at T=8192, 17.9 ms both, but causal T=2048 runs
-# 6.3 vs 4.8 ms chunked — the 3-D grid + scratch structure costs ~30% at
-# short causal lengths, so the resident forms stay for T <= threshold).
-BWD_CHUNK_THRESHOLD = 8192
-BWD_CHUNK = 4096
-# ... and, whatever the length, where the resident forms' full-sequence
-# operands would not fit the kernels' scoped VMEM (32 MiB asked for):
-# q/k and v/dO rows in the input dtype plus the two float32 row statistics,
-# which Mosaic pads from RES_LANES to 128 lanes, all double-buffered. At
-# T=8192 a head of 64 needs 21 MB and stays resident; a q.k head of 192
-# against a v head of 128 needs 27 MB plus its blocks, and Mosaic refused
-# it by 0.8 MB (compiled for a v5e, PR 30).
-RESIDENT_BWD_VMEM = 24 * 1024 * 1024
-
-
-def _bwd_dq_kernel_chunked(*refs, scale: float, block_k: int,
-                           has_bias: bool, causal: bool, n_chunks: int):
-    """dq pass with K/V streamed in chunks: grid (bh, qi, ci); K/V blocks
-    are the ci-th chunk; dq accumulates in scratch, flushed at the last
-    chunk."""
-    if has_bias:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref = refs[:7]
-        dq_ref, acc_ref = refs[7], refs[8]
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-        bias_ref = None
-        dq_ref, acc_ref = refs[6], refs[7]
-    q = q_ref[0]
-    do = do_ref[0]
-    in_dtype = q.dtype
-    lse = lse_ref[0][:, 0]
-    delta = delta_ref[0][:, 0]
-    qi = pl.program_id(1)
-    ci = pl.program_id(2)
-    chunk_k = k_ref.shape[1]
-    nb = chunk_k // block_k
-    block_q = q.shape[0]
-
-    @pl.when(ci == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def body(i, dq_acc):
-        kb = ci * nb + i  # global key-block index (for the causal mask)
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, pl.ds(i * block_k, block_k), 0][None, :]
-        if causal:
-            s = _diag_mask(s, qi, kb, block_q, block_k)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(in_dtype)
-        return dq_acc + jax.lax.dot(ds, k_blk,
-                                    preferred_element_type=jnp.float32)
-
-    if causal:
-        hi_global = _causal_hi(qi, block_q, block_k)
-        nblk = jnp.clip(hi_global - ci * nb, 0, nb)
-    else:
-        nblk = nb
-    acc_ref[...] = jax.lax.fori_loop(0, nblk, body, acc_ref[...])
-
-    @pl.when(ci == n_chunks - 1)
-    def _flush():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel_chunked(*refs, scale: float, block_q: int,
-                            has_bias: bool, causal: bool, n_chunks: int):
-    """dk/dv pass with Q/dO/lse/delta streamed in chunks: grid
-    (bh, ki, ci); scratch accumulators flushed at the last chunk."""
-    if has_bias:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref = refs[:7]
-        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = refs[7:11]
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-        bias_ref = None
-        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = refs[6:10]
-    k = k_ref[0]
-    v = v_ref[0]
-    in_dtype = k.dtype
-    ki = pl.program_id(1)
-    ci = pl.program_id(2)
-    chunk_q = q_ref.shape[1]
-    nb = chunk_q // block_q
-    block_k = k.shape[0]
-    bias_col = (bias_ref[0, :, 0] if bias_ref is not None else None)
-
-    @pl.when(ci == 0)
-    def _init():
+    def init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    def body(i, carry):
-        dk_acc, dv_acc = carry
-        qb = ci * nb + i  # global query-block index
-        q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse_blk = lse_ref[0, pl.ds(i * block_q, block_q), :][:, 0]
-        delta_blk = delta_ref[0, pl.ds(i * block_q, block_q), :][:, 0]
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if bias_col is not None:
-            s = s + bias_col[None, :]
-        if causal:
-            s = _diag_mask(s, qb, ki, block_q, block_k)
-        p = jnp.exp(s - lse_blk[:, None])
-        p_cast = p.astype(in_dtype)
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p_cast, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_blk[:, None]) * scale).astype(in_dtype)
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
+    def work(ci):
+        first = ci * nb  # global index of this chunk's first query block
 
-    if causal:
-        lo_global = (ki * block_k) // block_q
-        lo = jnp.clip(lo_global - ci * nb, 0, nb)
-    else:
-        lo = 0
-    dk, dv = jax.lax.fori_loop(lo, nb, body,
-                               (dk_acc_ref[...], dv_acc_ref[...]))
-    dk_acc_ref[...] = dk
-    dv_acc_ref[...] = dv
+        def tile(i, masked):
+            rows = pl.ds(i * block_q, block_q)
+            q_blk = q_ref[0, rows, :]
+            do_blk = do_ref[0, rows, :]
+            s = _scores(k, q_blk, scale)                        # (BK, BQ)
+            if bias is not None:
+                s = s + bias
+            if masked:
+                s = _diag_mask(s, (first + i) * block_q, ki * block_k, q_axis=1)
+            p = jnp.exp(s - lse_ref[0, :, rows])
+            dv_acc_ref[...] += jax.lax.dot(p.astype(in_dtype), do_blk,
+                                           preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do_blk, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, :, rows])).astype(in_dtype)
+            dk_acc_ref[...] += jax.lax.dot(ds, q_blk,
+                                           preferred_element_type=jnp.float32)
 
-    @pl.when(ci == n_chunks - 1)
-    def _flush():
-        dk_ref[0] = dk_acc_ref[...].astype(dk_ref.dtype)
+        if not causal:
+            _loop(tile, 0, nb, False)
+            return
+        # of this chunk: query blocks strictly above the diagonal contribute
+        # nothing to this key block; then those the diagonal crosses, then
+        # those wholly under it
+        lo = jnp.clip((ki * block_k) // block_q - first, 0, nb)
+        clear = jnp.clip(pl.cdiv((ki + 1) * block_k, block_q) - first, 0, nb)
+        _loop(tile, lo, clear, True)
+        _loop(tile, clear, nb, False)
+
+    def flush():
+        dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
+    _stepwise(n_chunks, init, work, flush)
 
-def _flash_bwd_chunked(q, k, v, bias, out, lse, g, scale, causal, has_bias):
-    """Backward for T > BWD_CHUNK_THRESHOLD: same math as ``_flash_bwd``,
-    with the full-sequence operands streamed chunkwise (third grid dim)."""
-    b, h, t_q, d = q.shape
-    t_k = k.shape[2]
-    d_v = v.shape[-1]
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
-    qf = q.reshape(b * h, t_q, d)
-    kf = k.reshape(b * h, t_k, d)
-    vf = v.reshape(b * h, t_k, d_v)
-    dof = g.reshape(b * h, t_q, d_v)
-    lsef = lse
-    deltaf = jnp.broadcast_to(delta.reshape(b * h, t_q, 1),
-                              (b * h, t_q, RES_LANES))
-    block_q = _pick_block(t_q, BLOCK_Q)
-    block_k = _pick_block(t_k, BLOCK_K)
-
-    def _pick_chunk(t, block):
-        # largest multiple of `block` <= BWD_CHUNK that divides t (the
-        # kernels index sub-blocks inside the chunk, so block | chunk)
-        c = (BWD_CHUNK // block) * block
-        while c > block and t % c:
-            c -= block
-        return c
-
-    chunk_k = _pick_chunk(t_k, block_k)
-    chunk_q = _pick_chunk(t_q, block_q)
-    n_chunks_k = t_k // chunk_k
-    n_chunks_q = t_q // chunk_q
-
-    if causal:
-        # Steps whose whole K/V chunk lies above the causal diagonal are
-        # compute-skipped in the kernel (nblk clips to 0) — ALSO skip
-        # their DMA by re-mapping the chunk index to the last needed
-        # chunk: consecutive grid steps with the same block index reuse
-        # the resident block, so dead chunks are never fetched.
-        def _k_chunk(bh, qi, ci):
-            return (bh, jnp.minimum(ci, ((qi + 1) * block_q - 1) // chunk_k),
-                    0)
-    else:
-        def _k_chunk(bh, qi, ci):
-            return (bh, ci, 0)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, ci: (bh, qi, 0)),
-        pl.BlockSpec((1, chunk_k, d), _k_chunk),
-        pl.BlockSpec((1, chunk_k, d_v), _k_chunk),
-        pl.BlockSpec((1, block_q, d_v), lambda bh, qi, ci: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, RES_LANES), lambda bh, qi, ci: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, RES_LANES), lambda bh, qi, ci: (bh, qi, 0)),
-    ]
-    args = [qf, kf, vf, dof, lsef, deltaf]
-    if has_bias:
-        in_specs.append(
-            pl.BlockSpec((1, chunk_k, 1),
-                         lambda bh, qi, ci: (bh // h,) + _k_chunk(bh, qi, ci)[1:]))
-        args.append(bias)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_chunked, scale=scale,
-                          block_k=block_k, has_bias=has_bias, causal=causal,
-                          n_chunks=n_chunks_k),
-        name="flash_attention_bwd_dq_chunked",
-        out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-        grid=(b * h, t_q // block_q, n_chunks_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, ci: (bh, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(*args)
-
-    if causal:
-        # mirror of the dq-pass DMA skip: query chunks strictly above the
-        # diagonal for this key block re-map to the first needed chunk
-        def _q_chunk(bh, ki, ci):
-            return (bh, jnp.maximum(ci, (ki * block_k) // chunk_q), 0)
-    else:
-        def _q_chunk(bh, ki, ci):
-            return (bh, ci, 0)
-    in_specs_kv = [
-        pl.BlockSpec((1, chunk_q, d), _q_chunk),
-        pl.BlockSpec((1, block_k, d), lambda bh, ki, ci: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, d_v), lambda bh, ki, ci: (bh, ki, 0)),
-        pl.BlockSpec((1, chunk_q, d_v), _q_chunk),
-        pl.BlockSpec((1, chunk_q, RES_LANES), _q_chunk),
-        pl.BlockSpec((1, chunk_q, RES_LANES), _q_chunk),
-    ]
-    args_kv = [qf, kf, vf, dof, lsef, deltaf]
-    if has_bias:
-        in_specs_kv.append(
-            pl.BlockSpec((1, block_k, 1), lambda bh, ki, ci: (bh // h, ki, 0)))
-        args_kv.append(bias)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_chunked, scale=scale,
-                          block_q=block_q, has_bias=has_bias, causal=causal,
-                          n_chunks=n_chunks_q),
-        name="flash_attention_bwd_dkv_chunked",
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t_k, d_v), v.dtype),
-        ],
-        grid=(b * h, t_k // block_k, n_chunks_q),
-        in_specs=in_specs_kv,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ki, ci: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda bh, ki, ci: (bh, ki, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d_v), jnp.float32)],
-        compiler_params=COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(*args_kv)
-
-    return (dq.reshape(b, h, t_q, d), dk.reshape(b, h, t_k, d),
-            dv.reshape(b, h, t_k, d_v))
+# Above this sequence length the backward switches to the CHUNKED forms:
+# the resident forms keep full K/V (dq pass) and full Q/dO (dkv pass)
+# VMEM-resident per grid step, which blows the ~16 MB VMEM budget past
+# T=8192; the chunked forms stream those operands through VMEM in
+# BWD_CHUNK-row chunks via a third grid dimension. Both forms are one kernel
+# a pass (`_bwd_dq_kernel`, `_bwd_dkv_kernel`): the same loops over a grid
+# step's blocks (under `causal` the blocks the diagonal crosses masked, the
+# blocks under it plain, the blocks above it skipped, each range clipped to
+# the chunk), accumulating in place in f32 scratch, which in the chunked
+# form persists across the (sequential) minor grid steps and is scaled and
+# flushed at the last. The resident grid is NOT dropped for always-chunked
+# (measured on v5e: chunked == resident at T=8192, 17.9 ms both, but causal
+# T=2048 runs 6.3 vs 4.8 ms chunked — the 3-D grid costs ~30% at short
+# causal lengths, so the resident forms stay for T <= threshold).
+BWD_CHUNK_THRESHOLD = 8192
+BWD_CHUNK = 4096
+# ... and, whatever the length, where `_resident_bwd_bytes` passes this: q/k
+# and v/dO rows in the input dtype plus two float32 row statistics padded
+# from RES_LANES to 128 lanes (as the dk/dv pass took them until PR 36; it
+# now takes them along lanes, (1, T), and the rule was left as it was: at
+# T=8192 the forms cost the same), all double-buffered, against the
+# kernels' scoped VMEM (32 MiB asked for). At T=8192 a head of 64 counts
+# 21 MB and stays resident; a q.k head of 192 against a v head of 128
+# counted 27 MB plus its blocks, and Mosaic refused it by 0.8 MB (compiled
+# for a v5e, PR 30).
+RESIDENT_BWD_VMEM = 24 * 1024 * 1024
 
 
 def _resident_bwd_bytes(t: int, d: int, d_v: int, itemsize: int) -> int:
     return 2 * t * ((d + d_v) * itemsize + 2 * 128 * 4)
 
 
-def _flash_bwd(q, k, v, bias, out, lse, g, scale, causal, has_bias):
-    t = max(q.shape[2], k.shape[2])
-    if (t > BWD_CHUNK_THRESHOLD
-            or _resident_bwd_bytes(t, q.shape[-1], v.shape[-1], q.dtype.itemsize) > RESIDENT_BWD_VMEM):
-        return _flash_bwd_chunked(q, k, v, bias, out, lse, g, scale,
-                                  causal, has_bias)
+def _pick_chunk(t: int, block: int) -> int:
+    """Largest multiple of ``block`` <= BWD_CHUNK that divides t (the
+    kernels index sub-blocks inside the chunk, so block | chunk)."""
+    c = (BWD_CHUNK // block) * block
+    while c > block and t % c:
+        c -= block
+    return c
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("chunk",))
+def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
+                  has_bias, block_q, block_k, interpret):
+    """dq from (b, h, t, d) operands and (b*h, t_q, RES_LANES) row
+    statistics; K/V whole a grid step (``chunk`` None) or ``chunk`` rows."""
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
     d_v = v.shape[-1]
-    # D = rowsum(dO * O): cheap elementwise-reduce, fused by XLA, stored
-    # lane-broadcast like lse (Mosaic block layout requirement).
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    chunk_k = chunk or t_k
 
-    qf = q.reshape(b * h, t_q, d)
-    kf = k.reshape(b * h, t_k, d)
-    vf = v.reshape(b * h, t_k, d_v)
-    dof = g.reshape(b * h, t_q, d_v)
-    lsef = lse  # already (b*h, t_q, RES_LANES) from the forward
-    deltaf = jnp.broadcast_to(delta.reshape(b * h, t_q, 1),
-                              (b * h, t_q, RES_LANES))
-    block_q = _pick_block(t_q, BLOCK_Q)
-    block_k = _pick_block(t_k, BLOCK_K)
-    bias_spec_q = pl.BlockSpec((1, t_k, 1), lambda bh, qi: (bh // h, 0, 0))
-    bias_spec_k = pl.BlockSpec((1, t_k, 1), lambda bh, ki: (bh // h, 0, 0))
+    def own(bh, qi, *ci):
+        return (bh, qi, 0)
+
+    def streamed(bh, qi, *ci):
+        if not ci:
+            return (bh, 0, 0)
+        # Steps whose whole K/V chunk lies above the causal diagonal are
+        # compute-skipped in the kernel (its ranges clip to nothing) — ALSO
+        # skip their DMA by re-mapping the chunk index to the last needed
+        # chunk: consecutive grid steps with the same block index reuse
+        # the resident block, so dead chunks are never fetched.
+        last = ((qi + 1) * block_q - 1) // chunk_k
+        return (bh, jnp.minimum(ci[0], last) if causal else ci[0], 0)
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, t_k, d), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((1, t_k, d_v), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((1, block_q, d_v), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, RES_LANES), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, block_q, RES_LANES), lambda bh, qi: (bh, qi, 0)),
+        pl.BlockSpec((1, block_q, d), own),
+        pl.BlockSpec((1, chunk_k, d), streamed),
+        pl.BlockSpec((1, chunk_k, d_v), streamed),
+        pl.BlockSpec((1, block_q, d_v), own),
+        pl.BlockSpec((1, block_q, RES_LANES), own),
+        pl.BlockSpec((1, block_q, RES_LANES), own),
     ]
-    args = [qf, kf, vf, dof, lsef, deltaf]
+    args = [*map(_heads_flat, (q, k, v, do)), lse, delta]
     if has_bias:
-        in_specs.append(bias_spec_q)
+        in_specs.append(pl.BlockSpec(
+            (1, chunk_k, 1), lambda bh, *at: (bh // h,) + streamed(bh, *at)[1:]))
         args.append(bias)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
-                          has_bias=has_bias, causal=causal),
-        name="flash_attention_bwd_dq",
+                          has_bias=has_bias, causal=causal,
+                          n_chunks=t_k // chunk if chunk else None),
+        name="flash_attention_bwd_dq" + ("_chunked" if chunk else ""),
         out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-        grid=(b * h, t_q // block_q),
+        grid=(b * h, t_q // block_q) + ((t_k // chunk,) if chunk else ()),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, block_q, d), own),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=COMPILER_PARAMS,
-        interpret=_interpret(),
+        interpret=interpret,
     )(*args)
+    return dq.reshape(b, h, t_q, d)
 
-    in_specs_kv = [
-        pl.BlockSpec((1, t_q, d), lambda bh, ki: (bh, 0, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, block_k, d_v), lambda bh, ki: (bh, ki, 0)),
-        pl.BlockSpec((1, t_q, d_v), lambda bh, ki: (bh, 0, 0)),
-        pl.BlockSpec((1, t_q, RES_LANES), lambda bh, ki: (bh, 0, 0)),
-        pl.BlockSpec((1, t_q, RES_LANES), lambda bh, ki: (bh, 0, 0)),
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("chunk",))
+def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
+                   has_bias, block_q, block_k, interpret):
+    """dk, dv from (b, h, t, d) operands and (b*h, 1, t_q) row statistics;
+    Q/dO whole a grid step (``chunk`` None) or ``chunk`` rows."""
+    b, h, t_q, d = q.shape
+    t_k = k.shape[2]
+    d_v = v.shape[-1]
+    chunk_q = chunk or t_q
+
+    def own(bh, ki, *ci):
+        return (bh, ki, 0)
+
+    def streamed(bh, ki, *ci):
+        if not ci:
+            return 0
+        # mirror of the dq-pass DMA skip: query chunks strictly above the
+        # diagonal for this key block re-map to the first needed chunk
+        first = (ki * block_k) // chunk_q
+        return jnp.maximum(ci[0], first) if causal else ci[0]
+
+    def rows(bh, *at):
+        return (bh, streamed(bh, *at), 0)
+
+    def lanes(bh, *at):
+        return (bh, 0, streamed(bh, *at))
+
+    in_specs = [
+        pl.BlockSpec((1, chunk_q, d), rows),
+        pl.BlockSpec((1, block_k, d), own),
+        pl.BlockSpec((1, block_k, d_v), own),
+        pl.BlockSpec((1, chunk_q, d_v), rows),
+        pl.BlockSpec((1, 1, chunk_q), lanes),
+        pl.BlockSpec((1, 1, chunk_q), lanes),
     ]
-    args_kv = [qf, kf, vf, dof, lsef, deltaf]
+    args = [*map(_heads_flat, (q, k, v, do)), lse, delta]
     if has_bias:
-        in_specs_kv.append(bias_spec_k)
-        args_kv.append(bias)
+        in_specs.append(pl.BlockSpec(
+            (1, block_k, 1), lambda bh, ki, *ci: (bh // h, ki, 0)))
+        args.append(bias)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          has_bias=has_bias, causal=causal),
-        name="flash_attention_bwd_dkv",
+                          has_bias=has_bias, causal=causal,
+                          n_chunks=t_q // chunk if chunk else None),
+        name="flash_attention_bwd_dkv" + ("_chunked" if chunk else ""),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, t_k, d_v), v.dtype),
         ],
-        grid=(b * h, t_k // block_k),
-        in_specs=in_specs_kv,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda bh, ki: (bh, ki, 0)),
-        ],
+        grid=(b * h, t_k // block_k) + ((t_q // chunk,) if chunk else ()),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((1, block_k, d), own),
+                   pl.BlockSpec((1, block_k, d_v), own)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         compiler_params=COMPILER_PARAMS,
-        interpret=_interpret(),
-    )(*args_kv)
+        interpret=interpret,
+    )(*args)
+    return dk.reshape(b, h, t_k, d), dv.reshape(b, h, t_k, d_v)
 
-    return (dq.reshape(b, h, t_q, d), dk.reshape(b, h, t_k, d),
-            dv.reshape(b, h, t_k, d_v))
+
+def _blocks(q, k) -> dict:
+    return dict(block_q=_pick_block(q.shape[2], BLOCK_Q),
+                block_k=_pick_block(k.shape[2], BLOCK_K), interpret=_interpret())
+
+
+def _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale, causal, has_bias):
+    b, h, t_q, d = q.shape
+    t_k = k.shape[2]
+    kw = dict(scale=scale, causal=causal, has_bias=has_bias, **_blocks(q, k))
+    chunked = (max(t_q, t_k) > BWD_CHUNK_THRESHOLD
+               or _resident_bwd_bytes(max(t_q, t_k), d, v.shape[-1], q.dtype.itemsize) > RESIDENT_BWD_VMEM)
+    # D = rowsum(dO * O): cheap elementwise-reduce, fused by XLA. The dq pass
+    # takes it and lse a row a sublane, lane-broadcast over a narrow trailing
+    # axis as the forward stores lse (Mosaic block layout requirement); the
+    # dk/dv pass along lanes.
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(b * h, t_q)
+    dq = _flash_bwd_dq(
+        q, k, v, g, lse, jnp.broadcast_to(delta[:, :, None], lse.shape), bias,
+        chunk=_pick_chunk(t_k, kw["block_k"]) if chunked else None, **kw)
+    dk, dv = _flash_bwd_dkv(
+        q, k, v, g, lse_lanes, delta[:, None, :], bias,
+        chunk=_pick_chunk(t_q, kw["block_q"]) if chunked else None, **kw)
+    return dq, dk, dv
 
 
 # ------------------------------------------------------------- public VJP
@@ -699,20 +659,22 @@ def _flash_bwd(q, k, v, bias, out, lse, g, scale, causal, has_bias):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash(q, k, v, bias, scale, causal, has_bias):
-    out, _ = _flash_fwd(q, k, v, bias, scale, causal, has_bias,
-                        save_residuals=False)
-    return out
+    return _flash_fwd(q, k, v, bias, scale=scale, causal=causal,
+                      has_bias=has_bias, save_residuals=False,
+                      **_blocks(q, k))[0]
 
 
 def _flash_vjp_fwd(q, k, v, bias, scale, causal, has_bias):
-    out, lse = _flash_fwd(q, k, v, bias, scale, causal, has_bias)
-    return out, (q, k, v, bias, out, lse)
+    out, lse, lse_lanes = _flash_fwd(q, k, v, bias, scale=scale, causal=causal,
+                                     has_bias=has_bias, save_residuals=True,
+                                     **_blocks(q, k))
+    return out, (q, k, v, bias, out, lse, lse_lanes)
 
 
 def _flash_vjp_bwd(scale, causal, has_bias, res, g):
-    q, k, v, bias, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, bias, out, lse, g, scale, causal,
-                            has_bias)
+    q, k, v, bias, out, lse, lse_lanes = res
+    dq, dk, dv = _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale,
+                            causal, has_bias)
     return dq, dk, dv, jnp.zeros_like(bias)
 
 

@@ -292,6 +292,111 @@ def test_flash_attention_padding_mask_and_causal():
                                        rtol=2e-3, atol=2e-4)
 
 
+_SPLIT = {  # id -> (dtype, T, BLOCK_Q, BLOCK_K, BWD_CHUNK or None for the resident pair, q.k head, v head, causal, padded)
+    # one block straddles the diagonal; resident, then chunks of three blocks:
+    # the diagonal at a chunk's first block, inside it and at its last
+    "resident-f32": ("float32", 512, 128, 128, None, 64, 64, True, False),
+    "resident-bf16": ("bfloat16", 512, 128, 128, None, 64, 64, True, False),
+    "resident-f32-padded": ("float32", 512, 128, 128, None, 64, 64, True, True),
+    "chunked-f32": ("float32", 768, 128, 128, 384, 64, 64, True, False),
+    "chunked-bf16": ("bfloat16", 768, 128, 128, 384, 64, 64, True, False),
+    "chunked-f32-padded": ("float32", 768, 128, 128, 384, 64, 64, True, True),
+    # two blocks straddle it (two key blocks a query block, two query blocks a key block)
+    "resident-q256-k128": ("float32", 512, 256, 128, None, 64, 64, True, False),
+    "resident-q128-k256": ("float32", 512, 128, 256, None, 64, 64, True, True),
+    "chunked-q256-k128": ("float32", 1024, 256, 128, 512, 64, 64, True, True),
+    "chunked-q128-k256": ("float32", 1024, 128, 256, 512, 64, 64, True, False),
+    # the cells' heads: 192^-0.5 stays on the scores, 256^-0.5 is folded into q and k
+    "resident-192-128-bf16": ("bfloat16", 512, 128, 128, None, 192, 128, True, False),
+    "chunked-192-128-f32": ("float32", 768, 128, 128, 384, 192, 128, True, False),
+    "resident-256-256-f32-padded": ("float32", 512, 128, 128, None, 256, 256, True, True),
+    "chunked-256-256-bf16": ("bfloat16", 768, 128, 128, 384, 256, 256, True, False),
+    # no diagonal: one loop, no mask, as before
+    "resident-full-f32-padded": ("float32", 512, 128, 128, None, 64, 64, False, True),
+    "chunked-full-f32": ("float32", 768, 128, 128, 384, 64, 64, False, False),
+    "chunked-full-256-256-bf16-padded": ("bfloat16", 768, 128, 128, 384, 256, 256, False, True),
+}
+
+
+def _patched_flash(monkeypatch, block_q, block_k, chunk):
+    from deeplearning4j_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setattr(fa, "BLOCK_Q", block_q)
+    monkeypatch.setattr(fa, "BLOCK_K", block_k)
+    if chunk is not None:
+        monkeypatch.setattr(fa, "BWD_CHUNK_THRESHOLD", 128)
+        monkeypatch.setattr(fa, "BWD_CHUNK", chunk)
+    return fa
+
+
+@pytest.mark.parametrize("case", list(_SPLIT))
+def test_flash_attention_split_loops_match_the_xla_form(monkeypatch, case):
+    """Under ``causal`` each kernel masks only the blocks the diagonal
+    crosses and runs the blocks under it through a body without a mask; the
+    output and all three gradients against the XLA softmax form in float32,
+    wherever the diagonal falls in a chunk and however many blocks it
+    crosses, at both cells' head sizes, with and without a key-padding mask."""
+    import jax
+    import jax.numpy as jnp
+    dtype, t, block_q, block_k, chunk, d_qk, d_v, causal, padded = _SPLIT[case]
+    fa = _patched_flash(monkeypatch, block_q, block_k, chunk)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    q, k = (jax.random.normal(key, (1, 2, t, d_qk)).astype(dtype) for key in ks[:2])
+    v, w = (jax.random.normal(key, (1, 2, t, d_v)).astype(dtype) for key in ks[2:])
+    mask = (jnp.arange(t)[None, :] < t - 150) if padded else None  # ends inside a block
+
+    def xla(q, k, v):
+        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+        s = jnp.einsum("bhqd,bhkd->bhqk", f32(q), f32(k), precision="highest") * d_qk ** -0.5
+        if padded:
+            s = jnp.where(mask[:, None, None, :], s, -1e30)
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), f32(v), precision="highest")
+
+    def both(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w), argnums=(0, 1, 2))(q, k, v)
+
+    flash = lambda q, k, v: fa.flash_attention(q, k, v, mask=mask, causal=causal)  # noqa: E731
+    text = jax.jit(jax.grad(lambda *a: jnp.sum(flash(*a)), argnums=(0, 1, 2))).lower(q, k, v).as_text(debug_info=True)
+    assert ("flash_attention_bwd_dkv_chunked" in text) == (chunk is not None)
+    np.testing.assert_allclose(flash(q, k, v).astype(jnp.float32), xla(q, k, v), rtol=0,
+                               atol=2e-5 if dtype == "float32" else 2e-2)
+    for got, want in zip(both(flash), both(xla)):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        np.testing.assert_allclose(got, want, rtol=0, atol=(1e-5 if dtype == "float32" else 2e-2) * np.abs(want).max())
+
+
+@pytest.mark.parametrize("chunk", [None, 384], ids=["resident", "chunked"])
+@pytest.mark.parametrize("last", [127, 300, 383])
+def test_flash_attention_is_causal_bit_for_bit(monkeypatch, chunk, last):
+    """Keys and values after position ``last`` reach no row up to it: the
+    output and dq there are the same bits whatever stands behind; and with a
+    cotangent on those rows alone, dk and dv behind ``last`` are exact zeros
+    (an unmasked block on the wrong side of the diagonal would show in
+    either)."""
+    import jax
+    import jax.numpy as jnp
+    fa = _patched_flash(monkeypatch, 128, 128, chunk)
+    t = 768
+    ks = jax.random.split(jax.random.PRNGKey(last), 6)
+    q, k, v, w, k2, v2 = (jax.random.normal(key, (1, 2, t, 64)).astype(jnp.bfloat16) for key in ks)
+    early = (jnp.arange(t) <= last)[None, None, :, None]
+    w = jnp.where(early, w, 0)
+
+    def run(k, v):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(q, k, v, causal=True), q, k, v)
+        return (out, *vjp(w))
+
+    out, dq, dk, dv = run(k, v)
+    out2, dq2, _, _ = run(jnp.where(early, k, k2), jnp.where(early, v, v2))
+    np.testing.assert_array_equal(out[:, :, :last + 1], out2[:, :, :last + 1])
+    np.testing.assert_array_equal(dq[:, :, :last + 1], dq2[:, :, :last + 1])
+    assert float(jnp.abs(out[:, :, last + 1:] - out2[:, :, last + 1:]).max()) > 0.1
+    for grad in (dk, dv):
+        assert not np.asarray(grad[:, :, last + 1:], np.float32).any()
+        assert np.asarray(grad[:, :, :last + 1], np.float32).any()
+
+
 def test_dot_product_attention_fallback_mask_forms_and_decode_causal():
     """XLA fallback must accept the same mask family as the kernel and use
     bottom-right-aligned causal masking for KV-cache decode shapes."""
@@ -801,10 +906,11 @@ def test_flash_attention_compiles_for_v5e_with_two_head_sizes_at_t8192(v5e_chip,
     """Latent attention's call at the Kimi Linear cell's shape (32 heads x
     8192 tokens, a q.k head of 192 against a v head of 128) and at the
     GLM-4.7-Flash cell's (20 heads, 192 + 64 against 256: the largest head
-    the route admits), causal, bf16. The resident backward kernels want
+    the route admits), causal, bf16. The resident backward kernels wanted
     32.8 MB of scoped VMEM at the first (Mosaic refuses above 32) and more
-    at the second: the route takes the chunked pair, and all three kernels
-    compile."""
+    at the second, with the row statistics a row a sublane in both passes;
+    the route's rule stands (PR 36 left it: at T = 8192 the forms cost the
+    same), it takes the chunked pair, and all three kernels compile."""
     import jax
     import jax.numpy as jnp
 
@@ -820,6 +926,47 @@ def test_flash_attention_compiles_for_v5e_with_two_head_sizes_at_t8192(v5e_chip,
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v).compile().as_text()
     assert text.count("tpu_custom_call") == 3
     assert "flash_attention_bwd_dq_chunked" in text and "flash_attention_bwd_dkv_chunked" in text
+
+
+def test_flash_attention_adds_no_layout_copy_to_a_glm_step(v5e_chip, monkeypatch):
+    """The dk/dv pass computes its score tile transposed and takes the row
+    statistics along lanes (PR 36); what it saves in VMEM must not come back
+    as re-layouts in HBM (the forward stores ``lse`` both ways itself: sliced
+    out of the padded (b*h, T, 8) array in XLA it was one copy a block). The
+    step of ``chip_smoke``'s small GLM-4.7-Flash (2 x 1024, heads of 192 + 64
+    against 256, bf16 compute, every scope recomputed), compiled for the
+    described chip: 54 Mosaic calls, twelve of them the three flash kernels
+    in its four blocks, and no more top-level copies than PR 35's step held."""
+    import jax
+    import jax.numpy as jnp
+
+    import chip_smoke
+    from deeplearning4j_tpu.runtime.environment import get_environment
+    from deeplearning4j_tpu.zoo import GlmMoeLite
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET")
+    env = get_environment()
+    dtype, remat = env.compute_dtype, env.remat_segments
+    try:
+        env.set_compute_dtype("bfloat16")
+        env.set_remat(True)
+        net = GlmMoeLite.tiny(**chip_smoke.Preset().glm).init()
+        step, packer = net._jitted_packed()
+        spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip)  # noqa: E731
+        ids = jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=v5e_chip)
+        args = (jax.tree.map(spec, packer.pack_device(net.train_state)), ids, ids, spec(jax.random.PRNGKey(0)),
+                None, None)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the route's platform probe sees the described chip
+        text = step.lower(*args).compile().as_text()
+    finally:
+        env.set_compute_dtype(dtype)
+        env.set_remat(remat)
+    entry = _entry_instructions(text)
+    ops = [op for _, op, _ in entry.values()]
+    assert ops.count("tpu_custom_call") == 54
+    assert ops.count("copy") <= 285, ops.count("copy")
+    flash = [m.group(1) for line in text[text.index("\nENTRY "):].splitlines() if "tpu_custom_call" in line
+             for m in [re.search(r"/(flash_attention_\w+)/pallas_call", line)] if m]
+    assert sorted(flash) == sorted(4 * ["flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"])
 
 
 def test_fused_attention_costs_no_layout_copy_in_a_bert_step(v5e_chip, monkeypatch):
