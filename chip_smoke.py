@@ -34,7 +34,13 @@ vocab 30522; batch 64 x seq 128, bf16 compute, library defaults otherwise):
    buffer; then *glm_moe_lite*: ``GlmMoeLite.tiny(...)`` at the published
    head sizes (rotary latent attention 192 + 64 against 256, a low-rank
    query) with its prediction layer on the tied table and head, where the
-   flash kernel routes at 256 / 256 in every block; both loss terms finite.
+   flash kernel routes at 256 / 256 in every block; both loss terms finite;
+   then *sdar_moe* (from ``main``, after the phases ``run`` drives):
+   ``SdarMoe`` at the benchmark cell's sizes (8 layers of published widths,
+   8 of 128 softmax-routed experts, a row of 4096 clean tokens = 8192
+   positions) trained by diffusion over blocks, where grouped-query
+   attention routes the ``bd_flash_attention_*`` kernels; no assignment
+   beyond the buffer, 2560 masked positions.
 4. *server*: ``ModelSerializer.write_model`` -> ``ModelRegistry.load`` with
    one replica per device -> ``ModelServer`` -> ``POST
    /v1/models/bert/predict`` with mixed row counts, ``/healthz``,
@@ -120,6 +126,12 @@ class Preset:
     glm: Dict[str, Any] = dataclasses.field(default_factory=lambda: dict(
         d_model=256, q_rank=96, kv_rank=128, qk_nope_dim=192,
         qk_shared_dim=64, v_dim=256, vocab_size=512))
+    # SdarMoe at the benchmark cell's sizes: published widths, its share of
+    # depth, experts and vocabulary; one row of ``sdar_seq`` clean tokens
+    sdar: Dict[str, Any] = dataclasses.field(default_factory=lambda: dict(
+        vocab_size=18992, layers_here=tuple(range(8)), held_experts=(0, 8),
+        held_rows=12288))
+    sdar_seq: int = 4096
 
 
 # ------------------------------------------------------------------ helpers
@@ -623,6 +635,76 @@ def check_glm_moe_lite(p: Preset) -> Dict[str, Any]:
             "mosaic_calls": n_calls}
 
 
+@_recomputing
+def check_sdar_moe(p: Preset) -> Dict[str, Any]:
+    """A few steps of ``SdarMoe`` through ``fit`` on one row ``[xt ; x0]``
+    whose blocks mask 1..B positions, equally many blocks each: attention
+    through the block-diffusion flash kernels with 8 query heads a key/value
+    head, held to: finite falling loss, nothing compiled after warm-up, no
+    AOT fallback, the kernels in the compiled step and no ``scores`` op, no
+    assignment left out, the head's count of masked positions.
+
+    The weights take the scales of the benchmark's cell (PERF.md section 6,
+    PR 38): this share holds 8 of 128 experts, and under the zoo's own
+    N(0, 0.02) an attention-only residual stream collapses onto one direction,
+    every position routes alike and the buffer overflows (15,621 assignments
+    in one layer, 49 in another, on the chip). So the vocabulary's embedding
+    rows are scaled to a deviation of 4, the MASK row stays, the per-head
+    norms' gains are 1.5, and the rate is a fine-tuning rate."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.train.listeners import CollectScoresListener
+    from deeplearning4j_tpu.train.updaters import Adam
+    from deeplearning4j_tpu.zoo import SdarMoe
+
+    zoo = SdarMoe(updater=Adam(1e-5, beta2=0.95), **p.sdar)
+    net = zoo.init()
+    scores = CollectScoresListener()
+    net.set_listeners(scores)
+    t, block, mask_id = p.sdar_seq, zoo.block_length, zoo.vocab_size - 1
+    params = dict(net.train_state.params)
+    table = params["layer_0"]["W"]
+    rows = jnp.full((zoo.vocab_size, 1), 4.0 / jnp.std(table)).at[mask_id].set(1.0)
+    params["layer_0"] = {"W": rows * table}
+    for key, block_params in params.items():
+        if "mixer" in block_params:
+            mixer = dict(block_params["mixer"], q_norm=1.5 * block_params["mixer"]["q_norm"],
+                         k_norm=1.5 * block_params["mixer"]["k_norm"])
+            params[key] = dict(block_params, mixer=mixer)
+    net.set_params(params)
+    rng = np.random.default_rng(0)
+    clean = rng.integers(0, mask_id, (1, t), dtype=np.int32)
+    per_block = rng.permutation(np.repeat(np.arange(1, block + 1), t // block // block))
+    masked = (rng.random((t // block, block)).argsort(-1).argsort(-1) < per_block[:, None]).reshape(1, t)
+    batch = DataSet(np.concatenate([np.where(masked, mask_id, clean), clean], 1).astype(np.int32),
+                    np.where(masked, clean, -1).astype(np.int32))
+    fallbacks = _cache_stats()["aot_fallbacks"]
+    losses = _fit_counted(net.fit, [batch], [batch], 1, 6, scores, "SdarMoe fit")
+    assert _cache_stats()["aot_fallbacks"] == fallbacks, "SdarMoe: an AOT executable refused its arguments"
+    assert _on_platform(net.train_state, p.platform), "SdarMoe train state is not on the device"
+    state = net.train_state.model_state
+    overflow = [float(s["mlp"]["overflow"]) for s in state.values() if "mlp" in s]
+    assigned = [float(jnp.sum(s["mlp"]["assigned"])) for s in state.values() if "mlp" in s]
+    assert len(overflow) == len(zoo.layers_here) and not any(overflow), \
+        f"SdarMoe: assignments beyond the experts' buffer {overflow} (assigned {assigned})"
+    head, = (s for s in state.values() if "masked_positions" in s)
+    want = t // block * (block + 1) // 2
+    assert float(head["masked_positions"]) == want, f"SdarMoe: {head['masked_positions']} masked positions, not {want}"
+    assert abs(float(head["diffusion_loss"]) - losses[-1]) <= 0.02 * losses[-1]
+    step, packer = net._jitted_packed()
+    compiled = step.lower(packer.pack_device(net.train_state), jnp.asarray(batch.features),
+                          jnp.asarray(batch.labels), jax.random.PRNGKey(0), None, None).compile()
+    text = compiled.as_text()
+    # forward and two backward passes in every block
+    n_calls = _mosaic_calls(compiled, p, 3 * len(zoo.layers_here), "SdarMoe train step")
+    if p.expect_mosaic:
+        for kernel in ("bd_flash_attention_fwd", "bd_flash_attention_bwd_dq", "bd_flash_attention_bwd_dkv"):
+            assert kernel in text, f"SdarMoe train step: {kernel} was routed around"
+        assert "/scores/" not in text and "/softmax/" not in text, "SdarMoe train step: the XLA attention ran"
+    _log(f"  SdarMoe train step: mosaic_calls={n_calls} assigned a layer {assigned}")
+    return {"steps": len(losses), "first_loss": losses[0], "last_loss": losses[-1], "mosaic_calls": n_calls,
+            "assigned": assigned, "masked_positions": float(head["masked_positions"])}
+
+
 # ----------------------------------------------------------- phase 4: server
 def _pad_rows(x: np.ndarray, bucket: int) -> np.ndarray:
     return np.concatenate(
@@ -821,6 +903,10 @@ def main() -> int:
     os.makedirs(OUT_DIR, exist_ok=True)
     with tempfile.TemporaryDirectory(dir=OUT_DIR) as workdir:
         run(Preset(), report, workdir)
+    import gc
+    gc.collect()
+    with _phase(report, "sdar_moe"):
+        report["sdar_moe"] = check_sdar_moe(Preset())
     report["smoke_wall_s"] = round(time.perf_counter() - t0, 2)
     with open(os.path.join(OUT_DIR, "chip_smoke.json"), "w") as f:
         json.dump(report, f, indent=2, default=str)

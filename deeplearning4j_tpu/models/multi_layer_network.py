@@ -187,6 +187,8 @@ class MultiLayerNetwork(TrainEngine):
                                       state=model_state.get(k, {}))
             if "main_loss" in model_state.get(k, {}):  # an output layer with ``record_loss``
                 new_state = {**new_state, k: {**model_state[k], "main_loss": loss.astype(jnp.float32)}}
+            if hasattr(final, "loss_state"):  # a head that keeps more of its last step than the loss
+                new_state = {**new_state, k: final.loss_state(model_state.get(k, {}), loss, y)}
             loss = loss + self._reg_score(params)
         # differentiable auxiliary losses surfaced by layers through the
         # state channel (e.g. MoE load balancing) — same trace, so grads

@@ -37,22 +37,31 @@ def layer_norm(x, gamma, beta, eps=1e-12):
 
 
 def dot_product_attention(q, k, v, mask=None, use_flash: bool = True,
-                          causal: bool = False):
+                          causal: bool = False, block_diffusion=None):
     """(batch, heads, time, d) attention. Routes through the Pallas flash
     kernel when ``flash_attention_compatible`` accepts the shapes, mask
-    family and platform (incl. key-padding masks and causal); an
-    incompatible call takes the XLA softmax form below. A kernel that
-    raises is a bug and surfaces — nothing here catches it."""
-    if use_flash:
-        from deeplearning4j_tpu.ops.pallas.flash_attention import (
-            flash_attention, flash_attention_compatible)
-        if flash_attention_compatible(q, k, v, mask, causal=causal):
-            # one kernel does scores, softmax and context: one scope
-            with jax.named_scope("flash"):
-                return flash_attention(q, k, v, mask, causal=causal)
+    family and platform (key-padding masks, causal, and
+    ``block_diffusion=(t, block)``: the block-diffusion mask over ``[noisy ;
+    clean]`` of ``2 t`` positions); an incompatible call takes the XLA
+    softmax form below. ``k`` and ``v`` may have fewer heads than ``q``
+    (query head ``i`` reads key/value head ``i // group``): the kernels
+    index them so under ``block_diffusion``, the XLA form repeats them. A
+    kernel that raises is a bug and surfaces — nothing here catches it."""
+    from deeplearning4j_tpu.ops.pallas.flash_attention import (
+        block_diffusion_allowed, flash_attention, flash_attention_compatible)
+    if use_flash and flash_attention_compatible(q, k, v, mask, causal=causal, block_diffusion=block_diffusion):
+        # one kernel does scores, softmax and context: one scope
+        with jax.named_scope("flash"):
+            return flash_attention(q, k, v, mask, causal=causal, block_diffusion=block_diffusion)
     d = q.shape[-1]
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(a, q.shape[1] // k.shape[1], axis=1) for a in (k, v))
     with jax.named_scope("scores"):
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(jnp.asarray(d, q.dtype))
+        if block_diffusion is not None:
+            at = jnp.arange(q.shape[2])
+            allowed = block_diffusion_allowed(at[:, None], at[None, :], *block_diffusion)
+            scores = jnp.where(allowed[None, None], scores, jnp.asarray(-1e9, scores.dtype))
         if mask is not None:
             if mask.ndim == 2:  # (batch, t_k) key-padding form
                 mask = mask[:, None, None, :]
@@ -550,6 +559,74 @@ class LatentAttention(Layer):
 
     def regularizable_params(self):
         return ("W_q", "W_qa", "W_qb", "W_kva", "W_kvb", "W_o")
+
+
+@register_layer
+@dataclasses.dataclass
+class GroupedQueryAttention(Layer):
+    """Multi-head attention in which ``n_heads`` query heads share
+    ``n_kv_heads`` key/value heads (query head ``i`` reads head ``i //
+    (n_heads / n_kv_heads)``), with a learned RMSNorm over each query's and
+    each key's head (Qwen3's form) and rotary positions, no biases::
+
+        q = rope(RMSNorm_head(x W_q), pos);  k = rope(RMSNorm_head(x W_k), pos);  v = x W_v
+        out = softmax(q k^T / sqrt(head_dim) + M) v  W_o
+
+    Causal unless ``block_diffusion`` is set to a block length: the input
+    is then ``[noisy ; clean]``, two halves of ``t / 2`` positions that carry
+    the same positions ``0 .. t/2 - 1``, under the block-diffusion mask
+    (``ops.pallas.flash_attention.block_diffusion_allowed``). The T x T part
+    goes through ``dot_product_attention`` and the routing it owns; K and V
+    are handed over at their own number of heads."""
+
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1e6
+    eps: float = 1e-6
+    block_diffusion: Optional[int] = None
+
+    def init(self, key, input_type, g: GlobalConfig):
+        d, h, kv, hd = input_type.size, self.n_heads, self.n_kv_heads, self.head_dim
+        shapes = {"W_q": (d, h * hd), "W_k": (d, kv * hd), "W_v": (d, kv * hd), "W_o": (h * hd, d)}
+        params = {name: init_weights(k, shape, self._winit(g), fan=shape, dtype=g.dtype)
+                  for (name, shape), k in zip(shapes.items(), jax.random.split(key, len(shapes)))}
+        params["q_norm"] = jnp.ones((hd,), g.dtype or jnp.float32)
+        params["k_norm"] = jnp.ones((hd,), g.dtype or jnp.float32)
+        return params, {}
+
+    def forward(self, params, state, x, *, training=False, rng=None, mask=None):
+        b, t, _ = x.shape
+        hd = self.head_dim
+        if self.block_diffusion is None:
+            positions, family = jnp.arange(t), dict(causal=True)
+        else:
+            half = jnp.arange(t // 2)
+            positions, family = jnp.concatenate([half, half]), dict(block_diffusion=(t // 2, self.block_diffusion))
+
+        def qkv(p, x_):
+            return tuple((x_ @ p[w]).reshape(b, t, -1, hd) for w in ("W_q", "W_k", "W_v"))
+
+        def qk_norm(p, q, k):
+            return rms_norm(q, p["q_norm"], self.eps), rms_norm(k, p["k_norm"], self.eps)
+
+        def rope(q, k, v):  # and the join into (b, h, t, d)
+            q, k = rotary(q, positions, self.rope_theta), rotary(k, positions, self.rope_theta)
+            return tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v))
+
+        q, k, v = scoped("qkv", qkv, params, x)
+        q, k = scoped("qk_norm", qk_norm, params, q, k)
+        q, k, v = scoped("rope", rope, q, k, v)
+        key_mask = None if mask is None else mask[:, None, None, :].astype(bool)
+        y = dot_product_attention(q, k, v, key_mask, **family)
+
+        def out(p, y_):
+            return y_.transpose(0, 2, 1, 3).reshape(b, t, -1) @ p["W_o"]
+
+        return scoped("out_proj", out, params, y), state
+
+    def regularizable_params(self):
+        return ("W_q", "W_k", "W_v", "W_o")
 
 
 @register_layer

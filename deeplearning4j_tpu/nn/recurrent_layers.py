@@ -418,3 +418,49 @@ class RnnOutputLayer(OutputLayer):
         # (``loss/lm_head`` in the device trace)
         with jax.named_scope("lm_head"):
             return super().preoutput(params, x)
+
+
+@register_layer
+@dataclasses.dataclass
+class BlockDiffusionLoss(RnnOutputLayer):
+    """Output head of a language model trained by diffusion over blocks
+    (BD3-LM's training form, arXiv:2503.09573 section 3; SDAR,
+    arXiv:2510.06303). The network ran once on ``[noisy ; clean]``: its input
+    here is (batch, 2 T, nIn), of which the first ``T`` hidden states, the
+    noisy half's, are scored, unshifted: position ``i`` predicts token ``i``.
+    Labels are (batch, T) integers: the clean token where the noisy half
+    holds the MASK id, and a negative number elsewhere (not trained). A
+    trained position of block ``b`` (``block_length`` positions) weighs ``1 /
+    t_b = block_length / m_b``, ``m_b`` the block's number of trained
+    positions, worked out from the labels; the weighted negative
+    log-likelihoods are summed and divided by ``T``, and averaged over rows.
+
+    The model state keeps the last step's ``diffusion_loss`` and
+    ``masked_positions`` (trained positions a row). At inference the layer
+    gives the noisy half's distributions."""
+
+    loss: Any = LossFunction.SPARSE_MCXENT
+    block_length: int = 4
+
+    def init(self, key, input_type, g: GlobalConfig):
+        params, state = super().init(key, input_type, g)
+        zero = jnp.zeros((), jnp.float32)
+        return params, {**state, "diffusion_loss": zero, "masked_positions": zero}
+
+    def activate(self, params, x):
+        return super().activate(params, x[:, :x.shape[1] // 2])
+
+    def compute_loss(self, params, x, labels, mask=None, state=None):
+        b, t = labels.shape
+        labels = labels.astype(jnp.int32)
+        trained = labels >= 0 if mask is None else (labels >= 0) & mask.astype(bool)
+        per_block = jnp.sum(trained.reshape(b, t // self.block_length, self.block_length), -1)
+        weight = jnp.repeat(self.block_length / jnp.maximum(per_block, 1).astype(jnp.float32), self.block_length, axis=1)
+        logp = jax.nn.log_softmax(self.preoutput(params, x[:, :t]).astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
+        return jnp.sum(jnp.where(trained, weight * nll, 0.0)) / (b * t)
+
+    def loss_state(self, state, loss, labels):
+        """The head's state after a step whose data loss was ``loss``."""
+        return {**state, "diffusion_loss": loss.astype(jnp.float32),
+                "masked_positions": jnp.sum(labels >= 0).astype(jnp.float32) / labels.shape[0]}

@@ -70,6 +70,27 @@ forward 1.96 -> 1.63, dq pass 2.60 -> 2.43, dk/dv pass 3.51 -> 3.04, against
 All three kernels are entered through one ``jax.jit`` each, so that the
 call sites of a model, which call at one shape, share one traced kernel.
 
+``block_diffusion=(t, block)`` is a third mask family, for training a model
+that denoises blocks of tokens (BD3-LM, arXiv:2503.09573): the sequence is
+``[noisy ; clean]``, ``2 t`` positions, cut into blocks of ``block`` within
+each half. A noisy query sees the noisy keys of its own block and the clean
+keys of earlier blocks; a clean query sees the clean keys of its own and
+earlier blocks and no noisy key (:func:`block_diffusion_allowed`):
+``t^2 + t block`` allowed entries of ``(2 t)^2``. The kernels work the mask
+out per tile from two iotas and the tile's place (no ``2t x 2t`` array
+anywhere) and visit only the tiles that hold an allowed entry: 80 of 256
+at ``t`` = 4096, ``block`` = 4, tiles of 512, 24 of them masked, the others
+whole. Under this family the query heads may outnumber the key/value heads
+(grouped-query attention): a key/value head's group of query heads is laid
+head after head along the kernels' row axis, ``(b * h_kv, group * 2t, d)``,
+a reshape of what the caller holds, so that K and V are fetched once a
+group in the forward and the dq pass, and the dk/dv pass adds up over the
+group in its scratch (its third grid axis runs over the group's heads as
+the chunked form's runs over chunks). K and V are never repeated in HBM.
+These calls are named ``bd_flash_attention_fwd`` / ``_bwd_dq`` /
+``_bwd_dkv``; what a masked tile costs over a whole one is measured, not
+yet cut (PERF.md).
+
 Used automatically by ``nn.attention_layers.dot_product_attention`` when
 :func:`flash_attention_compatible` says the shapes and the platform allow;
 otherwise the XLA softmax form runs. ``DL4J_TPU_PALLAS_INTERPRET=1`` runs
@@ -131,14 +152,26 @@ def _padding_mask_2d(mask, b: int, t_k: int):
     return None
 
 
-def flash_attention_compatible(q, k, v, mask=None, causal: bool = False) -> bool:
+def flash_attention_compatible(q, k, v, mask=None, causal: bool = False, block_diffusion=None) -> bool:
     """Kernel applicability: key-padding masks only (other mask shapes fall
     back to XLA), block-divisible sequence, head dim that tiles onto the MXU
-    lanes, and a key length long enough that the kernel beats XLA."""
+    lanes, and a key length long enough that the kernel beats XLA. Under
+    ``block_diffusion=(t, block)``: no other mask, ``2 t`` positions on both
+    sides, whole tiles in each half (``t`` a multiple of 128) and whole
+    blocks, K and V of a head resident in VMEM; only there may the query
+    heads be a multiple of the key/value heads."""
     if q.ndim != 4:
         return False
     t_q, d = q.shape[2], q.shape[3]
     t_k = k.shape[2]
+    if block_diffusion is not None:
+        t, block = block_diffusion
+        if (mask is not None or causal or t_q != 2 * t or t_k != 2 * t or t % 128 or t % block
+                or q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]
+                or 2 * t_k * (d + v.shape[3]) * q.dtype.itemsize > RESIDENT_BWD_VMEM):
+            return False
+    elif k.shape[1] != q.shape[1]:
+        return False
     if mask is not None and _padding_mask_2d(mask, q.shape[0], t_k) is None:
         return False
     if causal and t_q != t_k:
@@ -170,6 +203,77 @@ def _diag_mask(s, q0, k0, q_axis: int = 0):
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     return jnp.where(k_pos <= q_pos, s, MASK_VALUE)
+
+
+def block_diffusion_allowed(q_pos, k_pos, t: int, block: int):
+    """Whether the query at ``q_pos`` may see the key at ``k_pos`` under the
+    block-diffusion mask: positions in ``[0, 2t)``, the noisy half first,
+    blocks of ``block`` within each half. The mask's definition, on arrays
+    that broadcast against each other (the XLA path builds ``2t x 2t`` from
+    it; the kernels never do)."""
+    q_clean, k_clean = q_pos >= t, k_pos >= t
+    q_blk = (q_pos - jnp.where(q_clean, t, 0)) // block
+    k_blk = (k_pos - jnp.where(k_clean, t, 0)) // block
+    return jnp.where(k_clean, jnp.where(q_clean, k_blk <= q_blk, k_blk < q_blk), ~q_clean & (k_blk == q_blk))
+
+
+def _bd_mask(s, q0, k0, q_axis: int = 0, *, t: int, block: int):
+    """:func:`block_diffusion_allowed` inside a score tile whose first query
+    is ``q0`` and whose first key is ``k0`` (scalars; a tile lies in one half
+    on either axis, so which halves is a scalar matter): how many blocks the
+    query lies ahead of the key, held between two scalars."""
+    q_clean, k_clean = (q0 >= t).astype(jnp.int32), (k0 >= t).astype(jnp.int32)
+
+    def block_of(first, axis):
+        pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
+        # positions are not negative: a shift is the floor division
+        return pos >> (block.bit_length() - 1) if block & (block - 1) == 0 else jax.lax.div(pos, block)
+
+    ahead = block_of(q0 - q_clean * t, q_axis) - block_of(k0 - k_clean * t, 1 - q_axis)
+    lo = k_clean - q_clean                  # a noisy query sees clean keys of earlier blocks only
+    hi = (k_clean - q_clean * (1 - k_clean)) * t  # noisy keys: the same block (a clean query: none)
+    return jnp.where((ahead >= lo) & (ahead <= hi), s, MASK_VALUE)
+
+
+def _tile_mask(bd):
+    """What a kernel's ``tile`` applies where a range is masked."""
+    return functools.partial(_bd_mask, t=bd[0], block=bd[1]) if bd else _diag_mask
+
+
+def _bd_key_tiles(qi, block_q: int, block_k: int, t: int, block: int):
+    """The key tiles that query tile ``qi`` (of ``2t / block_q``) visits under
+    block diffusion, as ``(lo, hi, masked)`` ranges of key-tile indices: the
+    noisy keys of its own blocks (a noisy tile only), the clean keys that
+    all of its queries see, the clean keys that some of them see."""
+    clean = qi >= t // block_q
+    r0 = (qi - jnp.where(clean, t // block_q, 0)) * block_q  # its first row within its half
+    b_lo, b_hi = r0 // block, (r0 + block_q - 1) // block    # its first and last block
+    own_lo = (b_lo * block) // block_k
+    own_hi = jnp.where(clean, own_lo, pl.cdiv((b_hi + 1) * block, block_k))
+    strict = jnp.where(clean, 0, 1)  # a noisy query does not see its own block's clean keys
+    first = t // block_k
+    whole = first + ((b_lo - strict + 1) * block) // block_k
+    some = first + pl.cdiv((b_hi - strict + 1) * block, block_k)
+    return (own_lo, own_hi, True), (first, whole, False), (whole, some, True)
+
+
+def _bd_query_tiles(ki, block_q: int, block_k: int, t: int, block: int):
+    """The mirror of :func:`_bd_key_tiles` for the dk/dv pass: the query
+    tiles (of one head, ``2t / block_q``) that key tile ``ki`` is seen by.
+    Noisy keys: the noisy queries of their own blocks. Clean keys: the noisy
+    queries of later blocks and the clean queries of their own and later
+    blocks, each as a masked range and a whole one."""
+    clean = ki >= t // block_k
+    c0 = (ki - jnp.where(clean, t // block_k, 0)) * block_k
+    b_lo, b_hi = c0 // block, (c0 + block_k - 1) // block
+    n_q = t // block_q
+    own_lo = (b_lo * block) // block_q
+    ranges = [(own_lo, jnp.where(clean, own_lo, pl.cdiv((b_hi + 1) * block, block_q)), True)]
+    for strict, first in ((1, 0), (0, n_q)):  # the noisy queries, then the clean ones
+        lo = first + ((b_lo + strict) * block) // block_q
+        mid = first + pl.cdiv((b_hi + strict) * block, block_q)
+        ranges += [(lo, jnp.where(clean, mid, lo), True), (mid, jnp.where(clean, first + n_q, mid), False)]
+    return ranges
 
 
 def _folds(scale: float) -> bool:
@@ -219,9 +323,11 @@ def _loop(tile, lo, hi, masked: bool):
     jax.lax.fori_loop(lo, hi, one, None)
 
 
-def _heads_flat(x):
-    """(b, h, t, d) as the kernels' (b * h, t, d)."""
-    return x.reshape(-1, *x.shape[2:])
+def _heads_flat(x, h_kv: int = None):
+    """(b, h, t, d) as the kernels' (b * h, t, d); with fewer key/value
+    heads, ``h_kv``, a group's query heads head after head along the rows:
+    (b * h_kv, h / h_kv * t, d)."""
+    return x.reshape(x.shape[0] * (h_kv or x.shape[1]), -1, x.shape[3])
 
 
 def _lanes(x, n: int):
@@ -248,7 +354,7 @@ def _stepwise(n_chunks, init, work, flush):
 
 
 def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
-                causal: bool, save_residuals: bool):
+                causal: bool, save_residuals: bool, bd=None):
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if has_bias else None
     o_ref = refs[3 + has_bias]
@@ -260,6 +366,9 @@ def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
     in_dtype = q.dtype
     qi = pl.program_id(1)
     block_q = q.shape[0]
+    mask = _tile_mask(bd)
+    if bd:  # the rows run over a group's query heads, head after head
+        qi = qi % (2 * bd[0] // block_q)
     d_v = v_ref.shape[2]
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -272,7 +381,7 @@ def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
         if bias_ref is not None:
             s = s + bias_ref[0, pl.ds(i * block_k, block_k), 0][None, :]
         if masked:
-            s = _diag_mask(s, qi * block_q, i * block_k)
+            s = mask(s, qi * block_q, i * block_k)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, block_k))
@@ -282,7 +391,10 @@ def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
         acc_ref[...] = acc_ref[...] * _lanes(corr, d_v) + jax.lax.dot(
             p.astype(in_dtype), v_blk, preferred_element_type=jnp.float32)
 
-    if causal:
+    if bd:
+        for lo, hi, masked in _bd_key_tiles(qi, block_q, block_k, *bd):
+            _loop(tile, lo, hi, masked)
+    elif causal:
         # the key blocks wholly under the diagonal, then those it crosses
         below = (qi * block_q) // block_k
         _loop(tile, 0, below, False)
@@ -300,42 +412,46 @@ def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
         lse_lanes_ref[0] = lse[:, 0][None, :]
 
 
-_STATIC = ("scale", "causal", "has_bias", "block_q", "block_k", "interpret")
+_STATIC = ("scale", "causal", "has_bias", "block_q", "block_k", "interpret", "bd")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("save_residuals",))
 def _flash_fwd(q, k, v, bias, *, scale, causal, has_bias, block_q, block_k,
-               interpret, save_residuals):
+               interpret, save_residuals, bd=None):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
     d_v = v.shape[-1]
-    grid = (b * h, t_q // block_q)
+    # the grid's rows are the key/value heads; a head's group of query heads
+    # lies head after head along the query axis (one query head a row but
+    # under ``bd``)
+    bh, rows_q = b * k.shape[1], h // k.shape[1] * t_q
+    grid = (bh, rows_q // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         pl.BlockSpec((1, t_k, d), lambda bh, qi: (bh, 0, 0)),
         pl.BlockSpec((1, t_k, d_v), lambda bh, qi: (bh, 0, 0)),
     ]
-    args = [_heads_flat(q), _heads_flat(k), _heads_flat(v)]
+    args = [_heads_flat(q, k.shape[1]), _heads_flat(k), _heads_flat(v)]
     if has_bias:
         # bias is (b, t_k, 1); the index map divides the grid's batch*heads
         # row by heads, so all heads of one batch share the same block.
         in_specs.append(
             pl.BlockSpec((1, t_k, 1), lambda bh, qi: (bh // h, 0, 0)))
         args.append(bias)
-    out_shape = [jax.ShapeDtypeStruct((b * h, t_q, d_v), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((bh, rows_q, d_v), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d_v), lambda bh, qi: (bh, qi, 0))]
     if save_residuals:
         out_shape.append(
-            jax.ShapeDtypeStruct((b * h, t_q, RES_LANES), jnp.float32))
+            jax.ShapeDtypeStruct((bh, rows_q, RES_LANES), jnp.float32))
         out_specs.append(
             pl.BlockSpec((1, block_q, RES_LANES), lambda bh, qi: (bh, qi, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b * h, 1, t_q), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, rows_q), jnp.float32))
         out_specs.append(pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)))
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                           has_bias=has_bias, causal=causal,
-                          save_residuals=save_residuals),
-        name="flash_attention_fwd",
+                          save_residuals=save_residuals, bd=bd),
+        name=_name("fwd", bd),
         out_shape=out_shape,
         grid=grid,
         in_specs=in_specs,
@@ -359,7 +475,7 @@ def _flash_fwd(q, k, v, bias, *, scale, causal, has_bias, block_q, block_k,
 
 
 def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
-                   causal: bool, n_chunks):
+                   causal: bool, n_chunks, bd=None):
     """dq pass. Resident (``n_chunks`` None): grid (bh, qi). Chunked: grid
     (bh, qi, ci), K/V blocks are the ci-th chunk. dq accumulates in scratch,
     unscaled, and takes ``scale`` where it is written out."""
@@ -374,6 +490,9 @@ def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
     qi = pl.program_id(1)
     block_q = q.shape[0]
     nb = k_ref.shape[1] // block_k
+    mask = _tile_mask(bd)
+    if bd:  # resident only; the rows run over a group's query heads
+        qi = qi % (2 * bd[0] // block_q)
 
     def init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -388,7 +507,7 @@ def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
             if bias_ref is not None:
                 s = s + bias_ref[0, pl.ds(i * block_k, block_k), 0][None, :]
             if masked:
-                s = _diag_mask(s, qi * block_q, (first + i) * block_k)
+                s = mask(s, qi * block_q, (first + i) * block_k)
             p = jnp.exp(s - lse[:, None])                       # (BQ, BK)
             dp = jax.lax.dot_general(do, v_blk, _NT,
                                      preferred_element_type=jnp.float32)
@@ -396,6 +515,10 @@ def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
             acc_ref[...] += jax.lax.dot(ds, k_blk,
                                         preferred_element_type=jnp.float32)
 
+        if bd:
+            for lo, hi, masked in _bd_key_tiles(qi, block_q, block_k, *bd):
+                _loop(tile, lo, hi, masked)
+            return
         if not causal:
             _loop(tile, 0, nb, False)
             return
@@ -413,12 +536,14 @@ def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
 
 
 def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
-                    causal: bool, n_chunks):
+                    causal: bool, n_chunks, bd=None):
     """dk/dv pass. Resident (``n_chunks`` None): grid (bh, ki). Chunked:
     grid (bh, ki, ci), Q/dO/lse/delta blocks are the ci-th chunk. The score
     tile is computed transposed, (BK, BQ): keys on sublanes, queries on
     lanes, so that both results are plain matmuls of it; ``lse`` and
-    ``delta`` come along lanes. dk accumulates unscaled."""
+    ``delta`` come along lanes. dk accumulates unscaled. Under ``bd`` a
+    chunk is one query head of this key/value head's group: the scratch adds
+    up over the group."""
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     # this key block's bias (shared across q blocks), one a row of the tile
     bias = refs[6][0] if has_bias else None   # (BK, 1)
@@ -429,13 +554,14 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
     ki = pl.program_id(1)
     block_k = k.shape[0]
     nb = q_ref.shape[1] // block_q
+    mask = _tile_mask(bd)
 
     def init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     def work(ci):
-        first = ci * nb  # global index of this chunk's first query block
+        first = 0 if bd else ci * nb  # index of this chunk's first query block within its head
 
         def tile(i, masked):
             rows = pl.ds(i * block_q, block_q)
@@ -445,7 +571,7 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
             if bias is not None:
                 s = s + bias
             if masked:
-                s = _diag_mask(s, (first + i) * block_q, ki * block_k, q_axis=1)
+                s = mask(s, (first + i) * block_q, ki * block_k, q_axis=1)
             p = jnp.exp(s - lse_ref[0, :, rows])
             dv_acc_ref[...] += jax.lax.dot(p.astype(in_dtype), do_blk,
                                            preferred_element_type=jnp.float32)
@@ -455,6 +581,10 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
             dk_acc_ref[...] += jax.lax.dot(ds, q_blk,
                                            preferred_element_type=jnp.float32)
 
+        if bd:
+            for lo, hi, masked in _bd_query_tiles(ki, block_q, block_k, *bd):
+                _loop(tile, lo, hi, masked)
+            return
         if not causal:
             _loop(tile, 0, nb, False)
             return
@@ -501,6 +631,14 @@ BWD_CHUNK = 4096
 RESIDENT_BWD_VMEM = 24 * 1024 * 1024
 
 
+def _name(kernel: str, bd, chunk=None) -> str:
+    """The ``pallas_call``'s name, which the device trace shows: the
+    block-diffusion family's carry ``bd_``, so that their work is never
+    counted with the causal kernels' (their third grid axis is the group,
+    not a chunk of the sequence: no ``_chunked``)."""
+    return ("bd_" if bd else "") + "flash_attention_" + kernel + ("_chunked" if chunk and not bd else "")
+
+
 def _resident_bwd_bytes(t: int, d: int, d_v: int, itemsize: int) -> int:
     return 2 * t * ((d + d_v) * itemsize + 2 * 128 * 4)
 
@@ -516,13 +654,14 @@ def _pick_chunk(t: int, block: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("chunk",))
 def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
-                  has_bias, block_q, block_k, interpret):
+                  has_bias, block_q, block_k, interpret, bd=None):
     """dq from (b, h, t, d) operands and (b*h, t_q, RES_LANES) row
     statistics; K/V whole a grid step (``chunk`` None) or ``chunk`` rows."""
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
     d_v = v.shape[-1]
     chunk_k = chunk or t_k
+    bh, rows_q = b * k.shape[1], h // k.shape[1] * t_q  # as in the forward
 
     def own(bh, qi, *ci):
         return (bh, qi, 0)
@@ -546,7 +685,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
         pl.BlockSpec((1, block_q, RES_LANES), own),
         pl.BlockSpec((1, block_q, RES_LANES), own),
     ]
-    args = [*map(_heads_flat, (q, k, v, do)), lse, delta]
+    args = [_heads_flat(q, k.shape[1]), _heads_flat(k), _heads_flat(v), _heads_flat(do, k.shape[1]), lse, delta]
     if has_bias:
         in_specs.append(pl.BlockSpec(
             (1, chunk_k, 1), lambda bh, *at: (bh // h,) + streamed(bh, *at)[1:]))
@@ -554,10 +693,10 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           has_bias=has_bias, causal=causal,
-                          n_chunks=t_k // chunk if chunk else None),
-        name="flash_attention_bwd_dq" + ("_chunked" if chunk else ""),
-        out_shape=jax.ShapeDtypeStruct((b * h, t_q, d), q.dtype),
-        grid=(b * h, t_q // block_q) + ((t_k // chunk,) if chunk else ()),
+                          n_chunks=t_k // chunk if chunk else None, bd=bd),
+        name=_name("bwd_dq", bd, chunk),
+        out_shape=jax.ShapeDtypeStruct((bh, rows_q, d), q.dtype),
+        grid=(bh, rows_q // block_q) + ((t_k // chunk,) if chunk else ()),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), own),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -569,13 +708,16 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("chunk",))
 def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
-                   has_bias, block_q, block_k, interpret):
+                   has_bias, block_q, block_k, interpret, bd=None):
     """dk, dv from (b, h, t, d) operands and (b*h, 1, t_q) row statistics;
-    Q/dO whole a grid step (``chunk`` None) or ``chunk`` rows."""
+    Q/dO whole a grid step (``chunk`` None) or ``chunk`` rows. Under ``bd``
+    the chunk is ``t_q``, one query head, and the last grid axis runs over
+    the heads of a key/value head's group."""
     b, h, t_q, d = q.shape
-    t_k = k.shape[2]
+    h_kv, t_k = k.shape[1], k.shape[2]
     d_v = v.shape[-1]
     chunk_q = chunk or t_q
+    n_chunks = h // h_kv * t_q // chunk if chunk else None
 
     def own(bh, ki, *ci):
         return (bh, ki, 0)
@@ -602,21 +744,20 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
         pl.BlockSpec((1, 1, chunk_q), lanes),
         pl.BlockSpec((1, 1, chunk_q), lanes),
     ]
-    args = [*map(_heads_flat, (q, k, v, do)), lse, delta]
+    args = [_heads_flat(q, h_kv), _heads_flat(k), _heads_flat(v), _heads_flat(do, h_kv), lse, delta]
     if has_bias:
         in_specs.append(pl.BlockSpec(
             (1, block_k, 1), lambda bh, ki, *ci: (bh // h, ki, 0)))
         args.append(bias)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          has_bias=has_bias, causal=causal,
-                          n_chunks=t_q // chunk if chunk else None),
-        name="flash_attention_bwd_dkv" + ("_chunked" if chunk else ""),
+                          has_bias=has_bias, causal=causal, n_chunks=n_chunks, bd=bd),
+        name=_name("bwd_dkv", bd, chunk),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t_k, d_v), v.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, t_k, d), k.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, t_k, d_v), v.dtype),
         ],
-        grid=(b * h, t_k // block_k) + ((t_q // chunk,) if chunk else ()),
+        grid=(b * h_kv, t_k // block_k) + ((n_chunks,) if chunk else ()),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, block_k, d), own),
                    pl.BlockSpec((1, block_k, d_v), own)],
@@ -625,18 +766,19 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
-    return dk.reshape(b, h, t_k, d), dv.reshape(b, h, t_k, d_v)
+    return dk.reshape(b, h_kv, t_k, d), dv.reshape(b, h_kv, t_k, d_v)
 
 
-def _blocks(q, k) -> dict:
-    return dict(block_q=_pick_block(q.shape[2], BLOCK_Q),
-                block_k=_pick_block(k.shape[2], BLOCK_K), interpret=_interpret())
+def _blocks(q, k, bd=None) -> dict:
+    """Under ``bd`` a tile lies in one half: the blocks divide ``t``."""
+    return dict(block_q=_pick_block(bd[0] if bd else q.shape[2], BLOCK_Q),
+                block_k=_pick_block(bd[0] if bd else k.shape[2], BLOCK_K), interpret=_interpret())
 
 
-def _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale, causal, has_bias):
+def _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale, causal, has_bias, bd=None):
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
-    kw = dict(scale=scale, causal=causal, has_bias=has_bias, **_blocks(q, k))
+    kw = dict(scale=scale, causal=causal, has_bias=has_bias, bd=bd, **_blocks(q, k, bd))
     chunked = (max(t_q, t_k) > BWD_CHUNK_THRESHOLD
                or _resident_bwd_bytes(max(t_q, t_k), d, v.shape[-1], q.dtype.itemsize) > RESIDENT_BWD_VMEM)
     # D = rowsum(dO * O): cheap elementwise-reduce, fused by XLA. The dq pass
@@ -644,49 +786,62 @@ def _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale, causal, has_bias):
     # axis as the forward stores lse (Mosaic block layout requirement); the
     # dk/dv pass along lanes.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(b * h, t_q)
+                    axis=-1).reshape(lse.shape[:2])
+    if bd:  # K and V whole in the dq pass (``flash_attention_compatible`` holds them to VMEM); a query head a chunk
+        chunk_k, chunk_q = None, t_q
+    else:
+        chunk_k = _pick_chunk(t_k, kw["block_k"]) if chunked else None
+        chunk_q = _pick_chunk(t_q, kw["block_q"]) if chunked else None
     dq = _flash_bwd_dq(
         q, k, v, g, lse, jnp.broadcast_to(delta[:, :, None], lse.shape), bias,
-        chunk=_pick_chunk(t_k, kw["block_k"]) if chunked else None, **kw)
+        chunk=chunk_k, **kw)
     dk, dv = _flash_bwd_dkv(
         q, k, v, g, lse_lanes, delta[:, None, :], bias,
-        chunk=_pick_chunk(t_q, kw["block_q"]) if chunked else None, **kw)
+        chunk=chunk_q, **kw)
     return dq, dk, dv
 
 
 # ------------------------------------------------------------- public VJP
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, bias, scale, causal, has_bias):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, bias, scale, causal, has_bias, bd=None):
     return _flash_fwd(q, k, v, bias, scale=scale, causal=causal,
-                      has_bias=has_bias, save_residuals=False,
-                      **_blocks(q, k))[0]
+                      has_bias=has_bias, save_residuals=False, bd=bd,
+                      **_blocks(q, k, bd))[0]
 
 
-def _flash_vjp_fwd(q, k, v, bias, scale, causal, has_bias):
+def _flash_vjp_fwd(q, k, v, bias, scale, causal, has_bias, bd):
     out, lse, lse_lanes = _flash_fwd(q, k, v, bias, scale=scale, causal=causal,
-                                     has_bias=has_bias, save_residuals=True,
-                                     **_blocks(q, k))
+                                     has_bias=has_bias, save_residuals=True, bd=bd,
+                                     **_blocks(q, k, bd))
     return out, (q, k, v, bias, out, lse, lse_lanes)
 
 
-def _flash_vjp_bwd(scale, causal, has_bias, res, g):
+def _flash_vjp_bwd(scale, causal, has_bias, bd, res, g):
     q, k, v, bias, out, lse, lse_lanes = res
     dq, dk, dv = _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale,
-                            causal, has_bias)
+                            causal, has_bias, bd)
     return dq, dk, dv, jnp.zeros_like(bias)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def flash_attention(q, k, v, mask=None, causal: bool = False):
+def flash_attention(q, k, v, mask=None, causal: bool = False, block_diffusion=None):
     """(batch, heads, time, d) flash attention. ``mask`` may be a key-padding
     mask of shape (batch, t_k) or (batch, 1, 1, t_k) — 1/True = attend (check
     :func:`flash_attention_compatible` first). ``causal=True`` applies the
-    autoregressive triangle with diagonal block skipping."""
+    autoregressive triangle with diagonal block skipping;
+    ``block_diffusion=(t, block)`` the block-diffusion mask over ``[noisy ;
+    clean]`` of ``2 t`` positions, where ``k`` and ``v`` may have fewer heads
+    than ``q`` (query head ``i`` reads key/value head ``i // group``)."""
     b, t_k = q.shape[0], k.shape[2]
+    if block_diffusion is not None:
+        if mask is not None or causal:
+            raise ValueError("block_diffusion is a mask family of its own: no key-padding mask, not causal")
+        return _flash(q, k, v, jnp.zeros((b, t_k, 1), jnp.float32), 1.0 / float(q.shape[-1]) ** 0.5, False, False,
+                      tuple(int(n) for n in block_diffusion))
     kmask = _padding_mask_2d(mask, b, t_k)
     if mask is not None and kmask is None:
         raise ValueError("flash_attention supports key-padding masks only; "

@@ -543,5 +543,6 @@ def run_fit(net: TrainEngine, iterator, epochs: int, dispatcher,
         sync_timed(dispatcher, profiler)
         if profiler is not None:
             profiler.stop()
+            profiler.record_model_state(net.train_state.model_state)
     if adel is not None:
         adel.raise_pending()

@@ -43,6 +43,7 @@ import time
 from typing import Dict, Optional
 
 import jax
+import numpy as np
 
 from deeplearning4j_tpu.runtime import compile_cache, trace
 
@@ -99,6 +100,7 @@ class TrainingProfiler:
         self._cache_start: Optional[Dict] = None
         self._cache_stop: Optional[Dict] = None
         self._exchange = None  # ExchangeStats from a DistributedTrainer
+        self._model_state: Dict[str, object] = {}  # the small leaves of the last step's model state
 
     def attach_exchange(self, stats) -> "TrainingProfiler":
         """Attach a :class:`~deeplearning4j_tpu.runtime.profiler.ExchangeStats`
@@ -108,6 +110,18 @@ class TrainingProfiler:
         :meth:`summary` headline."""
         self._exchange = stats
         return self
+
+    #: leaves of the model state up to this many elements are counters
+    COUNTER_SIZE = 1024
+
+    def record_model_state(self, model_state) -> None:
+        """Keep the small leaves of the model state as ``fit`` leaves it (an
+        expert layer's ``assigned`` and ``overflow``, a head's recorded loss
+        terms): the arrays themselves, nothing is read until :meth:`report`."""
+        leaves = jax.tree_util.tree_flatten_with_path(model_state)[0]
+        with self._lock:
+            self._model_state = {"/".join(str(getattr(k, "key", k)) for k in path): leaf for path, leaf in leaves
+                                 if getattr(leaf, "size", self.COUNTER_SIZE + 1) <= self.COUNTER_SIZE}
 
     # ------------------------------------------------------------ recording
     def start(self) -> "TrainingProfiler":
@@ -216,6 +230,9 @@ class TrainingProfiler:
             # state-reading listener forces synchronous delivery, where it
             # is never recorded — flag that rather than report 0 as "free"
             out["step_measured"] = self._counts["step"] > 0
+            counters = dict(self._model_state)
+        # the last step's counters, by their path in the model state, read to the host here
+        out["model_state"] = {path: np.asarray(leaf, np.float64).ravel().tolist() for path, leaf in counters.items()}
         if self._exchange is not None:
             out["exchange"] = self._exchange.report()
         return out

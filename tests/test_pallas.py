@@ -928,6 +928,31 @@ def test_flash_attention_compiles_for_v5e_with_two_head_sizes_at_t8192(v5e_chip,
     assert "flash_attention_bwd_dq_chunked" in text and "flash_attention_bwd_dkv_chunked" in text
 
 
+def test_bd_flash_attention_compiles_for_v5e_at_the_sdar_cells_shape(v5e_chip, monkeypatch):
+    """The block-diffusion family at the SDAR cell's shape: 32 query heads
+    over 4 key/value heads of 128, [xt ; x0] of 2 x 4096 positions in blocks
+    of 4, bf16. K and V of a head stay whole in VMEM in the forward and the
+    dq pass (2 x 2 MB, double-buffered), a query head's Q and dO in the dk/dv
+    pass, whose third grid axis runs over the group: three Mosaic calls under
+    their own names, none of the causal family's."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET")
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16, sharding=v5e_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, block_diffusion=(4096, 4)).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("bd_flash_attention_fwd", "bd_flash_attention_bwd_dq", "bd_flash_attention_bwd_dkv"):
+        assert name in text
+    assert "_chunked" not in text and '"flash_attention_fwd' not in text
+
+
 def test_flash_attention_adds_no_layout_copy_to_a_glm_step(v5e_chip, monkeypatch):
     """The dk/dv pass computes its score tile transposed and takes the row
     statistics along lanes (PR 36); what it saves in VMEM must not come back
