@@ -29,6 +29,18 @@ the cache at import; entry points (``chip_smoke.py``, ``bench.py``) call
 - **hit / miss / corrupt counters + compile seconds**, exposed through
   :func:`stats`, ``runtime.profiler.compile_cache_stats`` and the serving
   ``/metrics`` endpoint (``compile_cache_hits_total`` …).
+- **spans per program and phase**: jax's own compile events, timed where
+  jax opens and closes them, as phases ``trace`` (jaxpr tracing),
+  ``lower`` (jaxpr to an MLIR module, Pallas / Mosaic kernels included),
+  ``compile`` (the backend compile, self time: a retrieval inside it is
+  not a compile) and ``retrieve`` (an executable read from the persistent
+  cache; a backend-compile span that holds one is booked to it). A span's
+  parent is the span open around it on its thread; a span nested in one of
+  its own phase (tracing ``f`` traces the jits ``f`` calls) is booked to
+  the outer program. :func:`programs` gives seconds and counts per
+  (program, phase), :func:`spans` the last spans, ``stats()`` the phases'
+  totals: ``compiles`` / ``compile_seconds`` count misses, ``hits`` /
+  ``retrieval_seconds`` hits.
 - **corrupt-entry tolerance**: a truncated or bit-rotten cache entry (or a
   fault injected at the ``runtime.compile_cache.load`` chaos point) is
   counted, logged, and answered with "not cached" — a cold compile is
@@ -51,15 +63,18 @@ Disable with ``DL4J_TPU_AOT_DISPATCH=0`` or
 
 from __future__ import annotations
 
+import collections
+import itertools
 import logging
 import os
 import threading
 import time
-from typing import Any, Dict, Hashable, Optional
+from typing import Any, Dict, Hashable, List, Optional
 
 import jax
 from jax._src import compilation_cache as _cc
 from jax._src import monitoring
+from jax.profiler import TraceAnnotation
 
 from deeplearning4j_tpu.runtime import chaos, trace
 
@@ -67,6 +82,20 @@ logger = logging.getLogger(__name__)
 
 #: JAX's own variable: when set, it alone places the cache.
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: jax's compile events that open and close a span, by phase
+SPAN_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+#: recorded once, on a persistent-cache hit, inside the backend-compile span
+RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: program names kept apart; the rest are booked under ``OTHER``
+MAX_PROGRAMS = 256
+OTHER = "(other)"
+#: spans kept, newest last
+MAX_SPANS = 512
 
 
 def default_dir() -> str:
@@ -94,10 +123,11 @@ def resolve_dir(directory: Optional[str] = None) -> str:
 
 
 class CompileCacheStats:
-    """Thread-safe counters for the persistent cache + AOT layer."""
+    """Thread-safe counters for the persistent cache + AOT layer, and the
+    compile spans' totals per (program, phase) with the last spans."""
 
     def __init__(self):
-        # guards: hits, misses, corrupt_entries, compiles, compile_seconds, retrieval_seconds, aot_compiles, aot_compile_seconds, aot_fallbacks
+        # guards: hits, misses, corrupt_entries, compiles, compile_seconds, retrieval_seconds, trace_seconds, lower_seconds, aot_compiles, aot_compile_seconds, aot_fallbacks, _programs, _spans
         self._lock = threading.Lock()
         self._zero()
 
@@ -105,12 +135,16 @@ class CompileCacheStats:
         self.hits = 0               # executables deserialized from the cache
         self.misses = 0             # consulted, absent -> backend compile
         self.corrupt_entries = 0    # unreadable entry -> fallback compile
-        self.compiles = 0           # backend compiles observed
-        self.compile_seconds = 0.0  # total backend compile wall time
-        self.retrieval_seconds = 0.0  # total cache deserialize wall time
+        self.compiles = 0           # backend compiles that retrieved nothing
+        self.compile_seconds = 0.0  # their self time
+        self.retrieval_seconds = 0.0  # backend-compile spans that retrieved
+        self.trace_seconds = 0.0    # jaxpr tracing, self time
+        self.lower_seconds = 0.0    # jaxpr -> MLIR module, self time
         self.aot_compiles = 0       # lower().compile() executables minted
         self.aot_compile_seconds = 0.0
         self.aot_fallbacks = 0      # signature drift -> jit path fallback
+        self._programs: Dict[str, Dict[str, List]] = {}  # program -> phase -> [seconds, count]
+        self._spans = collections.deque(maxlen=MAX_SPANS)
 
     def reset(self):
         with self._lock:
@@ -119,12 +153,45 @@ class CompileCacheStats:
     def record(self, field: str, dt: float = 0.0):
         with self._lock:
             setattr(self, field, getattr(self, field) + 1)
-            if field == "compiles":
-                self.compile_seconds += dt
-            elif field == "hits":
-                self.retrieval_seconds += dt
-            elif field == "aot_compiles":
+            if field == "aot_compiles":
                 self.aot_compile_seconds += dt
+
+    def book(self, phase: str, program: str, seconds: float, counted: bool,
+             span: Optional[Dict[str, Any]]) -> None:
+        """Add ``seconds`` to (``program``, ``phase``) and to the phase's
+        total, one to their counts when ``counted``; keep ``span``."""
+        with self._lock:
+            if (program not in self._programs
+                    and len(self._programs) - (OTHER in self._programs) >= MAX_PROGRAMS):
+                program = OTHER
+            total = self._programs.setdefault(program, {}).setdefault(phase, [0.0, 0])
+            total[0] += seconds
+            total[1] += counted
+            if phase == "trace":
+                self.trace_seconds += seconds
+            elif phase == "lower":
+                self.lower_seconds += seconds
+            elif phase == "compile":
+                self.compiles += counted
+                self.compile_seconds += seconds
+            else:
+                self.retrieval_seconds += seconds
+            if span is not None:
+                self._spans.append(span)
+
+    def programs(self, top: Optional[int] = None) -> Dict[str, Dict[str, Dict[str, Any]]]:
+        with self._lock:
+            table = {program: {phase: {"seconds": round(s, 6), "count": n}
+                               for phase, (s, n) in per.items()}
+                     for program, per in self._programs.items()}
+        if top is not None:
+            ranked = sorted(table, key=lambda p: -sum(v["seconds"] for v in table[p].values()))
+            table = {program: table[program] for program in ranked[:top]}
+        return table
+
+    def spans(self) -> List[Dict[str, Any]]:
+        with self._lock:
+            return [dict(span) for span in self._spans]
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -137,6 +204,8 @@ class CompileCacheStats:
                 "compiles": self.compiles,
                 "compile_seconds": round(self.compile_seconds, 4),
                 "retrieval_seconds": round(self.retrieval_seconds, 4),
+                "trace_seconds": round(self.trace_seconds, 4),
+                "lower_seconds": round(self.lower_seconds, 4),
                 "aot_compiles": self.aot_compiles,
                 "aot_compile_seconds": round(self.aot_compile_seconds, 4),
                 "aot_fallbacks": self.aot_fallbacks,
@@ -151,16 +220,128 @@ _orig_get = None
 
 
 def stats() -> Dict[str, Any]:
-    """Process-wide cache/AOT counters (see also
-    ``runtime.profiler.compile_cache_stats`` and serving ``/metrics``).
-    Note: hit/miss counts include jax's own small internal jits
-    (convert_element_type etc.), not only model programs — they are true
-    per-executable counts."""
+    """Process-wide cache/AOT counters and the compile phases' seconds
+    (see also ``runtime.profiler.compile_cache_stats`` and serving
+    ``/metrics``): a cheap flat snapshot. Note: hit/miss counts include
+    jax's own small internal jits (convert_element_type etc.), not only
+    model programs — they are true per-executable counts."""
     return STATS.snapshot()
+
+
+def programs(top: Optional[int] = None) -> Dict[str, Dict[str, Dict[str, Any]]]:
+    """``{program: {phase: {"seconds", "count"}}}`` since the process
+    started (or :func:`reset_stats`); with ``top`` only the programs with
+    the most seconds over all phases. A program is jax's name for it
+    (``jit(f)``); past :data:`MAX_PROGRAMS` names, :data:`OTHER`."""
+    return STATS.programs(top)
+
+
+def spans() -> List[Dict[str, Any]]:
+    """The last :data:`MAX_SPANS` spans, in the order they closed: ``id``,
+    ``phase``, ``program`` (the span's own name), ``start`` / ``end`` on
+    ``time.time()``, and ``parent``, the id of the span open around it on
+    its thread (None at the top)."""
+    return STATS.spans()
 
 
 def reset_stats() -> None:
     STATS.reset()
+
+
+# --------------------------------------------------------------- spans
+class _Open:
+    """A compile span jax has opened on this thread and not closed yet."""
+
+    __slots__ = ("id", "phase", "name", "program", "start", "parent",
+                 "nested", "inner_s", "hit")
+
+    def __init__(self, phase: str, name: str, start: float, parent: Optional["_Open"]):
+        self.id = next(_span_ids)
+        self.phase, self.name, self.start, self.parent = phase, name, start, parent
+        self.nested = parent is not None and parent.phase == phase
+        self.program = parent.program if self.nested else name
+        self.inner_s = 0.0  # seconds of the spans closed inside it
+        self.hit = False    # a cache retrieval closed inside it
+
+
+_span_ids = itertools.count(1)
+_local = threading.local()
+
+
+def _open_spans() -> List[_Open]:
+    stack = getattr(_local, "stack", None)
+    if stack is None:
+        stack = _local.stack = []
+    return stack
+
+
+def _record(span_id: int, phase: str, name: str, start: float, end: float,
+            parent: Optional[_Open]) -> Dict[str, Any]:
+    return {"id": span_id, "phase": phase, "program": name, "start": start,
+            "end": end, "parent": None if parent is None else parent.id}
+
+
+def _on_open(event: str, start: float, **kw) -> None:
+    """jax opens a span with ``record_scalar(event, start, fun_name=)``."""
+    phase = SPAN_EVENTS.get(event)
+    if phase is None:
+        return
+    name = kw.get("fun_name", "?")
+    if phase == "trace":
+        name = f"jit({name})"  # the name its lowering and compile carry
+    stack = _open_spans()
+    stack.append(_Open(phase, name, start, stack[-1] if stack else None))
+
+
+def _on_close(event: str, start: float, end: float, **kw) -> None:
+    """jax closes a span with ``record_event_time_span``: book its self
+    time (the span less the spans closed inside it)."""
+    phase = SPAN_EVENTS.get(event)
+    if phase is None:
+        return
+    stack = _open_spans()
+    at = next((i for i in range(len(stack) - 1, -1, -1)
+               if stack[i].start == start and stack[i].phase == phase), None)
+    if at is None:
+        return  # opened before the listeners were subscribed
+    span = stack[at]
+    del stack[at:]  # with any span jax left open inside it
+    seconds = end - start
+    if span.parent is not None:
+        span.parent.inner_s += seconds
+    record = _record(span.id, phase, span.name, start, end, span.parent)
+    if phase == "compile" and span.hit:
+        # a hit: the span is the retrieval's, already counted
+        STATS.book("retrieve", span.program, seconds - span.inner_s, False, record)
+    else:
+        STATS.book(phase, span.program, seconds - span.inner_s, not span.nested, record)
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    """A persistent-cache hit: the retrieval, a child of the backend-compile
+    span open on this thread."""
+    if event != RETRIEVAL_EVENT:
+        return
+    end = time.time()
+    stack = _open_spans()
+    parent = stack[-1] if stack else None
+    program, name = OTHER, OTHER
+    if parent is not None:
+        parent.inner_s += seconds
+        parent.hit = True
+        program, name = parent.program, parent.name
+    STATS.book("retrieve", program, seconds, True,
+               _record(next(_span_ids), "retrieve", name, end - seconds, end, parent))
+
+
+def _subscribe() -> None:
+    """Listen to jax's compile events; once, at import, for the process."""
+    monitoring.register_scalar_listener(_on_open)
+    monitoring.register_event_time_span_listener(_on_close)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+_subscribe()
 
 
 def is_enabled() -> bool:
@@ -172,8 +353,8 @@ def cache_dir() -> Optional[str]:
 
 
 def _install_hooks() -> None:
-    """Patch the cache load path (counters + chaos + corrupt tolerance) and
-    subscribe to jax's compile-duration monitoring stream. Idempotent."""
+    """Patch the cache load path (counters + chaos + corrupt tolerance).
+    Idempotent. The compile spans are listened to from import on."""
     global _hooks_installed, _orig_get
     if _hooks_installed:
         return
@@ -184,7 +365,6 @@ def _install_hooks() -> None:
         # signature (0.9 added executable_devices) and a wrapper that
         # names them turns every read into a TypeError jax downgrades to
         # a warning — a cache that silently never hits
-        t0 = time.perf_counter()
         try:
             chaos.inject("runtime.compile_cache.load")
             executable, compile_time = _orig_get(cache_key, *args, **kwargs)
@@ -200,19 +380,10 @@ def _install_hooks() -> None:
                 "to a fresh compile", str(cache_key)[:16],
                 type(e).__name__, e)
             return None, None
-        if executable is None:
-            STATS.record("misses")
-        else:
-            STATS.record("hits", time.perf_counter() - t0)
+        STATS.record("misses" if executable is None else "hits")
         return executable, compile_time
 
     _cc.get_executable_and_time = _guarded_get
-
-    def _on_duration(name: str, dur: float, **kw) -> None:
-        if name == "/jax/core/compile/backend_compile_duration":
-            STATS.record("compiles", dur)
-
-    monitoring.register_event_duration_secs_listener(_on_duration)
     _hooks_installed = True
 
 
@@ -245,8 +416,9 @@ def enable(directory: Optional[str] = None) -> str:
 
 
 def disable() -> None:
-    """Detach the persistent cache (counters and hooks stay; they are
-    inert without a configured directory). A cache placed through
+    """Detach the persistent cache (counters and hooks stay; the load-path
+    counters are inert without a configured directory, the compile spans
+    go on). A cache placed through
     ``JAX_COMPILATION_CACHE_DIR`` is not this module's to detach."""
     global _cache_dir
     if _cache_dir is None:
@@ -320,8 +492,13 @@ class AotCache:
         # gets the executable-cache outcome stamped on it (ISSUE 9)
         trace.annotate_current("aot", "hit" if entry is not None else "miss")
         if entry is None:
+            # on the host plane of any open profiler session, over the idle
+            # gap a recompile leaves on the device
             t0 = time.perf_counter()
-            entry = jitted.lower(*args).compile()
+            with TraceAnnotation("compile_cache.lower"):
+                lowered = jitted.lower(*args)
+            with TraceAnnotation("compile_cache.compile"):
+                entry = lowered.compile()
             STATS.record("aot_compiles", time.perf_counter() - t0)
             self._entries[key] = entry
         try:

@@ -3,8 +3,8 @@
 A :class:`TrainingProfiler` attached to ``fit(..., profiler=...)``
 (MultiLayerNetwork, ComputationGraph, ParallelWrapper) names every part of
 the fit loop's wall time. :meth:`TrainingProfiler.stage` is the one place a
-fit stage is timed: a ``perf_counter`` pair that feeds the totals, the
-histograms and ``trace.stage_event``, under a
+fit stage is timed: a ``perf_counter`` pair that feeds the totals and
+``trace.stage_event``, under a
 ``jax.profiler.TraceAnnotation("fit.<stage>")`` - so each stage also lands
 on the host plane of whatever ``jax.profiler`` session is open, on the
 device trace's clock. The stages that tile the fit thread:
@@ -28,9 +28,9 @@ submit -> loss ready, observed on the completion thread, off the fit thread.
 
 ``report()['data_wait_fraction']`` is the fraction of fit wall time the
 device spent starved for data; ``unattributed_fraction`` says how much of
-the fit call the stages do not explain. Histograms reuse
-:class:`~deeplearning4j_tpu.serving.metrics.LatencyHistogram` - one
-percentile implementation across training and serving.
+the fit call the stages do not explain. ``report()['setup']`` is what the
+process had spent compiling before the fit call began, by phase and by
+program (``runtime.compile_cache``): the set-up that precedes the window.
 
 Thread-safety: stages are recorded from the fit loop, the prefetch worker
 and the completion worker concurrently; all mutation is behind one lock.
@@ -88,17 +88,22 @@ class TrainingProfiler:
     #: is reported
     _CACHE_KEYS = ("aot_compiles", "aot_fallbacks", "aot_compile_seconds")
 
+    #: what ``report()['setup']`` keeps of ``compile_cache.stats()``
+    _SETUP_KEYS = ("trace_seconds", "lower_seconds", "compile_seconds",
+                   "retrieval_seconds", "hits", "misses", "compiles")
+    #: programs in ``report()['setup']``, those with the most seconds
+    SETUP_PROGRAMS = 10
+
     def __init__(self):
-        from deeplearning4j_tpu.serving.metrics import LatencyHistogram
-        # guards: _totals, _counts, _hists, _t_start, _t_stop, _cache_start, _cache_stop
+        # guards: _totals, _counts, _t_start, _t_stop, _cache_start, _cache_stop, _setup_programs
         self._lock = threading.Lock()
-        self._hists = {s: LatencyHistogram() for s in self.STAGES}
         self._totals = {s: 0.0 for s in self.STAGES}
         self._counts = {s: 0 for s in self.STAGES}
         self._t_start: Optional[float] = None
         self._t_stop: Optional[float] = None
         self._cache_start: Optional[Dict] = None
         self._cache_stop: Optional[Dict] = None
+        self._setup_programs: Optional[Dict] = None
         self._exchange = None  # ExchangeStats from a DistributedTrainer
         self._model_state: Dict[str, object] = {}  # the small leaves of the last step's model state
 
@@ -128,10 +133,12 @@ class TrainingProfiler:
         """Mark the window start (``fit`` calls this; explicit calls allow
         profiling a sub-window)."""
         cache = compile_cache.stats()
+        top = compile_cache.programs(self.SETUP_PROGRAMS)
         with self._lock:
             if self._t_start is None:
                 self._t_start = time.perf_counter()
                 self._cache_start = cache
+                self._setup_programs = top
         return self
 
     def stop(self) -> "TrainingProfiler":
@@ -166,7 +173,6 @@ class TrainingProfiler:
             self._totals[stage] += seconds
             if counted:
                 self._counts[stage] += 1
-                self._hists[stage].observe(seconds)
 
     def record_data_wait(self, seconds: float, counted: bool = True) -> None:
         self._record("data_wait", seconds, counted)
@@ -193,7 +199,11 @@ class TrainingProfiler:
     def report(self) -> Dict[str, float]:
         """Aggregate stage report. ``data_wait_fraction`` is data-wait time
         over the profiled wall-clock window; ``steps_per_sec`` counts
-        dispatched iterations over the same window."""
+        dispatched iterations over the same window. ``setup`` holds
+        ``compile_cache.stats()``'s phase seconds, hits, misses and compiles
+        as :meth:`start` found them, and ``programs``, the
+        :attr:`SETUP_PROGRAMS` programs with the most seconds by then, by
+        phase; None before :meth:`start`."""
         elapsed = self.elapsed()
         cache_now = compile_cache.stats()
         with self._lock:
@@ -206,8 +216,6 @@ class TrainingProfiler:
                 out[f"{s}_total_s"] = round(self._totals[s], 4)
                 out[f"{s}_mean_ms"] = round(
                     self._totals[s] / n * 1e3, 3) if n else 0.0
-                out[f"{s}_p99_ms"] = round(
-                    self._hists[s].percentile(99) * 1e3, 3)
             out["data_wait_fraction"] = round(
                 self._totals["data_wait"] / elapsed, 4) if elapsed else 0.0
             # the root is the fit call; what no fit-thread stage covers
@@ -230,6 +238,10 @@ class TrainingProfiler:
             # state-reading listener forces synchronous delivery, where it
             # is never recorded — flag that rather than report 0 as "free"
             out["step_measured"] = self._counts["step"] > 0
+            # what the process had compiled before the fit call began
+            out["setup"] = None if self._cache_start is None else dict(
+                {k: self._cache_start[k] for k in self._SETUP_KEYS},
+                programs=self._setup_programs)
             counters = dict(self._model_state)
         # the last step's counters, by their path in the model state, read to the host here
         out["model_state"] = {path: np.asarray(leaf, np.float64).ravel().tolist() for path, leaf in counters.items()}

@@ -158,6 +158,112 @@ def test_cached_compile_with_cache_errors_fatal(cache_dir):
     assert (r1 == r2).all()
 
 
+# ------------------------------------------------------ compile spans
+def _spans_probe():
+    """A fresh def each call: ``jit(spans_probe)`` is traced, lowered and
+    compiled (or retrieved) anew once the in-memory caches are cleared."""
+    def spans_probe(x):
+        return jnp.tanh(x) @ x.T
+    return jax.jit(spans_probe)
+
+
+def test_a_hit_is_a_retrieval_not_a_compile(cache_dir):
+    x = jnp.ones((8, 4))
+    compile_cache.reset_stats()
+    np.asarray(_spans_probe()(x))  # a miss
+    jax.clear_caches()
+    np.asarray(_spans_probe()(x))  # and a hit: counted before as a second compile
+    s = compile_cache.stats()
+    assert s["compiles"] == s["misses"] == 1 and s["hits"] == 1, s
+    assert s["trace_seconds"] > 0 and s["lower_seconds"] > 0
+    assert s["compile_seconds"] > 0 and s["retrieval_seconds"] > 0
+    program = compile_cache.programs()["jit(spans_probe)"]
+    assert set(program) == {"trace", "lower", "compile", "retrieve"}
+    assert all(p["seconds"] > 0 for p in program.values())
+    assert program["compile"]["count"] == program["retrieve"]["count"] == 1
+    assert program["trace"]["count"] == program["lower"]["count"] == 2
+    spans = {span["id"]: span for span in compile_cache.spans()}
+    retrievals = [span for span in spans.values() if span["phase"] == "retrieve"]
+    assert [r["program"] for r in retrievals] == ["jit(spans_probe)"]
+    parent = spans[retrievals[0]["parent"]]
+    assert parent["phase"] == "compile" and parent["program"] == "jit(spans_probe)"
+    assert parent["start"] <= retrievals[0]["start"] <= retrievals[0]["end"] <= parent["end"]
+    # the compile span that held the hit is the retrieval's seconds, whole
+    assert program["retrieve"]["seconds"] == pytest.approx(parent["end"] - parent["start"], abs=1e-5)
+
+
+def test_nested_traces_are_booked_to_the_outer_program():
+    def inner(x):
+        return jnp.cos(x) + 1.0
+    inner_jit = jax.jit(inner)
+
+    def outer(x):
+        return inner_jit(x) * 2.0
+    x = jnp.ones((3,))
+    compile_cache.reset_stats()
+    jax.jit(outer).lower(x)
+    programs = compile_cache.programs()
+    assert "jit(inner)" not in programs  # traced inside jit(outer): its seconds are outer's
+    assert programs["jit(outer)"]["trace"]["count"] == 1
+    spans = compile_cache.spans()
+    top = next(s for s in spans if s["program"] == "jit(outer)" and s["phase"] == "trace")
+    nested = next(s for s in spans if s["program"] == "jit(inner)")
+    assert nested["parent"] == top["id"] and top["parent"] is None
+    assert programs["jit(outer)"]["trace"]["seconds"] == pytest.approx(top["end"] - top["start"], abs=1e-5)
+    assert compile_cache.stats()["trace_seconds"] == pytest.approx(top["end"] - top["start"], abs=1e-4)
+
+
+def test_programs_past_the_cap_fold_into_other():
+    compile_cache.reset_stats()
+    try:
+        for i in range(compile_cache.MAX_PROGRAMS + 10):
+            compile_cache.STATS.book("lower", f"jit(p{i})", 0.5, True, None)
+        programs = compile_cache.programs()
+        assert len(programs) == compile_cache.MAX_PROGRAMS + 1
+        assert programs[compile_cache.OTHER]["lower"] == {"seconds": 5.0, "count": 10}
+        assert "jit(p0)" in programs and f"jit(p{compile_cache.MAX_PROGRAMS})" not in programs
+        assert list(compile_cache.programs(top=3)) == ["(other)", "jit(p0)", "jit(p1)"]
+        for i in range(2 * compile_cache.MAX_SPANS):
+            compile_cache.STATS.book("trace", "jit(q)", 0.0, True, {"id": i})
+        assert [s["id"] for s in compile_cache.spans()] == list(
+            range(compile_cache.MAX_SPANS, 2 * compile_cache.MAX_SPANS))
+    finally:
+        compile_cache.reset_stats()
+
+
+def test_fit_profiler_reports_the_setup_before_its_call():
+    from deeplearning4j_tpu.train import TrainingProfiler
+    net = MultiLayerNetwork(_mln_conf(seed=11)).init()
+    net.fit(_iterator(), epochs=1)  # set-up: the step is compiled here
+    before = compile_cache.stats()
+    prof = TrainingProfiler()
+    net.fit(_iterator(), epochs=1, profiler=prof)
+    setup = prof.report()["setup"]
+    assert {k: setup[k] for k in TrainingProfiler._SETUP_KEYS} == {
+        k: before[k] for k in TrainingProfiler._SETUP_KEYS}
+    assert setup["trace_seconds"] > 0 and setup["lower_seconds"] > 0
+    assert len(setup["programs"]) == TrainingProfiler.SETUP_PROGRAMS
+    step = setup["programs"]["jit(packed_train_step)"]
+    assert {"trace", "lower", "compile"} <= set(step)
+    assert TrainingProfiler().report()["setup"] is None  # never started
+
+
+def test_an_aot_miss_is_annotated_on_the_host_plane(tmp_path, aot_toggle):
+    from jax.profiler import ProfileData
+    aot_toggle.set_aot_dispatch(True)
+    fitted = jax.jit(lambda s, x: s + x.sum())
+    aot = compile_cache.AotCache("annotated")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        aot.call("k", fitted, jnp.ones((4,)), jnp.ones((2, 4)))  # a miss
+        aot.call("k", fitted, jnp.ones((4,)), jnp.ones((2, 4)))  # a hit
+    finally:
+        jax.profiler.stop_trace()
+    names = [e.name for plane in ProfileData.from_file(str(next(tmp_path.glob("**/*.xplane.pb")))).planes
+             for line in plane.lines for e in line.events]
+    assert names.count("compile_cache.lower") == names.count("compile_cache.compile") == 1
+
+
 # ------------------------------------------------- where the cache lives
 _REPO = pathlib.Path(__file__).resolve().parents[1]
 

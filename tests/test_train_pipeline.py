@@ -506,7 +506,8 @@ def test_stages_tile_the_fit_loop(prefetch):
     assert 0.0 <= r["unattributed_fraction"] < 0.25
     assert r["aot_compiles"] == 0 and r["aot_fallbacks"] == 0 and r["compile_in_fit_s"] == 0
     for stage in ("next_batch", "h2d", "rng", "drain", "sync"):
-        assert {f"{stage}_total_s", f"{stage}_mean_ms", f"{stage}_p99_ms"} <= set(r)
+        assert {f"{stage}_total_s", f"{stage}_mean_ms"} <= set(r)
+        assert f"{stage}_p99_ms" not in r
 
 
 def test_profiler_reports_compiles_inside_fit():
