@@ -84,9 +84,13 @@ whole. Under this family the query heads may outnumber the key/value heads
 (grouped-query attention): a key/value head's group of query heads is laid
 head after head along the kernels' row axis, ``(b * h_kv, group * 2t, d)``,
 a reshape of what the caller holds, so that K and V are fetched once a
-group in the forward and the dq pass, and the dk/dv pass adds up over the
-group in its scratch (its third grid axis runs over the group's heads as
-the chunked form's runs over chunks). K and V are never repeated in HBM.
+group in the forward and the dq pass. The dk/dv pass runs its grid over
+(key/value head, query head, key tile): a query head's Q and dO (4 MB at
+2t = 8192, heads of 128) are fetched once a query head, not once a key tile
+(32 fetches a run where there were 512 at the SDAR cell's shape), and dk and
+dv of the whole key/value head add up over the group in float32 scratch,
+each key tile zeroed at the first query head and written out at the last.
+K and V are never repeated in HBM.
 These calls are named ``bd_flash_attention_fwd`` / ``_bwd_dq`` /
 ``_bwd_dkv``; what a masked tile costs over a whole one is measured, not
 yet cut (PERF.md).
@@ -158,8 +162,11 @@ def flash_attention_compatible(q, k, v, mask=None, causal: bool = False, block_d
     lanes, and a key length long enough that the kernel beats XLA. Under
     ``block_diffusion=(t, block)``: no other mask, ``2 t`` positions on both
     sides, whole tiles in each half (``t`` a multiple of 128) and whole
-    blocks, K and V of a head resident in VMEM; only there may the query
-    heads be a multiple of the key/value heads."""
+    blocks, and what a head keeps in VMEM within bounds: K and V of a head
+    (forward, dq pass) or Q and dO of one (dk/dv pass), double-buffered,
+    and in the dk/dv pass dk and dv of a whole key/value head in float32
+    scratch; only there may the query heads be a multiple of the key/value
+    heads."""
     if q.ndim != 4:
         return False
     t_q, d = q.shape[2], q.shape[3]
@@ -168,7 +175,7 @@ def flash_attention_compatible(q, k, v, mask=None, causal: bool = False, block_d
         t, block = block_diffusion
         if (mask is not None or causal or t_q != 2 * t or t_k != 2 * t or t % 128 or t % block
                 or q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]
-                or 2 * t_k * (d + v.shape[3]) * q.dtype.itemsize > RESIDENT_BWD_VMEM):
+                or t_k * (d + v.shape[3]) * (2 * q.dtype.itemsize + 4) > RESIDENT_BWD_VMEM):
             return False
     elif k.shape[1] != q.shape[1]:
         return False
@@ -335,16 +342,17 @@ def _lanes(x, n: int):
     return jnp.tile(x, (1, n // 128)) if n % 128 == 0 else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _stepwise(n_chunks, init, work, flush):
+def _stepwise(n_chunks, init, work, flush, axis: int = 2):
     """A grid step of a kernel that accumulates in scratch: resident
     (``n_chunks`` None) it does all three, chunked the first chunk's step
-    initialises and the last one's flushes."""
+    initialises and the last one's flushes; the chunks run along the grid's
+    ``axis``."""
     if n_chunks is None:
         init()
         work(0)
         flush()
         return
-    ci = pl.program_id(2)
+    ci = pl.program_id(axis)
     pl.when(ci == 0)(init)
     work(ci)
     pl.when(ci == n_chunks - 1)(flush)
@@ -541,9 +549,11 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
     grid (bh, ki, ci), Q/dO/lse/delta blocks are the ci-th chunk. The score
     tile is computed transposed, (BK, BQ): keys on sublanes, queries on
     lanes, so that both results are plain matmuls of it; ``lse`` and
-    ``delta`` come along lanes. dk accumulates unscaled. Under ``bd`` a
-    chunk is one query head of this key/value head's group: the scratch adds
-    up over the group."""
+    ``delta`` come along lanes. dk accumulates unscaled. Under ``bd`` the
+    grid is (bh, query head of the group, key tile in :func:`_bd_key_order`)
+    and ``n_chunks`` the group: the scratch holds every key tile of the
+    key/value head and adds up over the group, a key tile's rows zeroed at
+    the first query head and written out at the last."""
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     # this key block's bias (shared across q blocks), one a row of the tile
     bias = refs[6][0] if has_bias else None   # (BK, 1)
@@ -551,8 +561,13 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
     k = _pre_scaled(k_ref[0], scale)          # (BK, D): for the scores alone
     v = v_ref[0]                              # (BK, Dv)
     in_dtype = k.dtype
-    ki = pl.program_id(1)
     block_k = k.shape[0]
+    if bd:
+        ki = _bd_key_order(pl.program_id(2), 2 * bd[0] // block_k)
+        own = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        dk_acc_ref, dv_acc_ref = dk_acc_ref.at[own], dv_acc_ref.at[own]
+    else:
+        ki = pl.program_id(1)
     nb = q_ref.shape[1] // block_q
     mask = _tile_mask(bd)
 
@@ -600,7 +615,7 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
         dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
-    _stepwise(n_chunks, init, work, flush)
+    _stepwise(n_chunks, init, work, flush, axis=1 if bd else 2)
 
 
 # Above this sequence length the backward switches to the CHUNKED forms:
@@ -706,35 +721,66 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
     return dq.reshape(b, h, t_q, d)
 
 
+def _bd_key_order(j, n_k: int):
+    """The key tile that the ``j``-th step of a query head visits in the
+    block-diffusion dk/dv pass: the first clean key tile, seen by every query
+    tile of the head (16 tiles at 2t = 8192 in tiles of 512), comes last, so
+    that the next query head's Q and dO are fetched behind the heaviest
+    step of the head."""
+    return jax.lax.rem(j + n_k // 2 + 1, n_k)
+
+
 @functools.partial(jax.jit, static_argnames=_STATIC + ("chunk",))
 def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
                    has_bias, block_q, block_k, interpret, bd=None):
     """dk, dv from (b, h, t, d) operands and (b*h, 1, t_q) row statistics;
     Q/dO whole a grid step (``chunk`` None) or ``chunk`` rows. Under ``bd``
-    the chunk is ``t_q``, one query head, and the last grid axis runs over
-    the heads of a key/value head's group."""
+    the grid is (key/value head, query head of its group, key tile): a query
+    head's Q, dO and row statistics keep one block for all the key tiles, so
+    they are fetched once a (key/value head, query head); dk and dv keep the
+    block of the key tile the last query head starts with until that head,
+    so that each tile is written back once, when it is whole."""
     b, h, t_q, d = q.shape
     h_kv, t_k = k.shape[1], k.shape[2]
     d_v = v.shape[-1]
-    chunk_q = chunk or t_q
-    n_chunks = h // h_kv * t_q // chunk if chunk else None
+    if bd:
+        group, n_k = h // h_kv, t_k // block_k
+        grid, n_chunks, chunk_q, acc_rows = (b * h_kv, group, n_k), group, t_q, t_k
 
-    def own(bh, ki, *ci):
-        return (bh, ki, 0)
+        def own(bh, g, kj):
+            return (bh, _bd_key_order(kj, n_k), 0)
 
-    def streamed(bh, ki, *ci):
-        if not ci:
-            return 0
-        # mirror of the dq-pass DMA skip: query chunks strictly above the
-        # diagonal for this key block re-map to the first needed chunk
-        first = (ki * block_k) // chunk_q
-        return jnp.maximum(ci[0], first) if causal else ci[0]
+        def rows(bh, g, kj):
+            return (bh, g, 0)
 
-    def rows(bh, *at):
-        return (bh, streamed(bh, *at), 0)
+        def lanes(bh, g, kj):
+            return (bh, 0, g)
 
-    def lanes(bh, *at):
-        return (bh, 0, streamed(bh, *at))
+        def out(bh, g, kj):
+            return (bh, _bd_key_order(jnp.where(g == group - 1, kj, 0), n_k), 0)
+    else:
+        chunk_q = chunk or t_q
+        n_chunks = t_q // chunk if chunk else None
+        grid, acc_rows = (b * h_kv, t_k // block_k) + ((n_chunks,) if chunk else ()), block_k
+
+        def own(bh, ki, *ci):
+            return (bh, ki, 0)
+
+        def streamed(bh, ki, *ci):
+            if not ci:
+                return 0
+            # mirror of the dq-pass DMA skip: query chunks strictly above the
+            # diagonal for this key block re-map to the first needed chunk
+            first = (ki * block_k) // chunk_q
+            return jnp.maximum(ci[0], first) if causal else ci[0]
+
+        def rows(bh, *at):
+            return (bh, streamed(bh, *at), 0)
+
+        def lanes(bh, *at):
+            return (bh, 0, streamed(bh, *at))
+
+        out = own
 
     in_specs = [
         pl.BlockSpec((1, chunk_q, d), rows),
@@ -757,12 +803,12 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
             jax.ShapeDtypeStruct((b * h_kv, t_k, d), k.dtype),
             jax.ShapeDtypeStruct((b * h_kv, t_k, d_v), v.dtype),
         ],
-        grid=(b * h_kv, t_k // block_k) + ((n_chunks,) if chunk else ()),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, block_k, d), own),
-                   pl.BlockSpec((1, block_k, d_v), own)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d_v), jnp.float32)],
+        out_specs=[pl.BlockSpec((1, block_k, d), out),
+                   pl.BlockSpec((1, block_k, d_v), out)],
+        scratch_shapes=[pltpu.VMEM((acc_rows, d), jnp.float32),
+                        pltpu.VMEM((acc_rows, d_v), jnp.float32)],
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
@@ -787,8 +833,8 @@ def _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale, causal, has_bias, b
     # dk/dv pass along lanes.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(lse.shape[:2])
-    if bd:  # K and V whole in the dq pass (``flash_attention_compatible`` holds them to VMEM); a query head a chunk
-        chunk_k, chunk_q = None, t_q
+    if bd:  # K and V whole in the dq pass, a query head's Q and dO in the dk/dv pass (``flash_attention_compatible``)
+        chunk_k = chunk_q = None
     else:
         chunk_k = _pick_chunk(t_k, kw["block_k"]) if chunked else None
         chunk_q = _pick_chunk(t_q, kw["block_q"]) if chunked else None

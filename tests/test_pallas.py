@@ -933,8 +933,10 @@ def test_bd_flash_attention_compiles_for_v5e_at_the_sdar_cells_shape(v5e_chip, m
     over 4 key/value heads of 128, [xt ; x0] of 2 x 4096 positions in blocks
     of 4, bf16. K and V of a head stay whole in VMEM in the forward and the
     dq pass (2 x 2 MB, double-buffered), a query head's Q and dO in the dk/dv
-    pass, whose third grid axis runs over the group: three Mosaic calls under
-    their own names, none of the causal family's."""
+    pass, fetched once a query head (its grid runs over key/value head,
+    query head, key tile) beside dk and dv of the whole key/value head in
+    float32 scratch (2 x 4 MB): three Mosaic calls under their own names,
+    none of the causal family's."""
     import jax
     import jax.numpy as jnp
 

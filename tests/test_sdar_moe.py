@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jcore
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -161,12 +162,17 @@ def qkv(t, heads, kv_heads, d=32, dv=32, b=2, seed=0):
 
 
 @pytest.mark.parametrize("t,block,heads,kv_heads,tile", [
-    (128, 4, 8, 1, 512), (256, 4, 4, 2, 128), (256, 1, 2, 2, 128), (256, 256, 2, 1, 128), (384, 96, 2, 1, 128)])
+    (128, 4, 8, 1, 512), (256, 4, 4, 2, 128), (256, 1, 2, 2, 128), (256, 256, 2, 1, 128), (384, 96, 2, 1, 128),
+    (256, 4, 8, 2, 128), (384, 12, 6, 2, 128)])
 def test_the_bd_kernels_match_the_dense_path_forward_and_in_all_three_gradients(t, block, heads, kv_heads, tile,
                                                                                  monkeypatch):
     """Under the interpreter, against ``dot_product_attention``'s XLA form
     with the 2T x 2T mask: 8 query heads a key/value head, one tile a half
-    and several, B = 1, B = T and a B that is no power of two."""
+    and several, B = 1, B = T and a B that is no power of two. The last two
+    give each key/value head several query heads and several key tiles a
+    half, so that the dk/dv pass adds up over the group in its whole-head
+    scratch, zeroes a key tile at the first query head and writes it at the
+    last, in the order ``_bd_key_order`` gives, under a B of 4 and of 12."""
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setattr(fa, "BLOCK_Q", tile)
     monkeypatch.setattr(fa, "BLOCK_K", tile)
@@ -187,11 +193,81 @@ def test_the_bd_kernels_match_the_dense_path_forward_and_in_all_three_gradients(
     assert got_grads[1].shape == k.shape and got_grads[2].shape == v.shape
 
 
+def kernel_calls(loss, *args):
+    """The grid mappings of the ``pallas_call``s in the gradient of ``loss``
+    with respect to its three arguments, traced at their shapes (nothing
+    runs), by the call's name."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (tuple, list)) else [param]:
+                    if isinstance(sub, (jcore.ClosedJaxpr, jcore.Jaxpr)):
+                        yield from walk(getattr(sub, "jaxpr", sub))
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*args)
+    return {eqn.params["name"]: eqn.params["grid_mapping"] for eqn in walk(traced.jaxpr)}
+
+
+def blocks_by_step(grid_mapping, operand: int):
+    """The grid's steps in the order the pipeline takes them (the last axis
+    fastest) and the block the index map of ``operand`` (inputs, then
+    outputs) gives at each: (rank of the grid, steps), (steps, rank of the
+    block)."""
+    at = np.indices(grid_mapping.grid).reshape(len(grid_mapping.grid), -1)
+    index_map = jcore.jaxpr_as_fun(grid_mapping.block_mappings[operand].index_map_jaxpr)
+    return at, np.stack(jax.vmap(index_map)(*jnp.asarray(at, jnp.int32)), axis=1)
+
+
+def changes(blocks):
+    """Where a block differs from the next step's: an input is fetched for
+    the step after each, an output written back at each and at the end."""
+    return np.append(np.any(blocks[1:] != blocks[:-1], axis=1), True)
+
+
+def test_the_bd_dkv_pass_fetches_a_query_heads_q_and_do_once_per_key_value_head():
+    """The dk/dv pass's grid at the SDAR cell's shape (32 query heads of 128
+    over 4 key/value heads, [xt ; x0] of 2 x 4096, blocks of 4), walked
+    through its index maps. Q, dO and the two row statistics change block 32
+    times a run, once a (key/value head, query head): 4 MB of Q and dO each
+    time, 128 MB a run, where the order (key/value head, key tile, query
+    head) fetched them at each of its 512 steps. dk and dv are written back
+    once a key tile, each when the group's last query head is through with
+    it. The causal family's chunked pass at the GLM cell's shape (20 heads of
+    256 / 256, T = 8192) keeps its grid and its 320 fetches: 16 a head, the
+    two chunks in turn over the key tiles of the first half, then the second
+    kept."""
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+    calls = kernel_calls(lambda q_, k_, v_: jnp.sum(fa.flash_attention(q_, k_, v_, block_diffusion=(4096, 4))), q, kv, kv)
+    assert sorted(calls) == ["bd_flash_attention_bwd_dkv", "bd_flash_attention_bwd_dq", "bd_flash_attention_fwd"]
+    dkv = calls["bd_flash_attention_bwd_dkv"]
+    assert dkv.grid == (4, 8, 16) and dkv.num_inputs == 6 and dkv.num_outputs == 2
+    for q_do_lse_delta in (0, 3, 4, 5):
+        assert changes(blocks_by_step(dkv, q_do_lse_delta)[1]).sum() == 32
+    for dk_dv in (6, 7):
+        at, blocks = blocks_by_step(dkv, dk_dv)
+        written = changes(blocks)
+        assert written.sum() == 4 * 16 and (at[1][written] == 7).all()
+        assert sorted(map(tuple, blocks[written])) == [(h, tile, 0) for h in range(4) for tile in range(16)]
+
+    q = jax.ShapeDtypeStruct((1, 20, 8192, 256), jnp.bfloat16)
+    calls = kernel_calls(lambda q_, k_, v_: jnp.sum(fa.flash_attention(q_, k_, v_, causal=True)), q, q, q)
+    dkv = calls["flash_attention_bwd_dkv_chunked"]
+    assert dkv.grid == (20, 16, 2)
+    for q_do_lse_delta in (0, 3, 4, 5):
+        assert changes(blocks_by_step(dkv, q_do_lse_delta)[1]).sum() == 320
+    assert changes(blocks_by_step(dkv, 6)[1]).sum() == 20 * 16
+
+
 def test_what_the_bd_kernels_cannot_take_is_turned_away_cleanly(monkeypatch):
     """T not a multiple of the tile, blocks that do not divide T, a mask or
     ``causal`` beside the family, query heads no multiple of the key/value
-    heads, K and V beyond VMEM: the XLA form runs and gives the mask's
-    answer. Fewer key/value heads are the new family's alone."""
+    heads, K and V beyond VMEM, or Q and dO of a head with dk and dv of a
+    whole key/value head in float32 beyond it (the dk/dv pass's scratch):
+    the XLA form runs and gives the mask's answer. Fewer key/value heads are
+    the new family's alone."""
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     q, k, v, _ = qkv(192, 4, 2)
     ok = fa.flash_attention_compatible
@@ -206,14 +282,32 @@ def test_what_the_bd_kernels_cannot_take_is_turned_away_cleanly(monkeypatch):
     assert not ok(q, k, v) and not ok(q, k, v, causal=True)
     big = jax.ShapeDtypeStruct((1, 8, 2 * 16384, 128), jnp.bfloat16)
     assert not ok(big, big, big, block_diffusion=(16384, 4))
+    # heads of 256 at 2 x 4096: Q and dO double-buffered are 16.8 MB, within
+    # the bound, but with dk and dv of the head in float32 33.6 MB; the SDAR
+    # cell's heads of 128 take 8.4 + 8.4 MB and stay
+    wide = jax.ShapeDtypeStruct((1, 8, 8192, 256), jnp.bfloat16)
+    assert 2 * 8192 * 512 * 2 <= fa.RESIDENT_BWD_VMEM < 8192 * 512 * (2 * 2 + 4)
+    assert not ok(wide, wide, wide, block_diffusion=(4096, 4))
+    sdar_q, sdar_kv = (jax.ShapeDtypeStruct((1, h, 8192, 128), jnp.bfloat16) for h in (32, 4))
+    assert ok(sdar_q, sdar_kv, sdar_kv, block_diffusion=(4096, 4))
     with pytest.raises(ValueError, match="mask family of its own"):
         fa.flash_attention(q, k, v, causal=True, block_diffusion=(128, 4))
+
+    def mask_answer(q, k, v, t):
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, 1)) / jnp.sqrt(32.0)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(
+            jnp.where(brute_force_mask(t, 4)[None, None], scores, -jnp.inf), -1), jnp.repeat(v, 2, 1))
+
     q, k, v, _ = qkv(192, 4, 2)
-    got = dot_product_attention(q, k, v, block_diffusion=(192, 4))
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, 1)) / jnp.sqrt(32.0)
-    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(
-        jnp.where(brute_force_mask(192, 4)[None, None], scores, -jnp.inf), -1), jnp.repeat(v, 2, 1))
-    close(got, want, 2e-5)
+    close(dot_product_attention(q, k, v, block_diffusion=(192, 4)), mask_answer(q, k, v, 192), 2e-5)
+    # the same rule at a small size: a bound that holds Q and dO but not the scratch beside them
+    q, k, v, _ = qkv(128, 4, 2)
+    monkeypatch.setattr(fa, "RESIDENT_BWD_VMEM", 2 * 256 * 64 * 4)
+    assert not ok(q, k, v, block_diffusion=(128, 4))
+    calls = []
+    monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw: calls.append(1))
+    close(dot_product_attention(q, k, v, block_diffusion=(128, 4)), mask_answer(q, k, v, 128), 2e-5)
+    assert not calls
 
 
 def attention_layer(**kw):
