@@ -29,6 +29,7 @@ held expert) and ``overflow`` (assignments beyond ``held_rows``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -39,6 +40,7 @@ from deeplearning4j_tpu.nn.base import GlobalConfig, Layer, register_layer
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.ops.initializers import init_weights
+from deeplearning4j_tpu.ops.pallas import rows_to_tokens
 
 
 @register_layer
@@ -123,14 +125,18 @@ class MixtureOfExperts(Layer):
         top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True), floor)  # gates sum to 1 over the selected
         return scores, top_idx, top_vals * self.routed_scale
 
-    def _dispatch(self, tokens, top_idx):
+    def _buffer_rows(self, n_assign: int) -> int:
+        return min(self.held_rows or n_assign, n_assign)
+
+    def _dispatch(self, tokens, top_idx, sum_by_kernel=False):
         """Sort the assignments to held experts by expert into the buffer.
         Returns (rows (R, nIn), the token and the slot of each row, whether a
         row holds an assignment, each held expert's rows in the buffer,
-        assignments routed to each held expert, assignments left out)."""
+        assignments routed to each held expert, assignments left out, and
+        with ``sum_by_kernel`` the plan by which ``rows_to_tokens`` sums rows
+        into tokens, else None)."""
         first, held = self._held()
-        n_assign = top_idx.size
-        rows = min(self.held_rows or n_assign, n_assign)
+        rows = self._buffer_rows(top_idx.size)
         local = top_idx.reshape(-1) - first
         expert = jnp.where((local >= 0) & (local < held), local, held)  # elsewhere: sorted to the end
         order = jnp.argsort(expert, stable=True)[:rows]
@@ -139,11 +145,18 @@ class MixtureOfExperts(Layer):
         sizes = jnp.diff(ends, prepend=0)
         filled = jnp.arange(rows) < ends[-1]
         token = order // top_idx.shape[1]
-        # rows past the last group are left as they are by the grouped matmul, forward and backward:
-        # select them away here, so that nothing of them reaches a token's gradient
-        rows_in = jnp.where(filled[:, None], tokens[token], 0)
+        plan = None
+        if sum_by_kernel:
+            # each expert's rows ascend by token (the sort is stable): the runs the kernel reads;
+            # a row that holds nothing gets the id past the last token, and the gather's zero
+            plan = rows_to_tokens.plan(jnp.where(filled, token, tokens.shape[0]), ends, tokens.shape[0])
+            rows_in = rows_to_tokens.tokens_to_rows(tokens, plan)
+        else:
+            # rows past the last group are left as they are by the grouped matmul, forward and backward:
+            # select them away here, so that nothing of them reaches a token's gradient
+            rows_in = jnp.where(filled[:, None], tokens[token], 0)
         return (rows_in, token, order, filled, sizes, assigned.astype(jnp.float32),
-                (jnp.sum(assigned) - ends[-1]).astype(jnp.float32))
+                (jnp.sum(assigned) - ends[-1]).astype(jnp.float32), plan)
 
     def _experts(self, p, rows, sizes):
         dot = lambda x, w: jax.lax.ragged_dot(x, w, sizes)
@@ -164,13 +177,21 @@ class MixtureOfExperts(Layer):
                      else None)
         scores, top_idx, gates = scoped(
             "router", self._route, params["W_router"], state.get("select_bias", 0.0), tokens, noise_key)
-        rows, token, slot, filled, sizes, assigned, overflow = scoped("dispatch", self._dispatch, tokens, top_idx)
         expert_p = {k: v for k, v in params.items() if k.startswith(("W_e", "b_e"))}
+        # the sums of rows into tokens (combine, and the gradient of dispatch's gather) by the kernel
+        # where it is compiled for this call; a call over several devices keeps XLA's scatter-add
+        by_kernel = rows_to_tokens.kernel_route(
+            tokens.shape[0], self._buffer_rows(top_idx.size), (shape[-1], self.n_out),
+            tokens, *expert_p.values())
+        rows, token, slot, filled, sizes, assigned, overflow, plan = scoped(
+            "dispatch", functools.partial(self._dispatch, sum_by_kernel=by_kernel), tokens, top_idx)
         out_rows = scoped("experts", self._experts, expert_p, rows, sizes)
 
         def combine(out_rows, gates):
             weight = jnp.where(filled, gates.reshape(-1)[slot], 0.0)
             weighted = jnp.where(filled[:, None], out_rows, 0.0) * weight[:, None].astype(out_rows.dtype)
+            if plan is not None:
+                return rows_to_tokens.rows_to_tokens(weighted, plan)
             return jnp.zeros((tokens.shape[0], self.n_out), out_rows.dtype).at[token].add(weighted)
 
         y = scoped("combine", combine, out_rows, gates)

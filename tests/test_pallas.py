@@ -962,8 +962,11 @@ def test_flash_attention_adds_no_layout_copy_to_a_glm_step(v5e_chip, monkeypatch
     out of the padded (b*h, T, 8) array in XLA it was one copy a block). The
     step of ``chip_smoke``'s small GLM-4.7-Flash (2 x 1024, heads of 192 + 64
     against 256, bf16 compute, every scope recomputed), compiled for the
-    described chip: 54 Mosaic calls, twelve of them the three flash kernels
-    in its four blocks, and no more top-level copies than PR 35's step held."""
+    described chip: 60 Mosaic calls, twelve of them the three flash kernels
+    in its four blocks, six the sum of rows into tokens of its three expert
+    layers (``combine`` forward, ``dispatch`` backward), and no more
+    top-level copies than the step held before the flash kernels' layouts
+    were chosen."""
     import jax
     import jax.numpy as jnp
 
@@ -989,11 +992,58 @@ def test_flash_attention_adds_no_layout_copy_to_a_glm_step(v5e_chip, monkeypatch
         env.set_remat(remat)
     entry = _entry_instructions(text)
     ops = [op for _, op, _ in entry.values()]
-    assert ops.count("tpu_custom_call") == 54
+    assert ops.count("tpu_custom_call") == 60
+    assert len(re.findall(r"/rows_to_tokens/pallas_call", text[text.index("\nENTRY "):])) == 6
     assert ops.count("copy") <= 285, ops.count("copy")
     flash = [m.group(1) for line in text[text.index("\nENTRY "):].splitlines() if "tpu_custom_call" in line
              for m in [re.search(r"/(flash_attention_\w+)/pallas_call", line)] if m]
     assert sorted(flash) == sorted(4 * ["flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"])
+
+
+@pytest.mark.parametrize("d,experts,top_k,held_rows,shared", [(2048, 128, 8, 12288, 0), (2304, 256, 8, 4096, 1)])
+def test_an_expert_layer_sums_rows_into_tokens_without_a_scatter_for_v5e(v5e_chip, monkeypatch, d, experts, top_k,
+                                                                          held_rows, shared):
+    """An expert layer of the SDAR cell (softmax top-8 of 128, 8 held, 12288
+    rows of 2048) and of the Kimi cell (sigmoid top-8 of 256, 8 held and the
+    shared one, 4096 rows of 2304), forward and backward at 8192 tokens in
+    bf16, every scope recomputed, compiled for the described chip: the sum
+    into tokens is the ``rows_to_tokens`` kernel twice, once under
+    ``combine`` (forward) and once under ``dispatch`` (the gather's
+    gradient), and no scatter writes a (tokens, width) array."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn import MixtureOfExperts
+    from deeplearning4j_tpu.nn.base import GlobalConfig
+    from deeplearning4j_tpu.nn.inputs import InputType
+    from deeplearning4j_tpu.runtime.environment import get_environment
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the route's platform probe sees the described chip
+    layer = MixtureOfExperts(n_out=d, n_experts=experts, held=(0, 8), top_k=top_k, router="sigmoid" if shared else "softmax",
+                             hidden_size=768, held_rows=held_rows, n_shared=shared, gated=True, activation="swish",
+                             aux_loss_coef=0.0)
+    g = GlobalConfig(dtype=jnp.float32)
+    layer._g = g
+    params, state = jax.eval_shape(lambda k: layer.init(k, InputType.recurrent(d, 8192), g), jax.random.PRNGKey(0))
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16 if a.ndim > 1 else a.dtype, sharding=v5e_chip)  # noqa: E731
+    x = jax.ShapeDtypeStruct((1, 8192, d), jnp.bfloat16, sharding=v5e_chip)
+
+    def loss(p, s_, x_):
+        return jnp.sum(layer.forward(p, s_, x_, training=True)[0].astype(jnp.float32) ** 2)
+
+    env = get_environment()
+    remat = env.remat_segments
+    try:
+        env.set_remat(True)
+        text = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(jax.tree.map(spec, params), jax.tree.map(spec, state),
+                                                             x).compile().as_text()
+    finally:
+        env.set_remat(remat)
+    kernels = re.findall(r'op_name="([^"]*rows_to_tokens/pallas_call)"', text[text.index("\nENTRY "):])
+    assert len(kernels) == 2, kernels
+    assert sum("jvp(combine)" in k and "transpose" not in k for k in kernels) == 1
+    assert sum("transpose(jvp(dispatch))" in k for k in kernels) == 1
+    assert not re.search(rf"bf16\[8192,{d}\]\S* scatter\(", text)
 
 
 def test_fused_attention_costs_no_layout_copy_in_a_bert_step(v5e_chip, monkeypatch):
