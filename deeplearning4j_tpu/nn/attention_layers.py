@@ -45,8 +45,9 @@ def dot_product_attention(q, k, v, mask=None, use_flash: bool = True,
     clean]`` of ``2 t`` positions); an incompatible call takes the XLA
     softmax form below. ``k`` and ``v`` may have fewer heads than ``q``
     (query head ``i`` reads key/value head ``i // group``): the kernels
-    index them so under ``block_diffusion``, the XLA form repeats them. A
-    kernel that raises is a bug and surfaces — nothing here catches it."""
+    index them so under ``block_diffusion`` and under ``causal`` without a
+    key-padding mask, the XLA form repeats them. A kernel that raises is a
+    bug and surfaces — nothing here catches it."""
     from deeplearning4j_tpu.ops.pallas.flash_attention import (
         block_diffusion_allowed, flash_attention, flash_attention_compatible)
     if use_flash and flash_attention_compatible(q, k, v, mask, causal=causal, block_diffusion=block_diffusion):
@@ -577,7 +578,8 @@ class GroupedQueryAttention(Layer):
     the same positions ``0 .. t/2 - 1``, under the block-diffusion mask
     (``ops.pallas.flash_attention.block_diffusion_allowed``). The T x T part
     goes through ``dot_product_attention`` and the routing it owns; K and V
-    are handed over at their own number of heads."""
+    are handed over at their own number of heads, which the flash kernels
+    take under either mask (causal without a key-padding mask)."""
 
     n_heads: int = 32
     n_kv_heads: int = 4

@@ -1,5 +1,6 @@
-"""Linear-attention layers: the gated delta rule with a per-channel decay
-(Kimi Delta Attention, KDA; Kimi Linear, arXiv:2510.26692).
+"""Linear-time sequence mixers: the gated delta rule with a per-channel
+decay (Kimi Delta Attention, KDA; Kimi Linear, arXiv:2510.26692), and the
+gated short convolution of the LFM2 family (``GatedShortConv``).
 
 Per head, with a state ``S`` of (d_k, d_v) that starts at zero::
 
@@ -164,6 +165,45 @@ def causal_conv(x, w):
     n, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
     return sum(padded[:, i:i + t] * w[i] for i in range(n))
+
+
+@register_layer
+@dataclasses.dataclass
+class GatedShortConv(Layer):
+    """The gated short convolution of the LFM2 family (its ``conv``
+    operator), no biases::
+
+        [B | C | x~] = x W_in            (W_in: d x 3d, split in this order)
+        y = (C * conv(B * x~)) W_out     (W_out: d x d)
+
+    ``conv`` is :func:`causal_conv` with ``kernel_size`` taps a channel
+    (``conv``: (kernel_size, d)) and zeros before the first token; no
+    activation. The gates and the taps compute in float32 and hand
+    ``W_out`` the input's dtype. Scopes: ``short_conv/in_proj``,
+    ``short_conv/conv`` (both gates and the convolution) and
+    ``short_conv/out_proj``."""
+
+    kernel_size: int = 3
+
+    def init(self, key, input_type, g: GlobalConfig):
+        d = input_type.size
+        shapes = {"W_in": (d, 3 * d), "conv": (self.kernel_size, d), "W_out": (d, d)}
+        return {name: init_weights(k, shape, self._winit(g), fan=shape, dtype=g.dtype)
+                for (name, shape), k in zip(shapes.items(), jax.random.split(key, len(shapes)))}, {}
+
+    @staticmethod
+    def gated_conv(w, bcx):
+        b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+        return (c * causal_conv(b * x, w.astype(jnp.float32))).astype(bcx.dtype)
+
+    def forward(self, params, state, x, *, training=False, rng=None, mask=None):
+        with jax.named_scope("short_conv"):
+            bcx = scoped("in_proj", lambda w, x_: x_ @ w, params["W_in"], x)
+            y = scoped("conv", self.gated_conv, params["conv"], bcx)
+            return scoped("out_proj", lambda w, y_: y_ @ w, params["W_out"], y), state
+
+    def regularizable_params(self):
+        return ("W_in", "W_out")
 
 
 def l2_normalize(x, eps=1e-6):

@@ -23,7 +23,9 @@ The aux loss rides the model-state channel: forward returns it under
 ``_aux_loss`` and ``MultiLayerNetwork._loss`` adds every such entry to the
 training loss (in-trace, so gradients flow to the router). The state also
 holds the last step's counters: ``assigned`` (assignments routed to each
-held expert) and ``overflow`` (assignments beyond ``held_rows``).
+held expert) and ``overflow`` (assignments beyond ``held_rows``), and under
+the sigmoid router the selection bias, which ``bias_rate`` moves after every
+training step against the load the step gave each expert.
 """
 
 from __future__ import annotations
@@ -64,6 +66,9 @@ class MixtureOfExperts(Layer):
     router_noise: float = 0.0  # stddev of train-time router logit jitter
     router: str = "softmax"  # or "sigmoid": scores + selection bias pick, unbiased scores weigh
     routed_scale: float = 1.0
+    # sigmoid router: each training step moves the selection bias by this towards an even load (down for
+    # experts chosen more often than the mean, up for the others: DeepSeek-V3's auxiliary-loss-free balancing)
+    bias_rate: float = 0.0
     gated: bool = False  # SwiGLU experts without biases
     n_shared: int = 0  # shared experts of the same width, run on every token
     held: Optional[Tuple[int, int]] = None  # (first, count) of n_experts; default all
@@ -200,6 +205,13 @@ class MixtureOfExperts(Layer):
 
         new_state = dict(state)
         new_state["assigned"], new_state["overflow"] = assigned, overflow
+        if training and self.bias_rate and self.router == "sigmoid":
+            # every expert's load over this step's tokens (padding left out), held here or not
+            chosen = jax.nn.one_hot(top_idx, E, dtype=jnp.float32).sum(1)
+            if mask is not None and len(shape) == 3:
+                chosen = chosen * mask.reshape(-1, 1).astype(jnp.float32)
+            load = chosen.sum(0)
+            new_state["select_bias"] = state["select_bias"] + self.bias_rate * jnp.sign(jnp.mean(load) - load)
         if self.aux_loss_coef:
             # Switch-style load balancing: fraction routed (top-1) x mean prob.
             # Masked (padding) tokens are excluded — balancing garbage tokens

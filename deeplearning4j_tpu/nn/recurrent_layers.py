@@ -422,6 +422,28 @@ class RnnOutputLayer(OutputLayer):
 
 @register_layer
 @dataclasses.dataclass
+class TiedRnnOutputLayer(RnnOutputLayer):
+    """``RnnOutputLayer`` whose weight is the embedding's table read
+    transposed, ``logits = x E^T`` (weight tying): ``E`` (vocabulary, nIn)
+    is owned by the embedding layer and named in ``tied`` as ``{"W":
+    "<embedding layer's key>/W"}``. The head owns no weight of its own (a
+    bias where ``has_bias``); ``E`` is one leaf of the network's parameters,
+    so its gradient is the sum of the lookup's and the head's and the
+    updater moves it once."""
+
+    def init(self, key, input_type, g: GlobalConfig):
+        params, state = super().init(key, input_type, g)
+        del params["W"]
+        return params, state
+
+    def preoutput(self, params, x):
+        with jax.named_scope("lm_head"):
+            y = x @ params["W"].T
+            return y + params["b"] if self.has_bias else y
+
+
+@register_layer
+@dataclasses.dataclass
 class BlockDiffusionLoss(RnnOutputLayer):
     """Output head of a language model trained by diffusion over blocks
     (BD3-LM's training form, arXiv:2503.09573 section 3; SDAR,

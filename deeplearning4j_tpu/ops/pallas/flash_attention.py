@@ -95,6 +95,15 @@ These calls are named ``bd_flash_attention_fwd`` / ``_bwd_dq`` /
 ``_bwd_dkv``; what a masked tile costs over a whole one is measured, not
 yet cut (PERF.md).
 
+Under ``causal``, without a key-padding mask, the query heads may be a
+multiple of the key/value heads too, in the same row layout: the forward and
+the dq pass fetch K and V once a group. The resident dk/dv pass runs its
+grid over (key/value head, query head, key tile) as above, the key tiles
+from last to first (the first, which every query tile sees, ends a head);
+its chunked form takes the group's query heads one after another along its
+chunk axis. These runs keep the causal kernels' names. With one query head
+a key/value head the kernels are the ones they were.
+
 Used automatically by ``nn.attention_layers.dot_product_attention`` when
 :func:`flash_attention_compatible` says the shapes and the platform allow;
 otherwise the XLA softmax form runs. ``DL4J_TPU_PALLAS_INTERPRET=1`` runs
@@ -165,8 +174,10 @@ def flash_attention_compatible(q, k, v, mask=None, causal: bool = False, block_d
     blocks, and what a head keeps in VMEM within bounds: K and V of a head
     (forward, dq pass) or Q and dO of one (dk/dv pass), double-buffered,
     and in the dk/dv pass dk and dv of a whole key/value head in float32
-    scratch; only there may the query heads be a multiple of the key/value
-    heads."""
+    scratch (:func:`_by_query_head_bytes`). The query heads may be a
+    multiple of the key/value heads under ``block_diffusion`` and under
+    ``causal`` without a key-padding mask (past the VMEM bound the causal
+    backward takes its chunked forms)."""
     if q.ndim != 4:
         return False
     t_q, d = q.shape[2], q.shape[3]
@@ -175,9 +186,10 @@ def flash_attention_compatible(q, k, v, mask=None, causal: bool = False, block_d
         t, block = block_diffusion
         if (mask is not None or causal or t_q != 2 * t or t_k != 2 * t or t % 128 or t % block
                 or q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]
-                or t_k * (d + v.shape[3]) * (2 * q.dtype.itemsize + 4) > RESIDENT_BWD_VMEM):
+                or _by_query_head_bytes(t_k, d, v.shape[3], q.dtype.itemsize) > RESIDENT_BWD_VMEM):
             return False
-    elif k.shape[1] != q.shape[1]:
+    elif k.shape[1] != q.shape[1] and (not causal or mask is not None or q.shape[1] % k.shape[1]
+                                       or k.shape[1] != v.shape[1]):
         return False
     if mask is not None and _padding_mask_2d(mask, q.shape[0], t_k) is None:
         return False
@@ -337,6 +349,14 @@ def _heads_flat(x, h_kv: int = None):
     return x.reshape(x.shape[0] * (h_kv or x.shape[1]), -1, x.shape[3])
 
 
+def _q_tiles(q, k, block_q: int, bd):
+    """Query tiles a query head has, where the kernels' rows run over a
+    group's query heads (always under ``bd``, else where the key/value heads
+    are fewer): a grid step's tile within its head is its row block modulo
+    this. ``None`` where a row is one query head."""
+    return q.shape[2] // block_q if bd or k.shape[1] != q.shape[1] else None
+
+
 def _lanes(x, n: int):
     """A lane-replicated (rows, 128) row statistic as (rows, n)."""
     return jnp.tile(x, (1, n // 128)) if n % 128 == 0 else jnp.broadcast_to(x[:, :1], (x.shape[0], n))
@@ -362,7 +382,7 @@ def _stepwise(n_chunks, init, work, flush, axis: int = 2):
 
 
 def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
-                causal: bool, save_residuals: bool, bd=None):
+                causal: bool, save_residuals: bool, bd=None, q_tiles=None):
     q_ref, k_ref, v_ref = refs[:3]
     bias_ref = refs[3] if has_bias else None
     o_ref = refs[3 + has_bias]
@@ -375,8 +395,8 @@ def _fwd_kernel(*refs, scale: float, block_k: int, has_bias: bool,
     qi = pl.program_id(1)
     block_q = q.shape[0]
     mask = _tile_mask(bd)
-    if bd:  # the rows run over a group's query heads, head after head
-        qi = qi % (2 * bd[0] // block_q)
+    if q_tiles:  # the rows run over a group's query heads, head after head
+        qi = qi % q_tiles
     d_v = v_ref.shape[2]
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -430,8 +450,7 @@ def _flash_fwd(q, k, v, bias, *, scale, causal, has_bias, block_q, block_k,
     t_k = k.shape[2]
     d_v = v.shape[-1]
     # the grid's rows are the key/value heads; a head's group of query heads
-    # lies head after head along the query axis (one query head a row but
-    # under ``bd``)
+    # lies head after head along the query axis
     bh, rows_q = b * k.shape[1], h // k.shape[1] * t_q
     grid = (bh, rows_q // block_q)
     in_specs = [
@@ -458,7 +477,7 @@ def _flash_fwd(q, k, v, bias, *, scale, causal, has_bias, block_q, block_k,
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                           has_bias=has_bias, causal=causal,
-                          save_residuals=save_residuals, bd=bd),
+                          save_residuals=save_residuals, bd=bd, q_tiles=_q_tiles(q, k, block_q, bd)),
         name=_name("fwd", bd),
         out_shape=out_shape,
         grid=grid,
@@ -483,7 +502,7 @@ def _flash_fwd(q, k, v, bias, *, scale, causal, has_bias, block_q, block_k,
 
 
 def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
-                   causal: bool, n_chunks, bd=None):
+                   causal: bool, n_chunks, bd=None, q_tiles=None):
     """dq pass. Resident (``n_chunks`` None): grid (bh, qi). Chunked: grid
     (bh, qi, ci), K/V blocks are the ci-th chunk. dq accumulates in scratch,
     unscaled, and takes ``scale`` where it is written out."""
@@ -499,8 +518,8 @@ def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
     block_q = q.shape[0]
     nb = k_ref.shape[1] // block_k
     mask = _tile_mask(bd)
-    if bd:  # resident only; the rows run over a group's query heads
-        qi = qi % (2 * bd[0] // block_q)
+    if q_tiles:  # the rows run over a group's query heads
+        qi = qi % q_tiles
 
     def init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -544,14 +563,17 @@ def _bwd_dq_kernel(*refs, scale: float, block_k: int, has_bias: bool,
 
 
 def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
-                    causal: bool, n_chunks, bd=None):
+                    causal: bool, n_chunks, bd=None, by_query_head=False, head_chunks=None):
     """dk/dv pass. Resident (``n_chunks`` None): grid (bh, ki). Chunked:
-    grid (bh, ki, ci), Q/dO/lse/delta blocks are the ci-th chunk. The score
-    tile is computed transposed, (BK, BQ): keys on sublanes, queries on
-    lanes, so that both results are plain matmuls of it; ``lse`` and
-    ``delta`` come along lanes. dk accumulates unscaled. Under ``bd`` the
-    grid is (bh, query head of the group, key tile in :func:`_bd_key_order`)
-    and ``n_chunks`` the group: the scratch holds every key tile of the
+    grid (bh, ki, ci), Q/dO/lse/delta blocks are the ci-th chunk; with
+    grouped query heads a head has ``head_chunks`` of them and the group's
+    heads follow one another along the chunk axis. The score tile is
+    computed transposed, (BK, BQ): keys on sublanes, queries on lanes, so
+    that both results are plain matmuls of it; ``lse`` and ``delta`` come
+    along lanes. dk accumulates unscaled. With ``by_query_head`` (under
+    ``bd``, and for grouped heads in the resident form) the grid is (bh,
+    query head of the group, key tile in :func:`_key_order`) and
+    ``n_chunks`` the group: the scratch holds every key tile of the
     key/value head and adds up over the group, a key tile's rows zeroed at
     the first query head and written out at the last."""
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
@@ -562,8 +584,8 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
     v = v_ref[0]                              # (BK, Dv)
     in_dtype = k.dtype
     block_k = k.shape[0]
-    if bd:
-        ki = _bd_key_order(pl.program_id(2), 2 * bd[0] // block_k)
+    if by_query_head:
+        ki = _key_order(pl.program_id(2), dk_acc_ref.shape[0] // block_k, bd)
         own = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
         dk_acc_ref, dv_acc_ref = dk_acc_ref.at[own], dv_acc_ref.at[own]
     else:
@@ -576,7 +598,8 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     def work(ci):
-        first = 0 if bd else ci * nb  # index of this chunk's first query block within its head
+        # index of this chunk's first query block within its head
+        first = 0 if by_query_head else (ci % head_chunks if head_chunks else ci) * nb
 
         def tile(i, masked):
             rows = pl.ds(i * block_q, block_q)
@@ -615,7 +638,7 @@ def _bwd_dkv_kernel(*refs, scale: float, block_q: int, has_bias: bool,
         dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
-    _stepwise(n_chunks, init, work, flush, axis=1 if bd else 2)
+    _stepwise(n_chunks, init, work, flush, axis=1 if by_query_head else 2)
 
 
 # Above this sequence length the backward switches to the CHUNKED forms:
@@ -658,6 +681,14 @@ def _resident_bwd_bytes(t: int, d: int, d_v: int, itemsize: int) -> int:
     return 2 * t * ((d + d_v) * itemsize + 2 * 128 * 4)
 
 
+def _by_query_head_bytes(t: int, d: int, d_v: int, itemsize: int) -> int:
+    """What the dk/dv pass that runs over query heads keeps in VMEM: a query
+    head's Q and dO, double-buffered, and dk and dv of the whole key/value
+    head in float32 scratch (the dq pass's K and V, double-buffered, are
+    less). The forms whose grid runs over query heads go by this rule."""
+    return t * (d + d_v) * (2 * itemsize + 4)
+
+
 def _pick_chunk(t: int, block: int) -> int:
     """Largest multiple of ``block`` <= BWD_CHUNK that divides t (the
     kernels index sub-blocks inside the chunk, so block | chunk)."""
@@ -677,6 +708,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
     d_v = v.shape[-1]
     chunk_k = chunk or t_k
     bh, rows_q = b * k.shape[1], h // k.shape[1] * t_q  # as in the forward
+    q_tiles = _q_tiles(q, k, block_q, bd)
 
     def own(bh, qi, *ci):
         return (bh, qi, 0)
@@ -684,6 +716,8 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
     def streamed(bh, qi, *ci):
         if not ci:
             return (bh, 0, 0)
+        if q_tiles:  # the query tile within its head
+            qi = qi % q_tiles
         # Steps whose whole K/V chunk lies above the causal diagonal are
         # compute-skipped in the kernel (its ranges clip to nothing) — ALSO
         # skip their DMA by re-mapping the chunk index to the last needed
@@ -708,7 +742,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           has_bias=has_bias, causal=causal,
-                          n_chunks=t_k // chunk if chunk else None, bd=bd),
+                          n_chunks=t_k // chunk if chunk else None, bd=bd, q_tiles=q_tiles),
         name=_name("bwd_dq", bd, chunk),
         out_shape=jax.ShapeDtypeStruct((bh, rows_q, d), q.dtype),
         grid=(bh, rows_q // block_q) + ((t_k // chunk,) if chunk else ()),
@@ -730,25 +764,37 @@ def _bd_key_order(j, n_k: int):
     return jax.lax.rem(j + n_k // 2 + 1, n_k)
 
 
+def _key_order(j, n_k: int, bd):
+    """The key tile of the ``j``-th step of a query head in a dk/dv pass
+    whose grid runs over query heads: :func:`_bd_key_order` under ``bd``;
+    else the last key tile first and the first, which every query tile of
+    the head sees under ``causal``, last (the same reason)."""
+    return _bd_key_order(j, n_k) if bd else n_k - 1 - j
+
+
 @functools.partial(jax.jit, static_argnames=_STATIC + ("chunk",))
 def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
                    has_bias, block_q, block_k, interpret, bd=None):
     """dk, dv from (b, h, t, d) operands and (b*h, 1, t_q) row statistics;
-    Q/dO whole a grid step (``chunk`` None) or ``chunk`` rows. Under ``bd``
-    the grid is (key/value head, query head of its group, key tile): a query
-    head's Q, dO and row statistics keep one block for all the key tiles, so
-    they are fetched once a (key/value head, query head); dk and dv keep the
-    block of the key tile the last query head starts with until that head,
-    so that each tile is written back once, when it is whole."""
+    Q/dO whole a grid step (``chunk`` None) or ``chunk`` rows. Under ``bd``,
+    and with grouped query heads in the resident form, the grid is
+    (key/value head, query head of its group, key tile): a query head's Q,
+    dO and row statistics keep one block for all the key tiles, so they are
+    fetched once a (key/value head, query head); dk and dv keep the block of
+    the key tile the last query head starts with until that head, so that
+    each tile is written back once, when it is whole. Chunked, the group's
+    query heads follow one another along the chunk axis."""
     b, h, t_q, d = q.shape
     h_kv, t_k = k.shape[1], k.shape[2]
     d_v = v.shape[-1]
-    if bd:
-        group, n_k = h // h_kv, t_k // block_k
+    group = h // h_kv
+    by_query_head, head_chunks = bool(bd) or (group > 1 and not chunk), None
+    if by_query_head:
+        n_k = t_k // block_k
         grid, n_chunks, chunk_q, acc_rows = (b * h_kv, group, n_k), group, t_q, t_k
 
         def own(bh, g, kj):
-            return (bh, _bd_key_order(kj, n_k), 0)
+            return (bh, _key_order(kj, n_k, bd), 0)
 
         def rows(bh, g, kj):
             return (bh, g, 0)
@@ -757,10 +803,11 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
             return (bh, 0, g)
 
         def out(bh, g, kj):
-            return (bh, _bd_key_order(jnp.where(g == group - 1, kj, 0), n_k), 0)
+            return (bh, _key_order(jnp.where(g == group - 1, kj, 0), n_k, bd), 0)
     else:
         chunk_q = chunk or t_q
-        n_chunks = t_q // chunk if chunk else None
+        n_chunks = group * t_q // chunk if chunk else None
+        head_chunks = t_q // chunk if chunk and group > 1 else None
         grid, acc_rows = (b * h_kv, t_k // block_k) + ((n_chunks,) if chunk else ()), block_k
 
         def own(bh, ki, *ci):
@@ -772,6 +819,9 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
             # mirror of the dq-pass DMA skip: query chunks strictly above the
             # diagonal for this key block re-map to the first needed chunk
             first = (ki * block_k) // chunk_q
+            if head_chunks:  # within the query head whose chunks these are
+                c = ci[0] % head_chunks
+                return ci[0] - c + (jnp.maximum(c, first) if causal else c)
             return jnp.maximum(ci[0], first) if causal else ci[0]
 
         def rows(bh, *at):
@@ -797,7 +847,8 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, bias, *, chunk, scale, causal,
         args.append(bias)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          has_bias=has_bias, causal=causal, n_chunks=n_chunks, bd=bd),
+                          has_bias=has_bias, causal=causal, n_chunks=n_chunks, bd=bd,
+                          by_query_head=by_query_head, head_chunks=head_chunks),
         name=_name("bwd_dkv", bd, chunk),
         out_shape=[
             jax.ShapeDtypeStruct((b * h_kv, t_k, d), k.dtype),
@@ -825,8 +876,9 @@ def _flash_bwd(q, k, v, bias, out, lse, lse_lanes, g, scale, causal, has_bias, b
     b, h, t_q, d = q.shape
     t_k = k.shape[2]
     kw = dict(scale=scale, causal=causal, has_bias=has_bias, bd=bd, **_blocks(q, k, bd))
+    rule = _by_query_head_bytes if k.shape[1] != h else _resident_bwd_bytes
     chunked = (max(t_q, t_k) > BWD_CHUNK_THRESHOLD
-               or _resident_bwd_bytes(max(t_q, t_k), d, v.shape[-1], q.dtype.itemsize) > RESIDENT_BWD_VMEM)
+               or rule(max(t_q, t_k), d, v.shape[-1], q.dtype.itemsize) > RESIDENT_BWD_VMEM)
     # D = rowsum(dO * O): cheap elementwise-reduce, fused by XLA. The dq pass
     # takes it and lse a row a sublane, lane-broadcast over a narrow trailing
     # axis as the forward stores lse (Mosaic block layout requirement); the
@@ -880,8 +932,9 @@ def flash_attention(q, k, v, mask=None, causal: bool = False, block_diffusion=No
     :func:`flash_attention_compatible` first). ``causal=True`` applies the
     autoregressive triangle with diagonal block skipping;
     ``block_diffusion=(t, block)`` the block-diffusion mask over ``[noisy ;
-    clean]`` of ``2 t`` positions, where ``k`` and ``v`` may have fewer heads
-    than ``q`` (query head ``i`` reads key/value head ``i // group``)."""
+    clean]`` of ``2 t`` positions. Under either, without a key-padding mask,
+    ``k`` and ``v`` may have fewer heads than ``q`` (query head ``i`` reads
+    key/value head ``i // group``)."""
     b, t_k = q.shape[0], k.shape[2]
     if block_diffusion is not None:
         if mask is not None or causal:
@@ -892,6 +945,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False, block_diffusion=No
     if mask is not None and kmask is None:
         raise ValueError("flash_attention supports key-padding masks only; "
                          "use the XLA fallback for other mask shapes")
+    if k.shape[1] != q.shape[1] and (mask is not None or not causal):
+        raise ValueError("grouped query heads: causal without a key-padding mask, or block_diffusion")
     scale = 1.0 / float(q.shape[-1]) ** 0.5
     has_bias = kmask is not None
     if has_bias:

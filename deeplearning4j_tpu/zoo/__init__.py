@@ -24,6 +24,7 @@ from deeplearning4j_tpu.zoo.textgen_lstm import TextGenerationLSTM
 from deeplearning4j_tpu.zoo.bert import Bert
 from deeplearning4j_tpu.zoo.glm_moe_lite import GlmMoeLite
 from deeplearning4j_tpu.zoo.kimi_linear import KimiLinear
+from deeplearning4j_tpu.zoo.lfm2_moe import Lfm2Moe
 from deeplearning4j_tpu.zoo.sdar_moe import SdarMoe
 from deeplearning4j_tpu.zoo.vgg19 import VGG19
 from deeplearning4j_tpu.zoo.squeezenet import SqueezeNet
@@ -32,5 +33,5 @@ from deeplearning4j_tpu.zoo.inception_resnet import InceptionResNetV1
 from deeplearning4j_tpu.zoo.yolo2 import TinyYOLO, YOLO2
 
 __all__ = ["ZooModel", "LeNet", "SimpleCNN", "AlexNet", "VGG16", "VGG19",
-           "ResNet50", "UNet", "Darknet19", "TextGenerationLSTM", "Bert", "KimiLinear", "GlmMoeLite", "SdarMoe",
+           "ResNet50", "UNet", "Darknet19", "TextGenerationLSTM", "Bert", "KimiLinear", "GlmMoeLite", "SdarMoe", "Lfm2Moe",
            "SqueezeNet", "Xception", "InceptionResNetV1", "TinyYOLO", "YOLO2"]

@@ -397,6 +397,117 @@ def test_flash_attention_is_causal_bit_for_bit(monkeypatch, chunk, last):
         assert np.asarray(grad[:, :, :last + 1], np.float32).any()
 
 
+_GQA = {  # id -> (dtype, T, BLOCK_Q, BLOCK_K, BWD_CHUNK or None for the resident pair, query heads, key/value heads)
+    "resident-g4-f32": ("float32", 512, 128, 128, None, 8, 2),
+    "resident-g4-bf16": ("bfloat16", 512, 128, 128, None, 8, 2),
+    "resident-g4-q256-k128": ("float32", 512, 256, 128, None, 4, 1),
+    "resident-g4-q128-k256": ("float32", 512, 128, 256, None, 4, 1),
+    # two chunks a query head: the group's heads one after another along the chunk axis
+    "chunked-g4-f32": ("float32", 768, 128, 128, 384, 8, 2),
+    "chunked-g4-bf16": ("bfloat16", 768, 128, 128, 384, 8, 2),
+    "chunked-g4-q256-k128": ("float32", 1024, 256, 128, 512, 4, 1),
+    "chunked-g2-q128-k256": ("float32", 1024, 128, 256, 512, 4, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_GQA))
+def test_causal_flash_with_grouped_heads_matches_the_xla_form(monkeypatch, case):
+    """Query heads a multiple of the key/value heads under ``causal``, heads
+    of 64: the output and all three gradients against the XLA softmax form,
+    which repeats K and V, resident and chunked, wherever the diagonal falls;
+    dk and dv come back at the key/value heads' count, each the sum over its
+    group; the runs keep the causal kernels' names."""
+    import jax
+    import jax.numpy as jnp
+    dtype, t, block_q, block_k, chunk, heads, kv_heads = _GQA[case]
+    fa = _patched_flash(monkeypatch, block_q, block_k, chunk)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    q, w = (jax.random.normal(key, (1, heads, t, 64)).astype(dtype) for key in (ks[0], ks[3]))
+    k, v = (jax.random.normal(key, (1, kv_heads, t, 64)).astype(dtype) for key in ks[1:3])
+    assert fa.flash_attention_compatible(q, k, v, causal=True)
+    assert not fa.flash_attention_compatible(q, k, v) and not fa.flash_attention_compatible(
+        q, k, v, jnp.ones((1, t), bool), causal=True)
+
+    def xla(q, k, v):
+        f32 = lambda a: jnp.repeat(a.astype(jnp.float32), heads // kv_heads, axis=1)  # noqa: E731
+        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), f32(k), precision="highest") * 64 ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), f32(v), precision="highest")
+
+    def both(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w), argnums=(0, 1, 2))(q, k, v)
+
+    flash = lambda q, k, v: fa.flash_attention(q, k, v, causal=True)  # noqa: E731
+    text = jax.jit(jax.grad(lambda *a: jnp.sum(flash(*a)), argnums=(0, 1, 2))).lower(q, k, v).as_text(debug_info=True)
+    assert ("flash_attention_bwd_dkv_chunked" in text) == (chunk is not None) and "bd_flash" not in text
+    np.testing.assert_allclose(flash(q, k, v).astype(jnp.float32), xla(q, k, v), rtol=0,
+                               atol=2e-5 if dtype == "float32" else 2e-2)
+    got, want = both(flash), both(xla)
+    assert got[1].shape == got[2].shape == k.shape
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=0, atol=(1e-5 if dtype == "float32" else 2e-2) * np.abs(b).max())
+    with pytest.raises(ValueError, match="grouped query heads"):
+        fa.flash_attention(q, k, v)
+
+
+def _dkv_grid(q, kv):
+    """The dk/dv pass's ``pallas_call`` in the causal gradient at these
+    shapes (traced, nothing runs) and the blocks its index maps give for each
+    operand, step by step in the pipeline's order (the last axis fastest)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.extend import core as jcore
+
+    from deeplearning4j_tpu.ops.pallas import flash_attention as fa
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (tuple, list)) else [param]:
+                    if isinstance(sub, (jcore.ClosedJaxpr, jcore.Jaxpr)):
+                        yield from walk(getattr(sub, "jaxpr", sub))
+
+    traced = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(fa.flash_attention(*a, causal=True)), argnums=(0, 1, 2)))(
+        q, kv, kv)
+    eqn = next(e for e in walk(traced.jaxpr) if e.params["name"].startswith("flash_attention_bwd_dkv"))
+    grid = eqn.params["grid_mapping"]
+    at = np.indices(grid.grid).reshape(len(grid.grid), -1)
+    blocks = [np.stack(jax.vmap(jcore.jaxpr_as_fun(m.index_map_jaxpr))(*jnp.asarray(at, jnp.int32)), axis=1)
+              for m in grid.block_mappings]
+    return eqn.params["name"], grid.grid, at, blocks
+
+
+def test_the_grouped_causal_dkv_pass_fetches_a_query_heads_q_and_do_once():
+    """At the LFM2 cell's shape (32 query heads of 64 over 8 key/value heads,
+    T = 8192) the resident dk/dv pass runs its grid over (key/value head,
+    query head, key tile): Q, dO and the two row statistics change block 32
+    times a run, once a query head (1 MB of Q and of dO each time), and dk and
+    dv are written once a key tile, when the group's last query head is
+    through with it, the last key tile first and the first last. One query
+    head a key/value head keeps the grid it had (GLM's 20 heads of 256:
+    chunked, 320 fetches)."""
+    import jax
+    import jax.numpy as jnp
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 64), jnp.bfloat16)
+    name, grid, at, blocks = _dkv_grid(q, kv)
+    assert name == "flash_attention_bwd_dkv" and grid == (8, 4, 16) and len(blocks) == 8
+    fetched = lambda b: np.append(np.any(b[1:] != b[:-1], axis=1), True)  # noqa: E731
+    for q_do_lse_delta in (0, 3, 4, 5):
+        assert fetched(blocks[q_do_lse_delta]).sum() == 32
+    for dk_dv in (6, 7):
+        written = fetched(blocks[dk_dv])
+        assert written.sum() == 8 * 16 and (at[1][written] == 3).all()
+        assert [tuple(b) for b in blocks[dk_dv][written][:16]] == [(0, tile, 0) for tile in range(15, -1, -1)]
+    glm = jax.ShapeDtypeStruct((1, 20, 8192, 256), jnp.bfloat16)
+    name, grid, _, blocks = _dkv_grid(glm, glm)
+    assert name == "flash_attention_bwd_dkv_chunked" and grid == (20, 16, 2)
+    assert np.append(np.any(blocks[0][1:] != blocks[0][:-1], axis=1), True).sum() == 320
+
+
 def test_dot_product_attention_fallback_mask_forms_and_decode_causal():
     """XLA fallback must accept the same mask family as the kernel and use
     bottom-right-aligned causal masking for KV-cache decode shapes."""
@@ -926,6 +1037,33 @@ def test_flash_attention_compiles_for_v5e_with_two_head_sizes_at_t8192(v5e_chip,
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v).compile().as_text()
     assert text.count("tpu_custom_call") == 3
     assert "flash_attention_bwd_dq_chunked" in text and "flash_attention_bwd_dkv_chunked" in text
+
+
+@pytest.mark.parametrize("t,form", [(8192, "resident"), (16384, "chunked")])
+def test_causal_flash_with_grouped_heads_compiles_for_v5e(v5e_chip, monkeypatch, t, form):
+    """The LFM2 cell's attention, 32 query heads over 8 key/value heads of
+    64, causal, bf16: at T = 8192 the resident backward (the dk/dv pass holds
+    a query head's Q and dO, double-buffered, beside dk and dv of the whole
+    key/value head in float32: 8.4 MB), at 16384 the chunked one; three Mosaic
+    calls under the causal kernels' names."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.delenv("DL4J_TPU_PALLAS_INTERPRET")
+    q = jax.ShapeDtypeStruct((1, 32, t, 64), jnp.bfloat16, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, t, 64), jnp.bfloat16, sharding=v5e_chip)
+    assert fa._by_query_head_bytes(8192, 64, 64, 2) <= fa.RESIDENT_BWD_VMEM
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3 and "bd_flash" not in text
+    suffix = "_chunked" if form == "chunked" else ""
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq" + suffix, "flash_attention_bwd_dkv" + suffix):
+        assert name in text
+    assert ("_chunked" in text) == (form == "chunked")
 
 
 def test_bd_flash_attention_compiles_for_v5e_at_the_sdar_cells_shape(v5e_chip, monkeypatch):

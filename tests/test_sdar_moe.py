@@ -267,7 +267,7 @@ def test_what_the_bd_kernels_cannot_take_is_turned_away_cleanly(monkeypatch):
     heads, K and V beyond VMEM, or Q and dO of a head with dk and dv of a
     whole key/value head in float32 beyond it (the dk/dv pass's scratch):
     the XLA form runs and gives the mask's answer. Fewer key/value heads are
-    the new family's alone."""
+    this family's and the causal one's without a key-padding mask."""
     monkeypatch.setenv("DL4J_TPU_PALLAS_INTERPRET", "1")
     q, k, v, _ = qkv(192, 4, 2)
     ok = fa.flash_attention_compatible
@@ -279,7 +279,7 @@ def test_what_the_bd_kernels_cannot_take_is_turned_away_cleanly(monkeypatch):
     assert not ok(q, k, v, jnp.ones((2, 256), bool), block_diffusion=(128, 4))
     assert not ok(q, k, v, block_diffusion=(64, 4))         # not the two halves of this sequence
     assert not ok(q[:, :3], k, v, block_diffusion=(128, 4))
-    assert not ok(q, k, v) and not ok(q, k, v, causal=True)
+    assert not ok(q, k, v) and ok(q, k, v, causal=True) and not ok(q, k, v, jnp.ones((2, 256), bool), causal=True)
     big = jax.ShapeDtypeStruct((1, 8, 2 * 16384, 128), jnp.bfloat16)
     assert not ok(big, big, big, block_diffusion=(16384, 4))
     # heads of 256 at 2 x 4096: Q and dO double-buffered are 16.8 MB, within
